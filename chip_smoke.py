@@ -1,0 +1,635 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the main path once on ONE TPU chip, through the entry points a user
+calls, at the repo's own BASELINE.json sizes, and checks every answer
+against the host oracle:
+
+  commit_verify  config 2: one 10,000-validator ed25519 Commit through
+                 ValidatorSet.verify_commit with the process-default
+                 verifier, then the same lanes with a seeded 1 % corrupted
+                 (signature / message / public-key bytes) through
+                 collect_commit_sigs + verify_generic — all 10,000 verdicts
+                 equal HostBatchVerifier's.
+  fast_sync      config 3, cut from 50,000 blocks to the Makefile's
+                 bench_fastsync size: 2,048 blocks x 64 validators, window
+                 512 (32,768 signatures per dispatch), through
+                 blockchain/reactor.verify_block_window + BlockExecutor
+                 .apply_block — final height, app_hash and last block id
+                 equal the same replay under HostBatchVerifier; a second
+                 pass with one seeded commit signature flipped stops at the
+                 same height with the same error.
+  secp256k1      config 4: a 256-validator secp256k1 commit through
+                 verify_commit / verify_generic -> ops/secp256k1_pallas.
+  ed25519_msm    one 512-signature window with ed25519_path="msm" (the RLC
+                 seed is a hash of the seeded content, so it is pinned).
+  node           a live node through the CLI, no TM_BATCH_VERIFIER in its
+                 environment: height 5, three broadcast_tx_commit read back
+                 by abci_query, /commit?height=3&verify=1, SIGTERM -> exit 0,
+                 and its start-up program a compile-cache HIT on what the
+                 kernel stages stored.
+
+A stage passes only with backend == "pallas", >= 1 device dispatch, zero
+device_fallback_total and host_fallback_total, zero audit mismatches and a
+closed breaker.  Seconds are printed as set-up facts, never recorded as
+performance numbers.
+
+One process per chip: this parent never imports jax.  The four kernel
+stages share one child; the CLI node is a second child started after the
+first has exited.  Without a TPU the first child says so and exits before
+any stage; the command takes no flag.
+
+Last line of stdout on success:
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+"""
+
+import json
+import os
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import time
+import traceback
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+OUT = os.path.join(HERE, "chiprun_out", "chip_smoke")
+
+SEED = 42
+N_VALIDATORS = 10_000
+CORRUPT_SHARE = 0.01
+FASTSYNC_BLOCKS, FASTSYNC_VALS, FASTSYNC_WINDOW = 2048, 64, 512
+SECP_VALIDATORS = 256
+MSM_WINDOW = 512
+NODE_HEIGHT = 5
+BACKEND = "pallas"  # what every stage must have run on
+
+KERNELS_DEADLINE_S = 840.0
+NODE_DEADLINE_S = 240.0
+KERNEL_STAGES = ("commit_verify", "fast_sync", "secp256k1", "ed25519_msm")
+STAGE_PREFIX = "STAGE "
+
+NO_TPU_EXIT = 3
+
+
+# ---------------------------------------------------------------------------
+# The verdict, shared by both children's reports (pure: tests drive it)
+# ---------------------------------------------------------------------------
+
+
+def stage_problems(report: dict) -> list:
+    """Why a stage report does not pass; empty when it does."""
+    name = report.get("stage", "?")
+    bad = []
+    if report.get("error") or not report.get("ok"):
+        bad.append(f"{name}: {report.get('error') or 'stage did not pass'}")
+    if report.get("platform") != "tpu":
+        bad.append(f"{name}: platform is {report.get('platform')!r}, not tpu")
+    if report.get("backend") != BACKEND:
+        bad.append(
+            f"{name}: backend is {report.get('backend')!r}, not {BACKEND}")
+    device = sum(
+        n for k, n in report.get("dispatches", {}).items()
+        if k.startswith(BACKEND + "/")
+    )
+    if device < 1:
+        bad.append(f"{name}: no device dispatch")
+    for key in ("device_fallback_total", "host_fallback_total"):
+        for reason, n in report.get(key, {}).items():
+            if n:
+                bad.append(f"{name}: {key}{{reason={reason}}} = {n:g}")
+    mismatches = report.get("device_audit_total", {}).get("mismatch", 0)
+    if mismatches:
+        bad.append(f"{name}: {mismatches:g} audit mismatches")
+    if report.get("breaker_state") != "closed":
+        bad.append(f"{name}: breaker is {report.get('breaker_state')!r}")
+    return bad
+
+
+def aggregate(reports: list, expected=KERNEL_STAGES + ("node",)) -> int:
+    """Exit code for a run: 0 only if every expected stage reported and
+    passed.  Prints one line per problem."""
+    problems = []
+    seen = {r.get("stage") for r in reports}
+    for name in expected:
+        if name not in seen:
+            problems.append(f"{name}: no report (stage did not run to an end)")
+    for r in reports:
+        problems.extend(stage_problems(r))
+    for p in problems:
+        print(f"chip_smoke: FAIL {p}", file=sys.stderr)
+    return 1 if problems else 0
+
+
+def _delta(after: dict, before: dict) -> dict:
+    out = {k: v - before.get(k, 0) for k, v in after.items()}
+    return {k: v for k, v in out.items() if v}
+
+
+# ---------------------------------------------------------------------------
+# Child 1: the kernel stages (the only code here that imports jax)
+# ---------------------------------------------------------------------------
+
+
+def _flip(data: bytes, rng) -> bytes:
+    i = rng.randrange(len(data) * 8)
+    b = bytearray(data)
+    b[i // 8] ^= 1 << (i % 8)
+    return bytes(b)
+
+
+def _compare_with_host(pubkeys, msgs, sigs, checks, verifier=None):
+    """Lane-for-lane parity of the device verdicts with the host oracle."""
+    import numpy as np
+
+    from tendermint_tpu.crypto.batch import HostBatchVerifier, verify_generic
+
+    got = verify_generic(pubkeys, msgs, sigs, verifier=verifier)
+    want = verify_generic(pubkeys, msgs, sigs, verifier=HostBatchVerifier())
+    diff = np.flatnonzero(got != want)
+    checks["lanes"] = len(want)
+    checks["rejected_host"] = int(np.count_nonzero(~want))
+    checks["rejected_device"] = int(np.count_nonzero(~got))
+    assert diff.size == 0, f"device != host in lanes {diff[:8].tolist()}"
+    return want
+
+
+def _corrupt(pubkeys, msgs, sigs, lanes, rng, rekey):
+    """Seeded one-bit corruptions, cycling signature / message / key."""
+    for k, lane in enumerate(lanes):
+        if k % 3 == 0:
+            sigs[lane] = _flip(sigs[lane], rng)
+        elif k % 3 == 1:
+            msgs[lane] = _flip(msgs[lane], rng)
+        else:
+            pubkeys[lane] = rekey(_flip(pubkeys[lane].bytes(), rng))
+
+
+def stage_commit_verify(checks: dict) -> None:
+    import math
+    import random
+
+    from tendermint_tpu.crypto.keys import PubKeyEd25519
+    from tendermint_tpu.testutil.chain import build_commit
+
+    chain_id, height = "smoke-commit", 500
+    valset, block_id, commit = build_commit(
+        N_VALIDATORS, seed=SEED, chain_id=chain_id, height=height)
+    # no verifier= argument: the process default, chosen as a node chooses it
+    valset.verify_commit(chain_id, block_id, height, commit)
+    checks["verify_commit_accepted"] = True
+
+    pubkeys, msgs, sigs, _ = valset.collect_commit_sigs(
+        chain_id, block_id, height, commit)
+    pubkeys, msgs, sigs = list(pubkeys), list(msgs), list(sigs)
+    rng = random.Random(SEED)
+    lanes = rng.sample(
+        range(len(sigs)), math.ceil(len(sigs) * CORRUPT_SHARE))
+    _corrupt(pubkeys, msgs, sigs, lanes, rng, PubKeyEd25519)
+    want = _compare_with_host(pubkeys, msgs, sigs, checks)
+    checks["corrupted"] = len(lanes)
+    assert not want[lanes].any(), "host oracle accepted a corrupted lane"
+    assert checks["rejected_host"] == len(lanes)
+
+
+def _replay(genesis, blocks, verifier):
+    """Windowed verify + apply, the way blockchain/reactor's sync loop does
+    it.  Returns (height, app_hash, last block id, error)."""
+    from tendermint_tpu.blockchain.reactor import verify_block_window
+    from tendermint_tpu.testutil.chain import fresh_executor
+    from tendermint_tpu.types import BlockID
+
+    st, block_exec = fresh_executor(genesis)
+    trusted, pos, err = set(), 0, None
+    while pos < len(blocks) - 1 and err is None:
+        window = blocks[pos : pos + FASTSYNC_WINDOW + 1]
+        parts = []
+        n_ok, err = verify_block_window(
+            st, window, verifier=verifier, parts_out=parts)
+        trusted.update(b.height for b in window[:n_ok])
+        for block, part_set in zip(window[:n_ok], parts):
+            block_id = BlockID(hash=block.hash(), parts_header=part_set.header())
+            st = block_exec.apply_block(
+                st, block_id, block,
+                trusted_last_commit=block.height - 1 in trusted,
+            )
+        if n_ok == 0 and err is None:
+            raise AssertionError(f"window at {pos} verified nothing")
+        pos += n_ok
+    return (
+        st.last_block_height, st.app_hash.hex(), st.last_block_id.hash.hex(),
+        str(err) if err is not None else None,
+    )
+
+
+def stage_fast_sync(checks: dict) -> None:
+    import random
+
+    from tendermint_tpu.crypto import batch
+    from tendermint_tpu.testutil.chain import build_chain
+    from tendermint_tpu.types.block import Commit
+
+    # set-up: the chain is data, built under the host oracle
+    device_default = batch.get_batch_verifier()
+    batch.set_batch_verifier(batch.HostBatchVerifier())
+    try:
+        fx = build_chain(
+            n_vals=FASTSYNC_VALS, n_heights=FASTSYNC_BLOCKS,
+            chain_id="smoke-sync", txs_per_block=2)  # app_hash moves
+    finally:
+        batch.set_batch_verifier(device_default)
+    load = fx.block_store.load_block
+    blocks = [load(h) for h in range(1, FASTSYNC_BLOCKS + 1)]
+
+    host = _replay(fx.genesis, blocks, batch.HostBatchVerifier())
+    device = _replay(fx.genesis, blocks, None)  # None: the default verifier
+    checks["replay"] = {"device": device, "host": host}
+    assert device == host, "device replay differs from the host replay"
+    assert host[0] == FASTSYNC_BLOCKS - 1 and host[3] is None, host
+
+    # one seeded commit signature flipped: the commit FOR height h_bad
+    # travels in block h_bad + 1
+    rng = random.Random(SEED)
+    h_bad = rng.randrange(FASTSYNC_WINDOW + 2, FASTSYNC_BLOCKS - 1)
+    j = rng.randrange(FASTSYNC_VALS)
+    carrier = load(h_bad + 1)
+    pcs = list(carrier.last_commit.precommits)
+    pcs[j] = pcs[j].with_signature(_flip(pcs[j].signature, rng))
+    carrier.last_commit = Commit(carrier.last_commit.block_id, pcs)
+    tampered = list(blocks)
+    tampered[h_bad] = carrier
+    host_bad = _replay(fx.genesis, tampered, batch.HostBatchVerifier())
+    device_bad = _replay(fx.genesis, tampered, None)
+    checks["tampered"] = {
+        "height": h_bad, "validator": j,
+        "device": device_bad, "host": host_bad,
+    }
+    assert device_bad == host_bad, "tampered replays differ"
+    assert host_bad[0] == h_bad - 1 and host_bad[3] is not None, host_bad
+
+
+def stage_secp256k1(checks: dict) -> None:
+    import random
+
+    from tendermint_tpu.crypto import secp256k1 as secp
+    from tendermint_tpu.crypto.keys import PrivKeySecp256k1, PubKeySecp256k1
+    from tendermint_tpu.types import BlockID, PartSetHeader, SignedMsgType, Vote
+    from tendermint_tpu.types.block import Commit
+    from tendermint_tpu.types.validator_set import Validator, ValidatorSet
+
+    chain_id, height = "smoke-secp", 9
+    rng = random.Random(SEED)
+    privs = [
+        PrivKeySecp256k1.generate(rng.randbytes(32))
+        for _ in range(SECP_VALIDATORS)
+    ]
+    by_addr = {p.pub_key().address(): p for p in privs}
+    valset = ValidatorSet([Validator(p.pub_key(), 10) for p in privs])
+    block_id = BlockID(b"\x77" * 32, PartSetHeader(1, b"\x88" * 32))
+    votes = []
+    for idx, val in enumerate(valset.validators):
+        vote = Vote(
+            vote_type=SignedMsgType.PRECOMMIT, height=height, round=0,
+            timestamp_ns=1_700_000_000_000_000_000 + idx, block_id=block_id,
+            validator_address=val.address, validator_index=idx,
+        )
+        votes.append(vote.with_signature(
+            by_addr[val.address].sign(vote.sign_bytes(chain_id))))
+    commit = Commit(block_id=block_id, precommits=votes)
+    valset.verify_commit(chain_id, block_id, height, commit)
+    checks["verify_commit_accepted"] = True
+
+    pubkeys, msgs, sigs, _ = valset.collect_commit_sigs(
+        chain_id, block_id, height, commit)
+    pubkeys, msgs, sigs = list(pubkeys), list(msgs), list(sigs)
+    lanes = rng.sample(range(len(sigs)), 6)
+    _corrupt(pubkeys, msgs, sigs, lanes[:5], rng, PubKeySecp256k1)
+    # and one well-formed DER signature over the wrong scalar
+    r, s = secp.der_decode_sig(sigs[lanes[5]])
+    sigs[lanes[5]] = secp.der_encode_sig(r, s ^ 1)
+    want = _compare_with_host(pubkeys, msgs, sigs, checks)
+    checks["corrupted"] = len(lanes)
+    assert not want[lanes].any(), "host oracle accepted a corrupted lane"
+
+
+def stage_ed25519_msm(checks: dict) -> None:
+    import random
+
+    from tendermint_tpu.crypto.batch import (
+        GuardedBatchVerifier,
+        TPUBatchVerifier,
+    )
+    from tendermint_tpu.crypto.keys import PubKeyEd25519
+    from tendermint_tpu.testutil.chain import build_commit
+
+    verifier = GuardedBatchVerifier(
+        TPUBatchVerifier(backend=BACKEND, ed25519_path="msm"))
+    chain_id, height = "smoke-msm", 7
+    valset, block_id, commit = build_commit(
+        MSM_WINDOW, seed=SEED + 1, chain_id=chain_id, height=height)
+    pubkeys, msgs, sigs, _ = valset.collect_commit_sigs(
+        chain_id, block_id, height, commit)
+    pubkeys, msgs, sigs = list(pubkeys), list(msgs), list(sigs)
+    clean = {}
+    want = _compare_with_host(pubkeys, msgs, sigs, clean, verifier=verifier)
+    assert want.all(), "host oracle rejected a clean window"
+    # a dirty window: the MSM rejects, chunk RLCs localize, the exact
+    # ladder decides the dirty rows
+    rng = random.Random(SEED + 1)
+    lanes = rng.sample(range(len(sigs)), 5)
+    _corrupt(pubkeys, msgs, sigs, lanes, rng, PubKeyEd25519)
+    want = _compare_with_host(pubkeys, msgs, sigs, checks, verifier=verifier)
+    checks["corrupted"] = len(lanes)
+    checks["clean_window_accepted"] = True
+    assert not want[lanes].any(), "host oracle accepted a corrupted lane"
+
+
+def _report(stage, error, checks, t0, versions, info, before=None) -> dict:
+    """One stage's report from ``verifier_info()`` taken after it (minus
+    ``before``, when the process ran earlier stages too)."""
+    def since(key):
+        after = info.get(key) or {}
+        return _delta(after, before[key]) if before else after
+
+    dev = info.get("device") or {}
+    compiled = {
+        k: v for k, v in (info.get("compile") or {}).items() if k != "cache_dir"
+    }
+    return {
+        "stage": stage,
+        "ok": error is None,
+        "error": error,
+        "checks": checks,
+        "platform": dev.get("platform"),
+        "device_kind": dev.get("kind"),
+        "device_count": dev.get("count"),
+        "versions": versions,
+        "backend": info.get("backend"),
+        "wall_seconds": round(time.monotonic() - t0, 1),
+        "compile_cache_dir": (info.get("compile") or {}).get("cache_dir"),
+        "compile": (
+            _delta(compiled, before["compile"]) if before else compiled),
+        "dispatches": since("dispatches"),
+        "device_fallback_total": since("device_fallback_total"),
+        "host_fallback_total": since("host_fallback_total"),
+        "device_audit_total": since("device_audit_total"),
+        "breaker_state": info.get("breaker_state"),
+    }
+
+
+def _versions() -> dict:
+    from importlib.metadata import version
+
+    return {p: version(p) for p in ("jax", "jaxlib", "libtpu")}
+
+
+def _run_kernel_stage(name: str, fn) -> dict:
+    """One stage: run it, then report what the process-wide verify metrics,
+    the breaker and the compile accounting saw during it."""
+    from tendermint_tpu.crypto.batch import verifier_info
+
+    before = verifier_info()
+    t0 = time.monotonic()
+    checks: dict = {}
+    error = None
+    try:
+        fn(checks)
+    except Exception as e:  # stage boundary: recorded, reported, exit != 0
+        traceback.print_exc()
+        error = f"{type(e).__name__}: {e}"
+    return _report(
+        name, error, checks, t0, _versions(), verifier_info(), before)
+
+
+def kernels_main() -> int:
+    import logging
+
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(
+            "chip_smoke: no TPU — jax.devices()[0].platform is "
+            f"{dev.platform!r} (JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS', '')!r}); no stage was run",
+            file=sys.stderr,
+        )
+        return NO_TPU_EXIT
+    # warnings (every host completion of a device dispatch is one) and the
+    # verifier-selection line; not the per-block chatter of 8,192 applies
+    logging.basicConfig(
+        level=logging.WARNING, stream=sys.stderr,
+        format="%(levelname)s %(name)s: %(message)s",
+    )
+    logging.getLogger("tendermint_tpu.verify").setLevel(logging.INFO)
+
+    from tendermint_tpu.crypto import batch
+    from tendermint_tpu.encoding import native
+
+    native.build_all()  # raises when cc refuses a committed .c file
+    try:
+        verifier = batch.get_batch_verifier()
+    except ValueError as e:
+        print(f"chip_smoke: verifier refused: {e}", file=sys.stderr)
+        return 4
+    if batch.verifier_info()["device"] is None:
+        print("chip_smoke: the default verifier is not a device verifier: "
+              f"{batch.describe_verifier(verifier)}", file=sys.stderr)
+        return 4
+    stages = {
+        "commit_verify": stage_commit_verify,
+        "fast_sync": stage_fast_sync,
+        "secp256k1": stage_secp256k1,
+        "ed25519_msm": stage_ed25519_msm,
+    }
+    rc = 0
+    for name in KERNEL_STAGES:
+        report = _run_kernel_stage(name, stages[name])
+        print(STAGE_PREFIX + json.dumps(report), flush=True)
+        rc = rc or (1 if stage_problems(report) else 0)
+    return rc
+
+
+# ---------------------------------------------------------------------------
+# Parent: orchestration (never imports jax) and the live-node stage
+# ---------------------------------------------------------------------------
+
+
+def _child_env() -> dict:
+    env = dict(os.environ)
+    env.pop("TM_BATCH_VERIFIER", None)  # the node chooses from the machine
+    return env
+
+
+def _run_kernels_child() -> tuple:
+    """Run child 1 to its end under a deadline; (exit code, stage reports).
+    Its stdout is echoed line by line; its stderr is inherited."""
+    proc = subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__), "--child", "kernels"],
+        stdout=subprocess.PIPE, text=True, cwd=HERE, env=_child_env(),
+    )
+    reports = []
+    killer = _kill_after(proc, KERNELS_DEADLINE_S)
+    try:
+        for line in proc.stdout:
+            print(line, end="", flush=True)
+            if line.startswith(STAGE_PREFIX):
+                reports.append(json.loads(line[len(STAGE_PREFIX):]))
+        rc = proc.wait()
+    finally:
+        killer.cancel()
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()
+    return rc, reports
+
+
+def _kill_after(proc, seconds: float):
+    import threading
+
+    def _kill():
+        print(f"chip_smoke: deadline of {seconds:.0f}s exceeded; killing "
+              f"pid {proc.pid}", file=sys.stderr)
+        proc.kill()
+
+    t = threading.Timer(seconds, _kill)
+    t.daemon = True
+    t.start()
+    return t
+
+
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def _drive_node(client, proc, checks: dict, deadline: float) -> dict:
+    """Height, transactions, stored-commit verification; returns /status."""
+    import base64
+    import http.client
+
+    from tendermint_tpu.rpc.client import RPCClientError
+
+    height = -1
+    while height < NODE_HEIGHT:
+        if proc.poll() is not None:
+            raise AssertionError(f"node exited with code {proc.returncode}")
+        if time.monotonic() > deadline:
+            raise AssertionError(f"height {height} < {NODE_HEIGHT} at deadline")
+        try:
+            status = client.status()
+            height = int(status["sync_info"]["latest_block_height"])
+        except (OSError, http.client.HTTPException, RPCClientError):
+            pass  # RPC not up yet
+        time.sleep(0.5)
+    checks["height"] = height
+
+    for i in range(3):
+        key, value = f"smoke{i}".encode(), f"v{i}".encode()
+        res = client.broadcast_tx_commit(key + b"=" + value)
+        assert res["check_tx"].get("code", 0) == 0, res
+        assert res["deliver_tx"].get("code", 0) == 0, res
+        got = client.abci_query(data=key)["response"]
+        assert got["code"] == 0, got
+        assert base64.b64decode(got["value"]) == value, got
+    checks["txs_committed_and_read_back"] = 3
+
+    verification = client.call("commit", height=3, verify=1)["verification"]
+    checks["commit_3_verification"] = verification
+    assert verification["verified"] is True, verification
+    return client.status()
+
+
+def node_stage() -> dict:
+    """The CLI node as a user starts it: init, node, RPC, SIGTERM."""
+    from tendermint_tpu.rpc.client import HTTPClient
+
+    home = os.path.join(OUT, "node")
+    shutil.rmtree(home, ignore_errors=True)
+    cli = [sys.executable, "-m", "tendermint_tpu.cmd.tendermint", "--home", home]
+    env = _child_env()
+    t0 = time.monotonic()
+    checks: dict = {}
+    error = None
+    info: dict = {}
+    log_path = os.path.join(OUT, "node.log")
+    proc = None
+    try:
+        subprocess.run(cli + ["init"], check=True, cwd=HERE, env=env, timeout=120)
+        port = _free_port()
+        with open(log_path, "w") as log:
+            proc = subprocess.Popen(
+                cli + ["node", "--proxy_app", "kvstore", "--rpc.laddr",
+                       f"tcp://127.0.0.1:{port}", "--p2p.laddr", "none"],
+                stdout=log, stderr=subprocess.STDOUT, cwd=HERE, env=env,
+            )
+        try:
+            status = _drive_node(
+                HTTPClient(f"127.0.0.1:{port}", timeout=90.0), proc, checks,
+                deadline=t0 + NODE_DEADLINE_S,
+            )
+            info = status["verifier_info"]
+        finally:
+            if proc.poll() is None:
+                proc.send_signal(signal.SIGTERM)
+                try:
+                    proc.wait(timeout=60)
+                except subprocess.TimeoutExpired:
+                    proc.kill()
+                    proc.wait()
+                    raise AssertionError("node ignored SIGTERM for 60 s")
+        checks["sigterm_exit_code"] = proc.returncode
+        assert proc.returncode == 0, f"node exit code {proc.returncode}"
+        with open(log_path) as f:
+            started = [ln.strip() for ln in f if ln.startswith("Batch verifier:")]
+        checks["start_line"] = started[0] if started else None
+        assert started and f"backend={BACKEND}" in started[0], started
+        compiled = info.get("compile", {})
+        assert compiled.get("cache_hits", 0) >= 1, (
+            f"the node's start-up program was not a compile-cache hit: "
+            f"{compiled} — the cache key moves between processes")
+    except Exception as e:  # stage boundary: recorded, reported, exit != 0
+        traceback.print_exc()
+        error = f"{type(e).__name__}: {e}"
+        if os.path.exists(log_path):
+            with open(log_path) as f:
+                sys.stderr.write("---- node.log (tail) ----\n")
+                sys.stderr.writelines(f.readlines()[-40:])
+    return _report("node", error, checks, t0, _versions(), info)
+
+
+def main() -> int:
+    if not os.path.isdir(os.path.join(HERE, "tendermint_tpu")):
+        print("chip_smoke: tendermint_tpu/ is not next to this script",
+              file=sys.stderr)
+        return 2
+    os.makedirs(OUT, exist_ok=True)
+    rc, reports = _run_kernels_child()
+    if not reports:
+        # the child named its reason (no TPU, a refused configuration, a
+        # compiler that refused an extension); no stage ran
+        return rc or 1
+    # the chip is free again: the kernel child has exited
+    node = node_stage()
+    print(STAGE_PREFIX + json.dumps(node), flush=True)
+    reports.append(node)
+    with open(os.path.join(OUT, "report.json"), "w") as f:
+        json.dump(reports, f, indent=1)
+    failed = aggregate(reports) or rc
+    if failed:
+        print(f"chip_smoke: FAILED (kernel child exit code {rc})",
+              file=sys.stderr)
+        return failed
+    first = reports[0]
+    print(json.dumps({"ok": True, "device": {
+        "platform": first["platform"], "kind": first["device_kind"],
+        "count": first["device_count"],
+    }}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    if sys.argv[1:] == ["--child", "kernels"]:
+        sys.exit(kernels_main())
+    sys.exit(main())

@@ -7,8 +7,52 @@ sizes, per-backend dispatch/compile latency, fallback counts) to go with
 the end-to-end number.
 """
 
+import os
 import sys
 from typing import Optional
+
+
+def cpu_requested() -> bool:
+    """The two explicit CPU switches.  A bench never measures the host or
+    XLA-on-CPU in place of a missing chip: the CPU has to be asked for."""
+    return (
+        os.environ.get("TM_BATCH_VERIFIER", "").lower() == "host"
+        or os.environ.get("JAX_PLATFORMS", "").lower() == "cpu"
+    )
+
+
+def bench_verifier():
+    """(verifier, verifier_info) selected the way a node selects it — from
+    TM_BATCH_VERIFIER, then jax.devices() under JAX_PLATFORMS.  Exits
+    non-zero when that lands on the host because no TPU was found and the
+    CPU was not asked for."""
+    from tendermint_tpu.crypto import batch
+
+    verifier = batch.reprobe(force=True)
+    info = batch.verifier_info()
+    print(f"# verifier: {info['description']}", file=sys.stderr, flush=True)
+    if info["latched_reason"] is not None and not cpu_requested():
+        raise SystemExit(
+            f"no device verifier ({info['description']}); set "
+            "TM_BATCH_VERIFIER=host or JAX_PLATFORMS=cpu to bench the CPU"
+        )
+    return verifier, info
+
+
+def bench_platform() -> str:
+    """jax.devices()[0].platform; exits non-zero when it is not a TPU and
+    the CPU was not asked for."""
+    import jax
+
+    dev = jax.devices()[0]
+    print(f"# device: {dev.platform} {dev.device_kind!r} x{len(jax.devices())}",
+          file=sys.stderr, flush=True)
+    if dev.platform != "tpu" and not cpu_requested():
+        raise SystemExit(
+            f"no TPU (jax.devices()[0] is {dev.platform}); set "
+            "JAX_PLATFORMS=cpu to bench the XLA kernel on the CPU"
+        )
+    return dev.platform
 
 
 def pop_metrics_out(argv=None) -> Optional[str]:

@@ -12,7 +12,6 @@ host-path numbers that explain where the fast-sync/consensus millisecond goes.
 
 Prints one JSON line per benchmark:
   {"metric": "...", "value": N, "unit": "..."}
-Used by `make bench-local` to regenerate BENCH_LOCAL.md.
 """
 
 import json

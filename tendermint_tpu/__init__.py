@@ -24,4 +24,30 @@ Layer map (mirrors reference layer map, see SURVEY.md §1):
   libs/       runtime substrate: services, db, wal files, pubsub, bitarray
 """
 
+import os as _os
+import sys as _sys
+
 from tendermint_tpu.version import __version__  # noqa: F401
+
+
+def _place_compile_cache() -> str:
+    """The ONE place the persistent XLA compile cache is placed: where
+    JAX_COMPILATION_CACHE_DIR is set it wins and nothing is set in code;
+    otherwise the fixed ``<checkout>/.jax_cache`` (the directory is part of
+    the cache key, so it is never built from a temp name, a pid or the
+    time).  Runs at package import, before any ``import jax`` of ours; a
+    jax that the embedding program imported first is told directly."""
+    path = _os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = _os.path.join(
+            _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+            ".jax_cache",
+        )
+        _os.environ["JAX_COMPILATION_CACHE_DIR"] = path
+        if "jax" in _sys.modules:
+            _sys.modules["jax"].config.update(
+                "jax_compilation_cache_dir", path)
+    return path
+
+
+_place_compile_cache()

@@ -92,6 +92,7 @@ def cmd_node(args) -> int:
     node = Node(cfg, priv_validator=pv)
     node.start()
     print(f"Node started. RPC: {cfg.rpc.laddr}", flush=True)
+    print(f"Batch verifier: {node.verifier_description}", flush=True)
 
     stop = []
     signal.signal(signal.SIGINT, lambda *a: stop.append(1))

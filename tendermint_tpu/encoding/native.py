@@ -2,21 +2,33 @@
 
 Compiled lazily on first import (cc against the running interpreter's
 headers, cached next to the source, rebuilt when the .c changes); any
-failure falls back to the pure-Python implementation — behavior is
-identical, only the constant factor changes. Set TM_NO_NATIVE_CODEC=1 to
-force the fallback (tests exercise both paths).
+failure falls back to the pure-Python implementation with a logged
+warning — behavior is identical, only the constant factor changes. Set
+TM_NO_NATIVE_CODEC=1 to force the fallback (tests exercise both paths).
+``build_all()`` rebuilds every extension from its committed source and
+raises if the compiler refuses (chip_smoke.py starts with it).
 """
 
 from __future__ import annotations
 
 import importlib.util
+import logging
 import os
 import subprocess
 import sys
 import sysconfig
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
+_PKG = os.path.dirname(_HERE)
 _SOABI = sysconfig.get_config_var("SOABI")
+logger = logging.getLogger("tendermint_tpu.native")
+
+# every extension in the tree: (source relative to the package, ldflags)
+EXTENSIONS = (
+    ("encoding/_codec_native.c", ()),
+    ("crypto/_hash_native.c", ()),
+    ("consensus/_wal_native.c", ("-lz",)),
+)
 
 
 def _build(src: str, so: str, extra_cflags=(), extra_ldflags=()) -> bool:
@@ -32,7 +44,8 @@ def _build(src: str, so: str, extra_cflags=(), extra_ldflags=()) -> bool:
            src, *extra_ldflags, "-o", tmp]
     try:
         res = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-    except Exception:
+    except (OSError, subprocess.TimeoutExpired) as e:
+        sys.stderr.write(f"native build failed ({os.path.basename(src)}): {e}\n")
         return False
     if res.returncode != 0:
         sys.stderr.write(
@@ -53,13 +66,31 @@ def load_ext(src: str, module_name: str, extra_cflags=(), extra_ldflags=()):
     try:
         if not os.path.exists(so) or os.path.getmtime(so) < os.path.getmtime(src):
             if not _build(src, so, extra_cflags, extra_ldflags):
+                logger.warning("%s did not build; pure Python serves instead",
+                               os.path.basename(src))
                 return None
         spec = importlib.util.spec_from_file_location(module_name, so)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         return mod
     except Exception:
+        logger.warning("loading %s failed; pure Python serves instead",
+                       os.path.basename(so), exc_info=True)
         return None
+
+
+def build_all() -> list:
+    """Rebuild every extension from its committed .c file, whatever *.so
+    lies in the tree; raises RuntimeError when the compiler refuses one.
+    Returns the built paths."""
+    built = []
+    for rel, ldflags in EXTENSIONS:
+        src = os.path.join(_PKG, rel)
+        so = os.path.splitext(src)[0] + f".{_SOABI}.so"
+        if not _build(src, so, extra_ldflags=ldflags):
+            raise RuntimeError(f"cc refused {rel} (see stderr)")
+        built.append(so)
+    return built
 
 
 def load():

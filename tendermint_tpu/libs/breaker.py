@@ -6,7 +6,7 @@ into consensus hazards:
 
 - a **crashed/preempted** device raises mid-dispatch — retrying a dead
   chip on every window burns the consensus routine's time budget;
-- a **hung** device (wedged tunnel, stuck DMA) blocks the calling thread
+- a **hung** device (wedged runtime, stuck DMA) blocks the calling thread
   forever — worse than an error, because nothing propagates;
 - a **silently corrupting** device returns wrong verdicts — a safety
   bug, not a perf bug, and must never be retried back into service.
@@ -31,7 +31,11 @@ by running it on a worker thread; a hung call surfaces as
 ``DispatchTimeout`` so the caller can fall back to the host path instead
 of stalling consensus.  The abandoned worker thread is daemonic and left
 to the wedged runtime — there is no safe way to kill it, and the breaker
-ensures we stop handing work to it.
+ensures we stop handing work to it.  Time the worker spends inside
+``compile_grace()`` — tracing, lowering and compiling a program the
+process has not run yet, which is host work and takes 15-40 s per shape
+on a TPU v5e — is not charged against the deadline: a cold first dispatch
+must complete on the device, not lose a race against its own compiler.
 
 Callers (parallel/planner.py, crypto/batch.py GuardedBatchVerifier)
 share one process-wide breaker via ``get_device_breaker()`` — one
@@ -43,8 +47,9 @@ from __future__ import annotations
 
 import threading
 import time
+from contextlib import contextmanager
 from dataclasses import asdict, dataclass
-from typing import Callable, List, Optional
+from typing import Callable, List, Optional, Tuple
 
 # state-machine states; GAUGE value encoding used by
 # tendermint_verify_device_breaker_state (see libs/metrics.py)
@@ -256,13 +261,73 @@ class CircuitBreaker:
 # -- supervised dispatch -------------------------------------------------------
 
 
+# Ceiling on the compile time one supervised call may have exempted.  The
+# largest program in the tree compiles in ~40 s on the v5e host; past ten
+# minutes the compiler itself is wedged and the deadline applies again.
+COMPILE_GRACE_MAX = 600.0
+
+_tls = threading.local()
+
+
+class _Supervision:
+    """Compile-time accounting for one supervised call, shared between the
+    worker (which enters/leaves ``compile_grace``) and the supervisor (which
+    subtracts the exempt seconds from the elapsed time)."""
+
+    def __init__(self, clock: Callable[[], float]):
+        self.clock = clock
+        self._mtx = threading.Lock()
+        self._depth = 0
+        self._since = 0.0
+        self._total = 0.0
+
+    def enter(self) -> None:
+        with self._mtx:
+            if self._depth == 0:
+                self._since = self.clock()
+            self._depth += 1
+
+    def leave(self) -> None:
+        with self._mtx:
+            self._depth -= 1
+            if self._depth == 0:
+                self._total += self.clock() - self._since
+
+    def exempt(self) -> Tuple[float, bool]:
+        """(exempt seconds so far, capped; whether a compile is running)."""
+        with self._mtx:
+            total = self._total
+            if self._depth:
+                total += self.clock() - self._since
+            return min(total, COMPILE_GRACE_MAX), self._depth > 0
+
+
+@contextmanager
+def compile_grace():
+    """Mark the enclosed region as program compilation.  Inside a
+    ``supervised_call`` worker the region's wall time is not charged against
+    the dispatch deadline (up to COMPILE_GRACE_MAX per call); anywhere else
+    it is a no-op.  Callers wrap only the step that traces/lowers/compiles
+    (ops/dispatch.call_jit) — device execution stays on the clock."""
+    sup = getattr(_tls, "supervision", None)
+    if sup is None:
+        yield
+        return
+    sup.enter()
+    try:
+        yield
+    finally:
+        sup.leave()
+
+
 def supervised_call(fn: Callable[[], object], deadline: float,
                     name: str = "device-dispatch"):
     """Run ``fn`` with a wall-clock deadline.
 
     ``deadline <= 0`` disables supervision (direct call).  Otherwise the
     call runs on a daemon worker thread; if it does not finish within
-    ``deadline`` seconds, ``DispatchTimeout`` is raised and the worker is
+    ``deadline`` seconds — not counting time spent under
+    ``compile_grace()`` — ``DispatchTimeout`` is raised and the worker is
     abandoned to the wedged runtime (it cannot be killed safely — the
     breaker's job is to stop sending work its way).
 
@@ -278,10 +343,12 @@ def supervised_call(fn: Callable[[], object], deadline: float,
     win = getattr(_profile._tls, "window", None)
     box: dict = {}
     done = threading.Event()
+    sup = _Supervision(time.monotonic)
 
     def _run():
         if win is not None:
             _profile._tls.window = win
+        _tls.supervision = sup
         try:
             box["result"] = fn()
         except BaseException as e:  # propagate to the supervising thread
@@ -289,12 +356,21 @@ def supervised_call(fn: Callable[[], object], deadline: float,
         finally:
             done.set()
 
+    t0 = time.monotonic()
     t = threading.Thread(target=_run, name=f"supervised-{name}", daemon=True)
     t.start()
-    if not done.wait(deadline):
-        raise DispatchTimeout(
-            f"{name} exceeded {deadline:.3f}s deadline (worker abandoned)"
-        )
+    while True:
+        exempt, compiling = sup.exempt()
+        left = deadline - (time.monotonic() - t0 - exempt)
+        if left <= 0 and not (compiling and exempt < COMPILE_GRACE_MAX):
+            raise DispatchTimeout(
+                f"{name} exceeded {deadline:.3f}s deadline "
+                f"({exempt:.1f}s of compilation exempted; worker abandoned)"
+            )
+        # while a compile runs the budget does not shrink, so poll for its
+        # end; otherwise sleep out exactly what is left of the budget
+        if done.wait(0.05 if compiling else max(left, 0.001)):
+            break
     if "error" in box:
         raise box["error"]
     return box.get("result")

@@ -90,6 +90,11 @@ class Counter(_Metric):
                 del self._values[lv]
         return len(doomed)
 
+    def snapshot(self) -> Dict[Tuple[str, ...], float]:
+        """Current value of every series, keyed by its label values."""
+        with self._mtx:
+            return dict(self._values)
+
     def expose(self) -> List[str]:
         with self._mtx:
             items = sorted(self._values.items())

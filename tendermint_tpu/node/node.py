@@ -190,6 +190,20 @@ class Node(BaseService):
 
         configure_planner(config.verify)
 
+        # the batch verifier is chosen ONCE, here, from the environment
+        # (TM_BATCH_VERIFIER, then jax.devices() under JAX_PLATFORMS) — not
+        # lazily at the first commit.  A [verify] combination the TPU
+        # compiler rejects (fe_backend = mxu on the pallas backend) raises
+        # now, with the reason; a chipless host gets the host verifier and
+        # a logged no_tpu.  cmd/tendermint prints the line beside "Node
+        # started"; /status serves verifier_info().
+        from tendermint_tpu.crypto.batch import (
+            describe_verifier,
+            get_batch_verifier,
+        )
+
+        self.verifier_description = describe_verifier(get_batch_verifier())
+
         if self.metrics is not None:
             # slow-subscriber drop accounting (libs/pubsub.py)
             m = self.metrics
@@ -723,6 +737,8 @@ class Node(BaseService):
 
     # info -------------------------------------------------------------------
     def status(self) -> dict:
+        from tendermint_tpu.crypto.batch import verifier_info
+
         rs = self.consensus_state.get_round_state()
         latest_height = self.block_store.height()
         meta = self.block_store.load_block_meta(latest_height) if latest_height else None
@@ -763,4 +779,9 @@ class Node(BaseService):
                 "round": rs.round,
                 "step": rs.step.name,
             },
+            # which verify backend on which device, with its dispatch /
+            # fallback / audit counters and breaker state — not
+            # unsafe-gated: an operator must be able to tell a device run
+            # from a quiet host run
+            "verifier_info": verifier_info(),
         }

@@ -68,6 +68,7 @@ from jax import lax
 from tendermint_tpu.crypto import ed25519 as _ed
 from tendermint_tpu.ops import ed25519_verify as _xla
 from tendermint_tpu.ops import fe_common as _fc
+from tendermint_tpu.ops.dispatch import call_jit
 
 P = _ed.P
 L = _ed.L
@@ -371,8 +372,8 @@ def _device_rlc(rows, rng, fe_backend: str, carry_mode: str) -> bool:
         [(s_b >> (4 * (_SB_WIN - 1 - t))) & 15 for t in range(_SB_WIN)],
         np.uint32,
     )
-    fn = _compiled_msm(fe_backend, carry_mode)
-    ok = fn(
+    ok = call_jit(
+        _compiled_msm(fe_backend, carry_mode),
         jnp.asarray(pool),
         [jnp.asarray(a) for a in sched.ias],
         [jnp.asarray(b) for b in sched.ibs],

@@ -49,6 +49,7 @@ from jax import lax
 
 from tendermint_tpu.crypto import ed25519 as _ed
 from tendermint_tpu.ops import fe_common as _fc
+from tendermint_tpu.ops.dispatch import call_jit
 
 P = _ed.P
 L = _ed.L
@@ -559,7 +560,9 @@ def verify_batch(
 
         data = NamedSharding(mesh, PS(mesh.axis_names[0]))
         args = [jax.device_put(a, data) for a in args]
-    ok = np.asarray(_compiled_kernel(b, mesh, fe_backend, carry_mode)(*args))[:n]
+    ok = np.asarray(
+        call_jit(_compiled_kernel(b, mesh, fe_backend, carry_mode), *args)
+    )[:n]
     return ok & valid
 
 

@@ -71,6 +71,7 @@ _K_SUB = _xla._K_SUB
 # ---------------------------------------------------------------------------
 
 from tendermint_tpu.ops import fe_common as _fc
+from tendermint_tpu.ops.dispatch import call_jit
 
 _FE = {(b, "eager"): _fc.make_fe("secp256k1", b) for b in _fc.FE_BACKENDS}
 _FE_VPU = _FE[("vpu", "eager")]
@@ -361,7 +362,6 @@ def verify_batch(
     digests: Sequence[bytes],
     sigs: Sequence[bytes],
     interpret: bool = False,
-    device=None,
     fe_backend: str = "vpu",
     carry_mode: str = "lazy",
 ) -> np.ndarray:
@@ -401,9 +401,9 @@ def verify_batch(
             rnl[i] = int_to_limbs(r + N)
             rnok[i] = 1
 
-    put = (lambda a: jax.device_put(a, device)) if device is not None else jnp.asarray
-    args = [put(np.ascontiguousarray(a.T)) for a in (qx, qy, d1, d2, rl, rnl)]
-    args.append(put(rnok[None, :]))
+    args = [jnp.asarray(np.ascontiguousarray(a.T))
+            for a in (qx, qy, d1, d2, rl, rnl)]
+    args.append(jnp.asarray(rnok[None, :]))
     if interpret:
         ok = np.asarray(
             _ladder_call(*args, interpret=True, lanes=lanes,
@@ -411,8 +411,8 @@ def verify_batch(
         )[0, :n]
     else:
         ok = np.asarray(
-            _ladder_jit(*args, lanes=lanes, fe_backend=fe_backend,
-                        carry_mode=carry_mode)
+            call_jit(_ladder_jit, *args, lanes=lanes, fe_backend=fe_backend,
+                     carry_mode=carry_mode)
         )[0, :n]
 
     f = forced[:n]

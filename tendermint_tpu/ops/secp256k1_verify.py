@@ -35,6 +35,7 @@ from jax import lax
 
 from tendermint_tpu.crypto import secp256k1 as _s
 from tendermint_tpu.ops import fe_common as _fc
+from tendermint_tpu.ops.dispatch import call_jit
 
 P = _s.P
 N = _s.N
@@ -517,7 +518,7 @@ def verify_batch(
         args = [jax.device_put(a, sh) for a in host]
     else:
         args = [jnp.asarray(a) for a in host]
-    ok = np.asarray(kernel(*args))[:n]
+    ok = np.asarray(call_jit(kernel, *args))[:n]
 
     f = forced[:n]
     return np.where(f >= 0, f.astype(bool), ok)

@@ -30,13 +30,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-# jax.enable_x64 is only a public re-export on some versions; the
-# experimental spelling is the one that exists everywhere we run
-try:
-    _enable_x64 = jax.enable_x64
-except AttributeError:
-    from jax.experimental import enable_x64 as _enable_x64
-
 from tendermint_tpu.libs import trace
 from tendermint_tpu.libs.metrics import get_verify_metrics
 from tendermint_tpu.libs.profile import get_profiler
@@ -443,7 +436,7 @@ def _verify_window_device(
     n = int(np.count_nonzero(win.present))
     t0 = time.perf_counter()
     with trace.span("verify.window_dispatch", backend=backend, H=H, V=V, n=n):
-        with _enable_x64(True):
+        with jax.enable_x64(True):
             if mesh is not None:
                 from jax.sharding import NamedSharding, PartitionSpec as PS
 

@@ -59,6 +59,7 @@ no quorum math lives in the callers anymore.
 
 from __future__ import annotations
 
+import logging
 import math
 import queue
 import random
@@ -75,6 +76,8 @@ from tendermint_tpu.libs.profile import get_profiler
 
 # (pubkey: PubKey object or raw 32-byte ed25519 key, msg, sig) or None
 SigTuple = Tuple[object, bytes, bytes]
+
+logger = logging.getLogger("tendermint_tpu.verify")
 
 MIN_LANES = 64  # smallest lane bucket (matches ops/ed25519_verify._bucket)
 MAX_POW2_LANES = 4096  # above this, buckets are multiples of 4096
@@ -645,7 +648,9 @@ def _execute_device_msm(plan: WindowPlan, mesh=None) -> WindowVerdict:
 
 
 def _execute_device(plan: WindowPlan, mesh=None) -> WindowVerdict:
-    from tendermint_tpu.parallel.commit_verify import _enable_x64
+    import jax
+
+    from tendermint_tpu.ops.dispatch import call_jit
     from tendermint_tpu.crypto.batch import (
         _resolve_ed25519_path,
         _resolve_fe_backend,
@@ -670,10 +675,9 @@ def _execute_device(plan: WindowPlan, mesh=None) -> WindowVerdict:
     ):
         # int64 powers: same consensus-safety reasoning as commit_verify —
         # without x64 the tally silently wraps at 2^31
-        with _enable_x64(True):
+        with jax.enable_x64(True):
             arrs = plan.dev
             if mesh is not None:
-                import jax
                 from jax.sharding import NamedSharding, PartitionSpec as PS
 
                 lane = NamedSharding(mesh, PS(tuple(mesh.axis_names)))
@@ -682,10 +686,10 @@ def _execute_device(plan: WindowPlan, mesh=None) -> WindowVerdict:
                     jax.device_put(arrs[-1], rep)
                 ]
             if reduce == "host":
-                ok_l = np.asarray(fn(*arrs))[:n]
+                ok_l = np.asarray(call_jit(fn, *arrs))[:n]
                 tally, committed, nbad = _host_reduce(plan, ok_l)
             else:
-                ok_l, tally, committed, nbad = fn(*arrs)
+                ok_l, tally, committed, nbad = call_jit(fn, *arrs)
                 ok_l = np.asarray(ok_l)[:n]
                 tally = np.asarray(tally)[: plan.H]
                 committed = np.asarray(committed)[: plan.H]
@@ -709,8 +713,6 @@ def _execute_device(plan: WindowPlan, mesh=None) -> WindowVerdict:
             m.record_device_shards(
                 (d.id for d in mesh.devices.flat), B // n_devices)
         else:
-            import jax
-
             m.record_device_shards((jax.devices()[0].id,), B)
         get_profiler().record(
             backend,
@@ -834,6 +836,11 @@ def set_device_executor(fn=None) -> None:
 
 
 def _note_device_fallback(reason: str, plan: WindowPlan) -> None:
+    # every host completion of a device dispatch is said out loud
+    logger.warning(
+        "planner dispatch completed on the host: reason=%s heights=%d "
+        "lanes=%d", reason, plan.H, plan.n_lanes,
+    )
     try:
         get_verify_metrics().device_fallback.add(1.0, (reason,))
     except Exception:

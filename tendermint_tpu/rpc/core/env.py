@@ -743,9 +743,8 @@ class RPCEnv:
         """Operator reset of the device circuit breaker — the ONLY way out
         of the quarantined state (a device that disagreed with the host
         oracle must not be re-admitted by timers).  reprobe=true also drops
-        the lazy default verifier and the TPU liveness cache so device
-        selection reruns from scratch (pays a full probe timeout if the
-        device is still dead)."""
+        the default verifier so device selection reruns from scratch in
+        this process (jax.devices() under JAX_PLATFORMS)."""
         self._require_unsafe()
         from tendermint_tpu.crypto import batch as _batch
         from tendermint_tpu.libs.breaker import get_device_breaker

@@ -304,10 +304,10 @@ def build_sim_net(
     ``{3: lambda pv: EquivocatingPV(pv)}``.  Returns (fabric, nodes);
     neither is started."""
     # Pin the commit verifier to the host backend before the first commit
-    # verify: the lazy default runs a TPU subprocess liveness probe under the
-    # process-wide verifier lock (tens of seconds on a CPU host), which
-    # blocks every node's receive routine mid-consensus and forces
-    # timeout-driven round bumps that destroy run-to-run hash determinism.
+    # verify: default selection imports jax and discovers devices under the
+    # process-wide verifier lock (seconds on a CPU host), which blocks every
+    # node's receive routine mid-consensus and forces timeout-driven round
+    # bumps that destroy run-to-run hash determinism.
     # An explicit TM_BATCH_VERIFIER or an already-installed verifier wins.
     import os
 

@@ -138,3 +138,61 @@ def build_chain(
         block_store=block_store,
         height=n_heights,
     )
+
+
+def fresh_executor(genesis: GenesisDoc):
+    """(genesis state, BlockExecutor over a fresh in-memory kvstore app) —
+    the replay side of a fast-sync bench or smoke."""
+    st = state_from_genesis(genesis)
+    db = MemDB()
+    sm_store.save_state(db, st)
+    conn = MultiAppConn(LocalClientCreator(KVStoreApp()))
+    conn.start()
+    return st, BlockExecutor(db, conn.consensus)
+
+
+def build_commit(
+    n_validators: int,
+    seed: int = 42,
+    chain_id: str = "bench-chain",
+    height: int = 500,
+):
+    """(valset, block_id, commit): one real Commit over ``n_validators``
+    seeded ed25519 validators, each precommit's canonical sign-bytes
+    differing only in its fixed64 timestamp (as in production).  The
+    headline bench (bench.py) and chip_smoke.py verify this commit."""
+    from dataclasses import replace
+
+    import numpy as np
+
+    from tendermint_tpu.crypto import ed25519 as ed
+    from tendermint_tpu.crypto.keys import PubKeyEd25519
+    from tendermint_tpu.types.core import PartSetHeader
+    from tendermint_tpu.types.validator_set import Validator, ValidatorSet
+
+    seeds = np.random.default_rng(seed).bytes(32 * n_validators)
+    block_id = BlockID(b"\xaa" * 32, PartSetHeader(1, b"\xbb" * 32))
+    vals, votes = [], {}
+    for i in range(n_validators):
+        priv = ed.gen_privkey(seeds[32 * i : 32 * (i + 1)])
+        pub = PubKeyEd25519(priv[32:])
+        vals.append(Validator(pub, 10))
+        vote = Vote(
+            vote_type=SignedMsgType.PRECOMMIT,
+            height=height,
+            round=0,
+            timestamp_ns=1_700_000_000_000_000_000 + i * 1_000,
+            block_id=block_id,
+            validator_address=pub.address(),
+            validator_index=i,
+        )
+        votes[pub.address()] = vote.with_signature(
+            ed.sign(priv, vote.sign_bytes(chain_id))
+        )
+    # ValidatorSet sorts by (power, address): precommits go in set order
+    valset = ValidatorSet(vals)
+    ordered = [
+        replace(votes[val.address], validator_index=i)
+        for i, val in enumerate(valset.validators)
+    ]
+    return valset, block_id, Commit(block_id, ordered)

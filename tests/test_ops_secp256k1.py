@@ -107,14 +107,6 @@ class TestKernelParity:
         assert list(got) == [True] * 3 + [False] + [True] * 4
 
 
-try:
-    import jax as _jax
-
-    _TPU = _jax.devices("tpu")[0]
-except Exception:
-    _TPU = None
-
-
 class TestPallasPipeline:
     """The fused windowed-Straus pallas path (ops/secp256k1_pallas)."""
 
@@ -221,18 +213,6 @@ class TestPallasPipeline:
                 or (rs[i] + K.N < K.P and x_aff == rs[i] + K.N)
             )
         assert got == want
-
-    @pytest.mark.skipif(_TPU is None, reason="needs the real chip")
-    def test_pallas_matches_oracle_on_tpu(self):
-        from tendermint_tpu.ops import secp256k1_pallas as sp
-
-        pubs, digs, sigs = _fixture(40)
-        r, sv = s.der_decode_sig(sigs[7])
-        sigs[7] = s.der_encode_sig(r, sv ^ 1)
-        digs[11] = sha256(b"not the signed digest")
-        got = sp.verify_batch(pubs, digs, sigs, device=_TPU)
-        want = [s.verify(pubs[i], digs[i], sigs[i]) for i in range(40)]
-        assert list(got) == want
 
     @pytest.mark.slow
     @pytest.mark.skipif(
