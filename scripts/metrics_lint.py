@@ -466,6 +466,11 @@ def _self_check():
         # capped `device` label, excess ids fold into "overflow")
         "tendermint_verify_device_lanes_total",
         "tendermint_verify_device_dispatch_total",
+        # where the host's share of a dispatch goes, tracing off: the
+        # audit's seconds, the Pallas valset caches, the sync loop's looks
+        "tendermint_verify_device_audit_seconds",
+        "tendermint_verify_valset_cache_total",
+        "tendermint_verify_sync_ticks_total",
     )
     verify_text = vm.registry.expose_text()
     missing_dev = [

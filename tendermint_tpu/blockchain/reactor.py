@@ -121,34 +121,37 @@ def verify_block_window(
     votes_rows: List[list] = []
     power_rows: List[list] = []
     local_parts: List = []
-    for i in range(n):
-        block, next_block = blocks[i], blocks[i + 1]
-        if block.header.validators_hash != valset.hash():
-            if i == 0:
-                # offset 0 is always OUR current valset; a mismatch there is
-                # a bad block, not a future valset — punishable, else the
-                # same block livelocks the sync loop forever
-                structural = WindowVerifyError(0, "wrong validators_hash")
-            break  # valset changed: verify the rest after applying up to here
-        commit = next_block.last_commit
-        parts = block.make_part_set()
-        block_id = BlockID(hash=block.hash(), parts_header=parts.header())
-        try:
-            # the ONE home of the per-precommit rules; its aligned outputs
-            # (non-nil precommits in index order) feed the planner row
-            pubkeys, msgs, sigs, powers = valset.collect_commit_sigs(
-                chain_id, block_id, block.height, commit
+    # one span for the loop: per window, never per block
+    with trace.span("fastsync.precheck", n=n) as sp:
+        for i in range(n):
+            block, next_block = blocks[i], blocks[i + 1]
+            if block.header.validators_hash != valset.hash():
+                if i == 0:
+                    # offset 0 is always OUR current valset; a mismatch there
+                    # is a bad block, not a future valset — punishable, else
+                    # the same block livelocks the sync loop forever
+                    structural = WindowVerifyError(0, "wrong validators_hash")
+                break  # valset changed: verify the rest after applying these
+            commit = next_block.last_commit
+            parts = block.make_part_set()
+            block_id = BlockID(hash=block.hash(), parts_header=parts.header())
+            try:
+                # the ONE home of the per-precommit rules; its aligned outputs
+                # (non-nil precommits in index order) feed the planner row
+                pubkeys, msgs, sigs, powers = valset.collect_commit_sigs(
+                    chain_id, block_id, block.height, commit
+                )
+            except CommitError as e:
+                structural = WindowVerifyError(i, str(e))
+                break
+            vrow, prow = planner.rows_from_commit(
+                commit.precommits, pubkeys, msgs, sigs, powers
             )
-        except CommitError as e:
-            structural = WindowVerifyError(i, str(e))
-            break
-        vrow, prow = planner.rows_from_commit(
-            commit.precommits, pubkeys, msgs, sigs, powers
-        )
-        votes_rows.append(vrow)
-        power_rows.append(prow)
-        local_parts.append(parts)
-        usable += 1
+            votes_rows.append(vrow)
+            power_rows.append(prow)
+            local_parts.append(parts)
+            usable += 1
+        sp.set(n=usable)
 
     if usable == 0:
         return 0, structural
@@ -381,13 +384,17 @@ class BlockchainReactor(Reactor):
         """Cancel-or-drain invalidated slots.  A running verify must drain —
         letting it race a fresh synchronous verify would double-dispatch
         its window through the device."""
-        for _, _, fut, _, _ in slots:
-            get_verify_metrics().speculative.add(1.0, ("miss",))
-            if not fut.cancel():
-                try:
-                    fut.result()
-                except BaseException:
-                    pass
+        with trace.span(
+            "fastsync.discard", slots=len(slots),
+            heights=sum(len(blocks) - 1 for *_, blocks in slots),
+        ):
+            for _, _, fut, _, _ in slots:
+                get_verify_metrics().speculative.add(1.0, ("miss",))
+                if not fut.cancel():
+                    try:
+                        fut.result()
+                    except BaseException:
+                        pass
 
     def _take_speculative(self) -> Optional[tuple]:
         """Harvest the in-flight window N+1 verification, if it still
@@ -405,12 +412,15 @@ class BlockchainReactor(Reactor):
             rest, self._spec = self._spec, []
             self._discard_speculation([head] + rest)
             return None
-        try:
-            n_ok, err = fut.result()
-        except CancelledError:
-            # on_stop cancelled the slot from another thread mid-harvest
-            get_verify_metrics().speculative.add(1.0, ("miss",))
-            return None
+        # how long the apply loop stood waiting for the bc-verify worker
+        with trace.span("fastsync.harvest", h0=first_h, hit=False) as sp:
+            try:
+                n_ok, err = fut.result()
+            except CancelledError:
+                # on_stop cancelled the slot from another thread mid-harvest
+                get_verify_metrics().speculative.add(1.0, ("miss",))
+                return None
+            sp.set(hit=True)
         get_verify_metrics().speculative.add(1.0, ("hit",))
         return blocks, parts_list, n_ok, err
 
@@ -463,13 +473,17 @@ class BlockchainReactor(Reactor):
                 (nxt[0].height, st.validators.hash(), fut, parts_list, nxt))
 
     def _try_sync_window(self) -> None:
+        ticks = get_verify_metrics().sync_ticks
         spec = self._take_speculative()
         if spec is not None:
+            ticks.add(1.0, ("harvest",))
             blocks, parts_list, n_ok, err = spec
         else:
             blocks = self.pool.peek_window(self.verify_window + 1)
             if len(blocks) < 2:
+                ticks.add(1.0, ("empty",))
                 return
+            ticks.add(1.0, ("window",))
             parts_list = []
             with trace.span(
                 "fastsync.window", h0=blocks[0].height, n=len(blocks) - 1,

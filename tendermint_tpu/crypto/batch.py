@@ -469,43 +469,47 @@ class GuardedBatchVerifier:
             return np.zeros((0,), dtype=bool)
         from tendermint_tpu.libs import breaker as _brk
 
-        br = self.breaker
-        if not br.allow():
-            reason = (
-                "quarantined" if br.state == _brk.QUARANTINED
-                else "breaker_open"
-            )
-            self._note_fallback(reason, algo, n)
-            return np.asarray(host_call(), dtype=bool)
-        attempts = 0
-        while True:
-            try:
-                ok = _brk.supervised_call(
-                    dev_call, self.deadline, name=f"batch-{algo}"
+        # guard.call's own time (less verify.dispatch and guard.audit under
+        # it) is the worker thread's spawn, the join and the bookkeeping
+        with trace.span("guard.call", algo=algo, n=n, attempts=0) as sp:
+            br = self.breaker
+            if not br.allow():
+                reason = (
+                    "quarantined" if br.state == _brk.QUARANTINED
+                    else "breaker_open"
                 )
-                ok = np.asarray(ok, dtype=bool)
-            except Exception as e:
-                timeout = isinstance(e, _brk.DispatchTimeout)
-                reason = "timeout" if timeout else "error"
-                br.record_failure(reason)
-                attempts += 1
-                if attempts <= self.retries and br.allow():
-                    try:
-                        get_verify_metrics().device_retries.add(1.0)
-                    except Exception:
-                        pass
-                    continue
                 self._note_fallback(reason, algo, n)
                 return np.asarray(host_call(), dtype=bool)
-            if self._audit(algo, n, ok, oracle):
-                # the device disagrees with the host oracle: safety bug.
-                # Quarantine (latched) and recompute the WHOLE window on
-                # the host — the sampled lanes say nothing about the rest.
-                br.quarantine(f"audit_mismatch:{algo}")
-                self._note_fallback("audit_mismatch", algo, n)
-                return np.asarray(host_call(), dtype=bool)
-            br.record_success()
-            return ok
+            attempts = 0
+            while True:
+                sp.set(attempts=attempts + 1)
+                try:
+                    ok = _brk.supervised_call(
+                        dev_call, self.deadline, name=f"batch-{algo}"
+                    )
+                    ok = np.asarray(ok, dtype=bool)
+                except Exception as e:
+                    timeout = isinstance(e, _brk.DispatchTimeout)
+                    reason = "timeout" if timeout else "error"
+                    br.record_failure(reason)
+                    attempts += 1
+                    if attempts <= self.retries and br.allow():
+                        try:
+                            get_verify_metrics().device_retries.add(1.0)
+                        except Exception:
+                            pass
+                        continue
+                    self._note_fallback(reason, algo, n)
+                    return np.asarray(host_call(), dtype=bool)
+                if self._audit(algo, n, ok, oracle):
+                    # the device disagrees with the host oracle: safety bug.
+                    # Quarantine (latched) and recompute the WHOLE window on
+                    # the host — the sampled lanes say nothing about the rest.
+                    br.quarantine(f"audit_mismatch:{algo}")
+                    self._note_fallback("audit_mismatch", algo, n)
+                    return np.asarray(host_call(), dtype=bool)
+                br.record_success()
+                return ok
 
     def _audit(self, algo, n, ok, oracle) -> bool:
         """Cross-check k seeded-sampled lanes against the host oracle.
@@ -520,11 +524,15 @@ class GuardedBatchVerifier:
             seq = self._dispatches
             self._dispatches += 1
         k = min(n, max(1, int(math.ceil(n * rate))))
-        rng = random.Random((self.audit_seed << 20) ^ seq)
-        lanes = rng.sample(range(n), k)
-        bad = [i for i in lanes if bool(ok[i]) != bool(oracle(i))]
+        t0 = time.perf_counter()
+        with trace.span("guard.audit", sampled=k) as sp:
+            rng = random.Random((self.audit_seed << 20) ^ seq)
+            lanes = rng.sample(range(n), k)
+            bad = [i for i in lanes if bool(ok[i]) != bool(oracle(i))]
+            sp.set(mismatches=len(bad))
         try:
             m = get_verify_metrics()
+            m.device_audit_seconds.observe(time.perf_counter() - t0)
             if len(lanes) - len(bad):
                 m.device_audit.add(float(len(lanes) - len(bad)), ("ok",))
             if bad:
@@ -794,6 +802,13 @@ def verify_generic(
     ed25519 batch (every flagged signer's sub-signature rides the same
     device dispatch — ref threshold_pubkey.go:41-55 loops serially); only
     structurally odd items fall back to host verify_bytes."""
+    # the span's own time is the key-type scan and the column lists; the
+    # verifier's spans (guard.call or verify.dispatch) are its children
+    with trace.span("verify.generic", n=len(pubkeys)):
+        return _verify_generic(pubkeys, msgs, sigs, verifier)
+
+
+def _verify_generic(pubkeys, msgs, sigs, verifier) -> np.ndarray:
     from tendermint_tpu.crypto.keys import PubKeySecp256k1
     from tendermint_tpu.crypto.multisig import PubKeyMultisigThreshold
 

@@ -51,6 +51,8 @@ from contextlib import contextmanager
 from dataclasses import asdict, dataclass
 from typing import Callable, List, Optional, Tuple
 
+from tendermint_tpu.libs import trace as _trace
+
 # state-machine states; GAUGE value encoding used by
 # tendermint_verify_device_breaker_state (see libs/metrics.py)
 CLOSED = "closed"
@@ -333,7 +335,8 @@ def supervised_call(fn: Callable[[], object], deadline: float,
 
     The caller's profiler window annotation (libs/profile.py is
     thread-local) is propagated into the worker so ledger rows still fold
-    into the right per-height group.
+    into the right per-height group, and so is the caller's open trace span
+    (libs/trace.py), so the worker's spans are its children.
     """
     if deadline is None or deadline <= 0:
         return fn()
@@ -341,6 +344,7 @@ def supervised_call(fn: Callable[[], object], deadline: float,
     from tendermint_tpu.libs import profile as _profile
 
     win = getattr(_profile._tls, "window", None)
+    span = _trace.current()
     box: dict = {}
     done = threading.Event()
     sup = _Supervision(time.monotonic)
@@ -348,6 +352,7 @@ def supervised_call(fn: Callable[[], object], deadline: float,
     def _run():
         if win is not None:
             _profile._tls.window = win
+        _trace.adopt(span)
         _tls.supervision = sup
         try:
             box["result"] = fn()

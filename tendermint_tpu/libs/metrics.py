@@ -388,6 +388,28 @@ class VerifyMetrics:
             "(ok|mismatch)",
             label_names=("outcome",),
         )
+        self.device_audit_seconds = r.histogram(
+            "verify_device_audit_seconds",
+            "Silent-corruption audit wall seconds per device dispatch "
+            "(the sampled lanes re-verified one by one on the host)",
+        )
+        # whole-valset caches of the Pallas path (ops/ed25519_pallas): the
+        # decompressed limbs on the host and their padded copies on the
+        # device, each keyed by the dispatch's whole pubkey array
+        self.valset_cache = r.counter(
+            "verify_valset_cache_total",
+            "Pallas valset cache lookups by cache (host|device) and result "
+            "(hit|miss)",
+            label_names=("cache", "result"),
+        )
+        # how each look of the fast-sync loop ended (blockchain/reactor
+        # _try_sync_window); one TRY_SYNC_INTERVAL sleep follows each
+        self.sync_ticks = r.counter(
+            "verify_sync_ticks_total",
+            "Fast-sync loop looks by how they ended: window (verified in "
+            "line), harvest (took a speculation), empty (under two blocks)",
+            label_names=("result",),
+        )
         # limb-multiplier attribution: which fe backend (ops/fe_common)
         # served each device window — vpu | mxu | mxu16 — which carry
         # schedule it traced with (eager | lazy), and which verify

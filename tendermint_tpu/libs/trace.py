@@ -19,16 +19,38 @@ the environment, trace.enable(), or the `trace_reset` RPC; export with the
 
 The buffer is a fixed-size ring: recording never blocks on a consumer and
 never grows memory — old spans are overwritten (dropped() counts them).
+
+Identity.  Every recorded span carries three ints in its exported ``args``:
+``span_id`` (unique in the process), ``parent_id`` (the span open on the same
+thread when it was entered, else None) and ``root_id`` (its top ancestor's
+id: every span of one verify_commit call, or of one fast-sync window, shares
+it).  The parent comes from a thread-local stack of open spans, touched only
+while the tracer is enabled.  Work handed to another thread keeps its place
+in the tree through a handle:
+
+    handle = trace.current()            # None when disabled or nothing open
+    # ... on the other thread:
+    trace.adopt(handle)                 # spans opened here are its children
+
+Args known only at exit go through the span: ``with trace.span(..) as sp:
+... sp.set(hit=True)``.
+
+Rule for call sites: a span is per call, per dispatch or per window — never
+per block, per lane or per signature — and its kwargs are O(1) to compute
+(``len(x)``, an int already in hand).  A fast-sync window applies thousands
+of blocks and the benchmark's ring holds 65,536 records.
 """
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
 from typing import List, Optional
 
 _now_ns = time.perf_counter_ns
+_ids = itertools.count(1)  # next() is atomic under the GIL
 
 DEFAULT_CAPACITY = 8192
 
@@ -44,12 +66,18 @@ class _NoopSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    """One open span; also the handle ``current()`` hands to other threads."""
+
+    __slots__ = ("_tracer", "name", "args", "_t0",
+                 "span_id", "parent_id", "root_id")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
@@ -57,12 +85,32 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
+        stack = self._tracer._stack()
+        self.span_id = next(_ids)
+        if stack:
+            parent = stack[-1]
+            self.parent_id = parent.span_id
+            self.root_id = parent.root_id
+        else:
+            self.parent_id = None
+            self.root_id = self.span_id
+        stack.append(self)
         self._t0 = _now_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        self._tracer.record(self.name, self._t0, _now_ns(), self.args)
+        t1 = _now_ns()
+        stack = self._tracer._stack()
+        if stack and stack[-1] is self:  # not so after an adopt() under it
+            stack.pop()
+        self.args.update(span_id=self.span_id, parent_id=self.parent_id,
+                         root_id=self.root_id)
+        self._tracer.record(self.name, self._t0, t1, self.args)
         return False
+
+    def set(self, **args) -> None:
+        """Args known only at exit (a verdict, a count)."""
+        self.args.update(args)
 
 
 class Tracer:
@@ -71,6 +119,7 @@ class Tracer:
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY):
         self._mtx = threading.Lock()
+        self._tls = threading.local()  # .stack: this thread's open spans
         self.enabled = False
         self._configure(capacity)
 
@@ -106,10 +155,32 @@ class Tracer:
             return min(self._next, self.capacity)
 
     # recording -------------------------------------------------------------
+    def _stack(self) -> list:
+        try:
+            return self._tls.stack
+        except AttributeError:
+            stack = self._tls.stack = []
+            return stack
+
     def span(self, name: str, **args) -> object:
         if not self.enabled:
             return _NOOP
         return _Span(self, name, args)
+
+    def current(self) -> Optional[_Span]:
+        """The innermost span open on this thread, as a handle another
+        thread can adopt; None when disabled or nothing is open."""
+        if not self.enabled:
+            return None
+        stack = self._stack()
+        return stack[-1] if stack else None
+
+    def adopt(self, handle: Optional[_Span]) -> None:
+        """Make ``handle`` (another thread's ``current()``) the parent of
+        the spans this thread opens from now on.  For a thread made for one
+        hand-off; None (tracing was off at the hand-off) does nothing."""
+        if handle is not None and self.enabled:
+            self._tls.stack = [handle]
 
     def instant(self, name: str, **args) -> None:
         if not self.enabled:
@@ -220,6 +291,14 @@ def span(name: str, **args) -> object:
     if not _tracer.enabled:
         return _NOOP
     return _Span(_tracer, name, args)
+
+
+def current() -> Optional[_Span]:
+    return _tracer.current()
+
+
+def adopt(handle: Optional[_Span]) -> None:
+    _tracer.adopt(handle)
 
 
 def instant(name: str, **args) -> None:

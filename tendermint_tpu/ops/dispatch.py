@@ -26,6 +26,7 @@ from typing import Optional
 import jax
 from jax import monitoring
 
+from tendermint_tpu.libs import trace
 from tendermint_tpu.libs.breaker import compile_grace
 
 _mtx = threading.Lock()
@@ -98,9 +99,18 @@ def call_jit(fn, *args, **static):
     if seen:
         return fn(*args, **static)
     t0 = time.monotonic()
-    with compile_grace():
-        out = fn(*args, **static)
+    # names the program when something traces, lowers or compiles where a
+    # steady state was expected; lanes is the leading dimension (the bucket)
+    shape = getattr(args[0], "shape", ()) if args else ()
+    with trace.span(
+        "jit.first_call", fn=getattr(fn, "__name__", type(fn).__name__),
+        lanes=int(shape[0]) if shape else 0,
+    ) as sp:
+        with compile_grace():
+            out = fn(*args, **static)
+        seconds = time.monotonic() - t0
+        sp.set(seconds=round(seconds, 3))
     with _mtx:
         _seen.add(key)
-        _stats["compile_seconds"] += time.monotonic() - t0
+        _stats["compile_seconds"] += seconds
     return out

@@ -54,6 +54,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tendermint_tpu.crypto import ed25519 as _ed
+from tendermint_tpu.libs import trace
+from tendermint_tpu.libs.metrics import get_verify_metrics
 from tendermint_tpu.ops import ed25519_verify as _xla
 from tendermint_tpu.ops import fe_common as _fc
 from tendermint_tpu.ops.dispatch import call_jit
@@ -741,6 +743,8 @@ def _decompress_valset(pubs: np.ndarray) -> Tuple[np.ndarray, np.ndarray, np.nda
     verification hits the same validator-set array every height."""
     key = hashlib.sha256(pubs.tobytes()).digest()
     hit = _valset_cache.get(key)
+    get_verify_metrics().valset_cache.add(
+        1.0, ("host", "miss" if hit is None else "hit"))
     if hit is not None:
         return hit
     n = pubs.shape[0]
@@ -779,6 +783,8 @@ def _upload_valset(pubs, neg_ax, ay, b):
     uploaded again."""
     key = (hashlib.sha256(pubs.tobytes()).digest(), b)
     hit = _dev_valset_cache.get(key)
+    get_verify_metrics().valset_cache.add(
+        1.0, ("device", "miss" if hit is None else "hit"))
     if hit is not None:
         return hit
     pub_words = np.ascontiguousarray(pubs).view("<u4").astype(np.uint32)
@@ -824,18 +830,22 @@ def verify_batch(pubs: np.ndarray, msgs: Sequence[bytes], sigs: np.ndarray,
     if n == 0:
         return np.zeros((0,), dtype=bool)
 
-    neg_ax, ay, valid = _decompress_valset(pubs)
-    valid = valid & ((sigs[:, 63] & 224) == 0)  # Go's only s range check
-
-    lens = np.array([len(m) for m in msgs]) if msgs else np.zeros((0,), int)
+    # valset limbs and the lanes grouped by message length (one group for a
+    # commit), before any launch: the host work that is not packing
+    with trace.span("dispatch.prepare", n=n):
+        neg_ax, ay, valid = _decompress_valset(pubs)
+        valid = valid & ((sigs[:, 63] & 224) == 0)  # Go's only s range check
+        lens = np.array([len(m) for m in msgs]) if msgs else np.zeros((0,), int)
+        groups = []
+        for ln in np.unique(lens):
+            idx = np.nonzero(lens == ln)[0]
+            groups.append((idx, (
+                pubs[idx], [msgs[i] for i in idx], sigs[idx],
+                neg_ax[idx], ay[idx], valid[idx], int(ln),
+            )))
     out = np.zeros((n,), dtype=bool)
-    for ln in np.unique(lens):
-        idx = np.nonzero(lens == ln)[0]
-        out[idx] = _verify_uniform(
-            pubs[idx], [msgs[i] for i in idx], sigs[idx],
-            neg_ax[idx], ay[idx], valid[idx], int(ln), interpret,
-            fe_backend, carry_mode,
-        )
+    for idx, cols in groups:
+        out[idx] = _verify_uniform(*cols, interpret, fe_backend, carry_mode)
     return out
 
 
@@ -976,6 +986,14 @@ def pack_variable_words(pubs, msgs, sigs, ln: int, b: int):
     return tmpl, vrows, vwords
 
 
+def _sig_words(sigs, valid) -> np.ndarray:
+    """(n, 64) signature bytes as (n, 16) LE words, a fresh array; invalid
+    rows' scalars zeroed to keep device work defined."""
+    sig_words = np.ascontiguousarray(sigs).view("<u4").astype(np.uint32)
+    sig_words[~valid] = 0
+    return sig_words
+
+
 def _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln, interpret,
                     fe_backend="vpu", carry_mode="lazy"):
     n = pubs.shape[0]
@@ -987,28 +1005,29 @@ def _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln, interpret,
     nblocks = (total + 1 + 16 + 127) // 128
     rows = nblocks * 32
 
-    sig_words = np.ascontiguousarray(sigs).view("<u4").astype(np.uint32)
-    # zero invalid rows' scalars to keep device work defined
-    sig_words = sig_words.copy()
-    sig_words[~valid] = 0
-
     if not interpret:
         # packed path: ship only signatures + the message words that actually
-        # vary across the batch; everything else is device-cached or template
-        tmpl, vrows, vwords = pack_variable_words(pubs, msgs, sigs, ln, b)
-        negax_d, ay_d, pubw_d = _upload_valset(pubs, neg_ax, ay, b)
-        ok = np.asarray(
-            call_jit(
+        # vary across the batch; everything else is device-cached or template.
+        # One device launch; its three spans split the host's share of it
+        with trace.span("dispatch.pack", n=n, lanes=b):
+            sig_words = _pad_rows(_sig_words(sigs, valid), b)
+            tmpl, vrows, vwords = pack_variable_words(pubs, msgs, sigs, ln, b)
+        with trace.span("dispatch.launch", lanes=b):  # copies in + enqueue
+            negax_d, ay_d, pubw_d = _upload_valset(pubs, neg_ax, ay, b)
+            out = call_jit(
                 _device_verify_packed,
                 negax_d, ay_d, pubw_d,
-                jnp.asarray(_pad_rows(sig_words, b)),
+                jnp.asarray(sig_words),
                 jnp.asarray(tmpl), jnp.asarray(vrows), jnp.asarray(vwords),
                 lanes=lanes, fe_backend=fe_backend, carry_mode=carry_mode,
             )
-        )[:n]
+        # the device's run, the copy back and the wake of this thread
+        with trace.span("dispatch.wait", lanes=b):
+            ok = np.asarray(out)[:n]
         return ok & valid
 
     # reference path (interpret mode): full padded input assembled on host
+    sig_words = _sig_words(sigs, valid)
     padded = np.zeros((b, nblocks * 128), dtype=np.uint8)
     padded[:n, :32] = sigs[:, :32]
     padded[:n, 32:64] = pubs

@@ -20,6 +20,7 @@ from tendermint_tpu.crypto import merkle
 from tendermint_tpu.crypto.batch import verify_generic
 from tendermint_tpu.crypto.keys import PubKey
 from tendermint_tpu.encoding.codec import Reader, Writer
+from tendermint_tpu.libs import trace
 from tendermint_tpu.types.core import (
     BlockID,
     SignedMsgType,
@@ -342,21 +343,28 @@ class ValidatorSet:
 
         One BatchVerifier dispatch for all non-nil precommits (the reference
         loops serially at validator_set.go:273-298)."""
-        pubkeys, msgs, sigs, powers = self.collect_commit_sigs(
-            chain_id, block_id, height, commit
-        )
-        ok = verify_generic(pubkeys, msgs, sigs, verifier=verifier)
-        tallied = 0
-        for j in range(len(pubkeys)):
-            if not ok[j]:
-                raise CommitError("invalid signature in commit")
-            tallied += powers[j]
+        with trace.span(
+            "commit.verify", height=height, n=len(commit.precommits)
+        ):
+            # not inside collect_commit_sigs: fast sync calls that per block
+            with trace.span("commit.collect", n=len(commit.precommits)):
+                pubkeys, msgs, sigs, powers = self.collect_commit_sigs(
+                    chain_id, block_id, height, commit
+                )
+            ok = verify_generic(pubkeys, msgs, sigs, verifier=verifier)
+            with trace.span("commit.tally", n=len(pubkeys)):
+                tallied = 0
+                for j in range(len(pubkeys)):
+                    if not ok[j]:
+                        raise CommitError("invalid signature in commit")
+                    tallied += powers[j]
 
-        if tallied * 3 <= self.total_voting_power() * 2:
-            raise CommitError(
-                f"insufficient voting power: got {tallied}, "
-                f"needed more than {self.total_voting_power() * 2 // 3}"
-            )
+                if tallied * 3 <= self.total_voting_power() * 2:
+                    raise CommitError(
+                        f"insufficient voting power: got {tallied}, "
+                        f"needed more than "
+                        f"{self.total_voting_power() * 2 // 3}"
+                    )
 
     def verify_future_commit(
         self, new_set: "ValidatorSet", chain_id: str, block_id: BlockID, height: int,

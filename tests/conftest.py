@@ -5,6 +5,8 @@ covered by `python chip_smoke.py` through the chip tool, never from here."""
 import os
 import sys
 
+import pytest
+
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
@@ -24,3 +26,37 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from tendermint_tpu.crypto import batch as _batch  # noqa: E402
 
 _batch.set_batch_verifier(_batch.HostBatchVerifier())
+
+
+@pytest.fixture
+def tracing():
+    """libs/trace's module-level tracer (the one the program's call sites
+    use), on for one test and left off and empty after it."""
+    from tendermint_tpu.libs import trace
+
+    trace.reset(4096)
+    trace.enable()
+    try:
+        yield trace
+    finally:
+        trace.disable()
+        trace.reset(trace.DEFAULT_CAPACITY)
+
+
+@pytest.fixture
+def verify_counters():
+    """Reads a verify metric family's series as /metrics prints them:
+    ``verify_counters(family, {label: value})`` -> their sum now."""
+    from tendermint_tpu.libs.metrics import get_verify_metrics
+
+    def read(family, labels=None):
+        want = [f'{k}="{v}"' for k, v in (labels or {}).items()]
+        total = 0.0
+        for line in get_verify_metrics().registry.expose_text().splitlines():
+            series, _, value = line.rpartition(" ")
+            name, _, rest = series.partition("{")
+            if name == family and all(w in rest for w in want):
+                total += float(value)
+        return total
+
+    return read

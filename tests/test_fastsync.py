@@ -488,6 +488,78 @@ class TestPipelinedVerify:
         assert punished == []  # stale speculation never punished anyone
         bc.on_stop()
 
+    # -- what the sync loop says about itself: how each look ended (counter),
+    # how long a harvest stood waiting and what a discarded speculation cost
+    # (spans, per window)
+
+    class _AcceptAll:
+        def verify_ed25519(self, items):
+            import numpy as np
+
+            return np.ones((len(items),), dtype=bool)
+
+        verify_secp256k1 = verify_ed25519
+
+    def test_ticks_and_harvest_spans(self, tracing, verify_counters):
+        def ticks():
+            return {r: verify_counters("tendermint_verify_sync_ticks_total",
+                                       {"result": r})
+                    for r in ("window", "harvest", "empty")}
+
+        fx = build_chain(n_vals=4, n_heights=12, chain_id="tick-chain")
+        bc, store = self._direct_reactor(fx, window=4, verifier=self._AcceptAll())
+        tracing.reset()  # building the chain verified a commit a block
+        before = ticks()
+        for _ in range(5):
+            bc._try_sync_window()
+        assert store.height() == fx.height - 1
+        bc.on_stop()
+        grown = {k: v - before[k] for k, v in ticks().items()}
+        # 1..4 in line, 5..8 and 9..11 from the worker, then nothing to do
+        assert grown == {"window": 1.0, "harvest": 2.0, "empty": 2.0}
+        spans = [e for e in tracing.export() if e.get("ph") == "X"]
+        harvests = [e for e in spans if e["name"] == "fastsync.harvest"]
+        assert [e["args"]["h0"] for e in harvests] == [5, 9]
+        assert all(e["args"]["hit"] is True for e in harvests)
+        assert not [e for e in spans if e["name"] == "fastsync.discard"]
+        # per window, never per block: 11 blocks applied, a handful of spans
+        assert len([e for e in spans if e["name"] == "fastsync.precheck"]) == 3
+        assert len(spans) <= 3 * 16
+
+    def test_discarded_speculation_is_spanned(self, tracing):
+        import base64
+
+        from tendermint_tpu.abci.examples.kvstore import PersistentKVStoreApp
+        from tendermint_tpu.crypto.keys import PrivKeyEd25519
+        from tendermint_tpu.types import MockPV
+
+        joiner = MockPV(PrivKeyEd25519.generate(bytes([92]) * 32))
+
+        def on_height(h, st):
+            if h == 4:  # in force from height 6: inside the second window
+                return [b"val:" + base64.b64encode(
+                    joiner.get_pub_key().bytes()) + b"!50"]
+            return []
+
+        fx = build_chain(
+            n_vals=4, n_heights=12, chain_id="tick-churn",
+            app_factory=PersistentKVStoreApp, on_height=on_height,
+            extra_pvs=[joiner],
+        )
+        bc, store = self._direct_reactor(
+            fx, window=4, verifier=self._AcceptAll(),
+            app_factory=PersistentKVStoreApp,
+        )
+        for _ in range(8):
+            bc._try_sync_window()
+        assert store.height() == fx.height - 1
+        bc.on_stop()
+        discards = [e for e in tracing.export()
+                    if e.get("name") == "fastsync.discard"]
+        assert discards, "the valset change voided no speculation"
+        for e in discards:
+            assert e["args"]["slots"] >= 1 and e["args"]["heights"] >= 1
+
 
 class TestVerifyBlockWindowSharded:
     """The mesh path: the same window flows through parallel/commit_verify,

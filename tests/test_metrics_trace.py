@@ -23,15 +23,19 @@ from tendermint_tpu.libs.metrics import (
 from tendermint_tpu.libs.trace import Tracer, _NOOP
 
 
-def _load_metrics_lint():
+def _load_script(name):
     path = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "scripts", "metrics_lint.py",
+        "scripts", name + ".py",
     )
-    spec = importlib.util.spec_from_file_location("metrics_lint", path)
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def _load_metrics_lint():
+    return _load_script("metrics_lint")
 
 
 # -- labeled Histogram --------------------------------------------------------------
@@ -234,7 +238,10 @@ class TestTracer:
         win = by_name["fastsync.window"]
         assert win["ph"] == "X" and win["dur"] >= 0
         assert win["cat"] == "fastsync"
-        assert win["args"] == {"h0": 5, "n": 3}
+        args = win["args"]
+        assert {k: args[k] for k in ("h0", "n")} == {"h0": 5, "n": 3}
+        assert isinstance(args["span_id"], int) and args["span_id"] > 0
+        assert args["parent_id"] is None and args["root_id"] == args["span_id"]
         step = by_name["consensus.step"]
         assert step["ph"] == "i" and step["s"] == "t"
 
@@ -315,6 +322,330 @@ class TestTracer:
         # zero-alloc path
         assert trace_mod.span("x") is _NOOP
 
+
+def _spans(events):
+    return [e for e in events if e.get("ph") == "X"]
+
+
+def _by_name(events):
+    out = {}
+    for e in _spans(events):
+        out.setdefault(e["name"], []).append(e)
+    return out
+
+
+@pytest.fixture
+def no_tracing(monkeypatch):
+    """Tracing off, and any attempt to build a span or to touch a tracer's
+    thread-local stack fails the test."""
+    class Untouchable:
+        def __getattr__(self, name):
+            raise AssertionError(f"thread-local read: {name}")
+
+        def __setattr__(self, name, value):
+            raise AssertionError(f"thread-local written: {name}")
+
+    def no_span(*a, **k):
+        raise AssertionError("a _Span was built with tracing off")
+
+    assert not trace_mod.enabled()
+    monkeypatch.setattr(trace_mod, "_Span", no_span)
+    monkeypatch.setattr(trace_mod.get_tracer(), "_tls", Untouchable())
+    return Untouchable
+
+
+@pytest.fixture
+def guarded_host():
+    """The guard as a node runs it (deadline, worker thread, 5 % audit)
+    around the host verifier standing in for the device."""
+    from tendermint_tpu.crypto import batch
+    from tendermint_tpu.libs import breaker
+
+    breaker.reset_device_guard()
+    yield batch.GuardedBatchVerifier(batch.HostBatchVerifier())
+    breaker.reset_device_guard()
+
+
+class TestSpanIdentity:
+    def test_parent_and_root_by_thread_nesting(self):
+        t = Tracer(capacity=16)
+        t.enable()
+        with t.span("a"):
+            with t.span("b"):
+                with t.span("c"):
+                    pass
+            with t.span("d"):
+                pass
+        with t.span("e"):
+            pass
+        ev = {e["name"]: e["args"] for e in _spans(t.export())}
+        ids = [ev[k]["span_id"] for k in "abcde"]
+        assert len(set(ids)) == 5
+        assert ev["a"]["parent_id"] is None
+        assert ev["b"]["parent_id"] == ev["a"]["span_id"]
+        assert ev["c"]["parent_id"] == ev["b"]["span_id"]
+        assert ev["d"]["parent_id"] == ev["a"]["span_id"]  # b was popped
+        assert {ev[k]["root_id"] for k in "abcd"} == {ev["a"]["span_id"]}
+        assert ev["e"]["parent_id"] is None
+        assert ev["e"]["root_id"] == ev["e"]["span_id"]
+        assert t.current() is None
+
+    def test_spans_on_another_thread_do_not_nest_without_a_handoff(self):
+        t = Tracer(capacity=8)
+        t.enable()
+        def other():
+            with t.span("other"):
+                pass
+
+        with t.span("main"):
+            th = threading.Thread(target=other)
+            th.start()
+            th.join(10)
+            assert not th.is_alive()
+        ev = {e["name"]: e["args"] for e in _spans(t.export())}
+        assert ev["other"]["parent_id"] is None
+        assert ev["other"]["root_id"] != ev["main"]["root_id"]
+
+    def test_adopted_handle_and_late_args(self):
+        t = Tracer(capacity=8)
+        t.enable()
+        got = {}
+        with t.span("root"):
+            with t.span("mid") as mid:
+                got["handle"] = t.current()
+                assert got["handle"] is mid
+
+        def other():
+            # mid has exited; a thread may still take it as its parent
+            t.adopt(got["handle"])
+            with t.span("late", hit=False) as sp:
+                sp.set(hit=True, n=3)
+            got["left_open"] = t.current()
+
+        th = threading.Thread(target=other)
+        th.start()
+        th.join(10)
+        assert not th.is_alive()
+        ev = {e["name"]: e["args"] for e in _spans(t.export())}
+        assert ev["late"]["parent_id"] == ev["mid"]["span_id"]
+        assert ev["late"]["root_id"] == ev["root"]["span_id"]
+        assert ev["late"]["hit"] is True and ev["late"]["n"] == 3
+        assert got["left_open"] is got["handle"]  # the adopted parent stays
+        _NOOP.set(anything=1)  # the disabled handle takes late args too
+
+    def test_handoff_through_supervised_call(self, tracing):
+        from tendermint_tpu.libs import breaker
+
+        seen = {}
+
+        def work():
+            seen["thread"] = threading.current_thread().name
+            with trace_mod.span("worker.inner"):
+                with trace_mod.span("worker.leaf"):
+                    pass
+            return 7
+
+        with trace_mod.span("caller.outer"):
+            with trace_mod.span("caller.call"):
+                assert breaker.supervised_call(work, 10.0, name="t") == 7
+        assert seen["thread"] == "supervised-t"  # it did cross a thread
+        ev = {e["name"]: e for e in _spans(trace_mod.export())}
+        inner, call = ev["worker.inner"], ev["caller.call"]
+        assert inner["tid"] != call["tid"]
+        assert inner["args"]["parent_id"] == call["args"]["span_id"]
+        assert ev["worker.leaf"]["args"]["parent_id"] == inner["args"]["span_id"]
+        root = ev["caller.outer"]["args"]["span_id"]
+        assert {e["args"]["root_id"] for e in ev.values()} == {root}
+
+    def test_disabled_allocates_no_span_and_touches_no_thread_local(
+            self, no_tracing):
+        t = Tracer(capacity=4)
+        t._tls = no_tracing()
+        assert t.span("x", a=1) is _NOOP
+        assert t.current() is None
+        t.adopt(None)
+        assert len(t) == 0
+        # and the module-level entry points the program calls
+        assert trace_mod.span("x", a=1) is _NOOP
+        assert trace_mod.current() is None
+        trace_mod.adopt(None)
+
+    def test_ids_survive_chrome_trace_json_and_trace_merge(self, tracing):
+        # what the benchmark's program_spans() makes of the same export is
+        # in tests/bench/test_bench_span_reducers.py
+        with trace_mod.span("fastsync.window", h0=1, n=2, mode="sync"):
+            with trace_mod.span("planner.pack", H=2):
+                pass
+        doc = json.loads(json.dumps(trace_mod.chrome_trace()))
+        ev = {e["name"]: e for e in _spans(doc["traceEvents"])}
+        win, pack = ev["fastsync.window"]["args"], ev["planner.pack"]["args"]
+        assert win["mode"] == "sync" and pack["H"] == 2
+        assert win["parent_id"] is None
+        assert pack["parent_id"] == win["span_id"]
+        assert pack["root_id"] == win["root_id"] == win["span_id"]
+        # trace_merge retags a node's dump_trace payload; args ride along
+        tm = _load_script("trace_merge")
+        payload = dict(doc, anchor={"wall_ns": 5_000, "perf_ns": 1_000})
+        merged = {e["name"]: e for e in tm._trace_events(payload, 3, 0)
+                  if e.get("ph") == "X"}
+        assert merged["planner.pack"]["pid"] == 3
+        assert merged["planner.pack"]["args"] == pack
+
+
+class TestProgramSpans:
+    """The spans the two served paths draw (names as PERF.md section 3)."""
+
+    COMMIT_NAMES = ["commit.verify", "commit.collect", "verify.generic",
+                    "guard.call", "guard.audit", "verify.dispatch",
+                    "commit.tally"]
+
+    def test_one_verify_commit_is_one_tree(self, tracing, guarded_host):
+        from tendermint_tpu.testutil.chain import build_commit
+
+        valset, block_id, commit = build_commit(24, height=7)
+        valset.verify_commit("bench-chain", block_id, 7, commit,
+                             verifier=guarded_host)
+        events = _spans(trace_mod.export())
+        assert len(events) <= 16
+        by = _by_name(events)
+        for name in self.COMMIT_NAMES:
+            assert len(by.get(name, [])) == 1, (name, sorted(by))
+        arg = {n: by[n][0]["args"] for n in self.COMMIT_NAMES}
+        root = arg["commit.verify"]
+        assert root["parent_id"] is None and root["height"] == 7
+        assert {e["args"]["root_id"] for e in events} == {root["span_id"]}
+        parent = {n: arg[n]["parent_id"] for n in self.COMMIT_NAMES}
+        sid = {n: arg[n]["span_id"] for n in self.COMMIT_NAMES}
+        assert parent["commit.collect"] == sid["commit.verify"]
+        assert parent["verify.generic"] == sid["commit.verify"]
+        assert parent["commit.tally"] == sid["commit.verify"]
+        assert parent["guard.call"] == sid["verify.generic"]
+        assert parent["guard.audit"] == sid["guard.call"]
+        # the dispatch ran on the guard's worker thread, under guard.call
+        assert parent["verify.dispatch"] == sid["guard.call"]
+        assert by["verify.dispatch"][0]["tid"] != by["guard.call"][0]["tid"]
+        assert arg["guard.call"]["attempts"] == 1 and arg["guard.call"]["n"] == 24
+        assert arg["guard.audit"] == dict(
+            arg["guard.audit"], sampled=2, mismatches=0)  # ceil(5 % of 24)
+
+    def test_one_block_window_is_spanned_per_window_not_per_block(
+            self, tracing, guarded_host):
+        from tendermint_tpu.blockchain.reactor import verify_block_window
+        from tendermint_tpu.state.state_types import state_from_genesis
+        from tendermint_tpu.testutil.chain import build_chain
+
+        fx = build_chain(n_vals=4, n_heights=9, chain_id="span-chain")
+        blocks = [fx.block_store.load_block(h) for h in range(1, 10)]
+        trace_mod.reset()
+        with trace_mod.span("fastsync.window", h0=1, n=8, mode="sync"):
+            n_ok, err = verify_block_window(
+                state_from_genesis(fx.genesis), blocks, verifier=guarded_host)
+        assert (n_ok, err) == (8, None)
+        events = _spans(trace_mod.export())
+        by = _by_name(events)
+        counts = {n: len(v) for n, v in by.items()}
+        assert counts == {
+            "fastsync.window": 1, "fastsync.precheck": 1, "planner.pack": 1,
+            "verify.generic": 1, "guard.call": 1, "verify.dispatch": 1,
+            "guard.audit": 1,
+        }
+        assert by["fastsync.precheck"][0]["args"]["n"] == 8
+        root = by["fastsync.window"][0]["args"]["span_id"]
+        assert {e["args"]["root_id"] for e in events} == {root}
+
+    def test_audit_seconds_are_observed_with_tracing_off(
+            self, guarded_host, verify_counters):
+        from tendermint_tpu.testutil.chain import build_commit
+
+        family = "tendermint_verify_device_audit_seconds_count"
+        assert not trace_mod.enabled()
+        before = verify_counters(family)
+        valset, block_id, commit = build_commit(8, height=3)
+        valset.verify_commit("bench-chain", block_id, 3, commit,
+                             verifier=guarded_host)
+        assert verify_counters(family) == before + 1
+
+    def test_device_launch_spans_and_valset_cache_counters(
+            self, tracing, monkeypatch, verify_counters):
+        """ops/ed25519_pallas._verify_uniform's packed path with the jitted
+        program stood in for (no chip here): prepare, then pack, launch and
+        wait around one launch, and one lookup of each valset cache."""
+        import numpy as np
+
+        from tendermint_tpu.ops import ed25519_pallas as ep
+        from tendermint_tpu.testutil.chain import build_commit
+
+        def series(cache, result):
+            return verify_counters("tendermint_verify_valset_cache_total",
+                                   {"cache": cache, "result": result})
+
+        launched = []
+
+        def fake_call_jit(fn, *args, **static):
+            launched.append(args[3].shape)
+            return np.ones((args[3].shape[0],), dtype=bool)
+
+        monkeypatch.setattr(ep, "call_jit", fake_call_jit)
+        monkeypatch.setattr(ep, "_valset_cache", {})
+        monkeypatch.setattr(ep, "_dev_valset_cache", {})
+        valset, block_id, commit = build_commit(5, height=3)
+        pubs = np.frombuffer(b"".join(
+            v.pub_key.bytes() for v in valset.validators), np.uint8).reshape(5, 32)
+        msgs = [pc.sign_bytes("bench-chain") for pc in commit.precommits]
+        sigs = np.frombuffer(b"".join(
+            pc.signature for pc in commit.precommits), np.uint8).reshape(5, 64)
+        before = {k: series(*k) for k in (("host", "miss"), ("host", "hit"),
+                                          ("device", "miss"), ("device", "hit"))}
+        for _ in range(2):
+            ok = ep.verify_batch(pubs, msgs, sigs)
+        assert ok.tolist() == [True] * 5 and launched == [(128, 16)] * 2
+        after = {k: series(*k) for k in before}
+        assert {k: after[k] - before[k] for k in before} == {
+            ("host", "miss"): 1, ("host", "hit"): 1,
+            ("device", "miss"): 1, ("device", "hit"): 1}
+        by = _by_name(trace_mod.export())
+        assert {n: len(v) for n, v in by.items()} == {
+            "dispatch.prepare": 2, "dispatch.pack": 2, "dispatch.launch": 2,
+            "dispatch.wait": 2}
+        assert by["dispatch.pack"][0]["args"]["n"] == 5
+        assert {e["args"]["lanes"] for n, v in by.items() for e in v
+                if n != "dispatch.prepare"} == {128}
+
+    def test_first_call_of_a_program_is_named(self, tracing):
+        import jax
+        import jax.numpy as jnp
+
+        from tendermint_tpu.ops.dispatch import call_jit
+
+        @jax.jit
+        def add_one_for_the_trace_test(x):
+            return x + 1
+
+        for _ in range(2):
+            call_jit(add_one_for_the_trace_test, jnp.zeros((128, 3)))
+        first = _by_name(trace_mod.export())["jit.first_call"]
+        assert len(first) == 1  # the second call is seen and draws nothing
+        args = first[0]["args"]
+        assert args["fn"] == "add_one_for_the_trace_test"
+        assert args["lanes"] == 128 and args["seconds"] >= 0
+
+    def test_tracing_off_builds_no_span_on_either_path(
+            self, no_tracing, guarded_host):
+        """What the driver's untraced runs execute: every new call site
+        takes the shared no-op."""
+        from tendermint_tpu.blockchain.reactor import verify_block_window
+        from tendermint_tpu.state.state_types import state_from_genesis
+        from tendermint_tpu.testutil.chain import build_chain, build_commit
+
+        valset, block_id, commit = build_commit(8, height=3)
+        valset.verify_commit("bench-chain", block_id, 3, commit,
+                             verifier=guarded_host)
+        fx = build_chain(n_vals=4, n_heights=4, chain_id="off-chain")
+        blocks = [fx.block_store.load_block(h) for h in range(1, 5)]
+        n_ok, err = verify_block_window(
+            state_from_genesis(fx.genesis), blocks, verifier=guarded_host)
+        assert (n_ok, err) == (3, None)
+        assert len(trace_mod.get_tracer()) == 0
 
 # -- strict linter ------------------------------------------------------------------
 

@@ -265,14 +265,16 @@ class TestTraceExport:
         a Chrome trace with consensus-step and WAL-fsync spans."""
         from tendermint_tpu.libs import trace
 
-        h0 = live_node.block_store.height()
         _, body = _rpc_get(live_node, "/trace_reset?enable=true")
         try:
             res = json.loads(body)["result"]
             assert res["enabled"] is True
-            # a fresh commit must land while tracing
+            # a whole height must pass while tracing: the one under way may
+            # have done its fsyncs before the tracer came on, and its block is
+            # stored before its EndHeight fsync
+            h0 = live_node.block_store.height()
             assert wait_for(
-                lambda: live_node.block_store.height() >= h0 + 1, timeout=30
+                lambda: live_node.block_store.height() >= h0 + 2, timeout=30
             )
             status, body = _rpc_get(live_node, "/dump_trace")
             assert status == 200
