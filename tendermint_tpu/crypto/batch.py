@@ -22,7 +22,9 @@ Accept/reject is bit-exact across backends (tests/test_ops_ed25519.py).
 from __future__ import annotations
 
 import logging
+import math
 import os
+import random
 import threading
 import time
 from typing import List, NamedTuple, Optional, Sequence, Tuple
@@ -30,6 +32,7 @@ from typing import List, NamedTuple, Optional, Sequence, Tuple
 import numpy as np
 
 from tendermint_tpu.crypto import ed25519 as _ed
+from tendermint_tpu.crypto import oracle_pool as _oracle_pool
 from tendermint_tpu.crypto.keys import PubKey, PubKeyEd25519
 from tendermint_tpu.libs import trace
 from tendermint_tpu.libs.metrics import get_verify_metrics
@@ -385,6 +388,26 @@ class TPUBatchVerifier:
         return ok
 
 
+def _item_rows(items, lanes) -> list:
+    return [(items[i].pubkey, items[i].msg, items[i].sig) for i in lanes]
+
+
+def _deadline_at(deadline, t0=None) -> Optional[float]:
+    """``deadline`` seconds after ``t0`` (now, by default) on the monotonic
+    clock; None where the dispatch is not supervised (deadline <= 0)."""
+    if deadline is None or deadline <= 0:
+        return None
+    return (time.monotonic() if t0 is None else t0) + deadline
+
+
+class _AuditSample(NamedTuple):
+    """One dispatch's audit between submit and compare."""
+
+    lanes: List[int]
+    rows: list  # the lanes' (pubkey, msg, sig)
+    ticket: Optional[_oracle_pool.Ticket]  # None: the oracle runs inline
+
+
 class GuardedBatchVerifier:
     """Fault-tolerant wrapper around a device BatchVerifier.
 
@@ -400,7 +423,11 @@ class GuardedBatchVerifier:
          window are re-verified on the host oracle; any disagreement
          quarantines the breaker (operator reset required) and the
          window's verdict is recomputed entirely on the host, so a
-         wrong device verdict never escapes this class.
+         wrong device verdict never escapes this class.  The lanes are
+         drawn before the device is asked; from 8 of them up, and with 3
+         or more cores, the oracle runs in worker processes
+         (crypto/oracle_pool) while the device call does, and the
+         verdicts are compared, all of them, once the device has answered.
 
     The wrapped device object only needs the BatchVerifier surface
     (verify_ed25519 / verify_ed25519_raw / verify_secp256k1), which is
@@ -438,7 +465,7 @@ class GuardedBatchVerifier:
             "ed25519", len(items),
             lambda: self.device.verify_ed25519(items),
             lambda: self.host.verify_ed25519(items),
-            lambda i: _ed.verify(items[i].pubkey, items[i].msg, items[i].sig),
+            lambda lanes: _item_rows(items, lanes),
         )
 
     def verify_ed25519_raw(self, pubs, msgs, sigs) -> np.ndarray:
@@ -446,31 +473,42 @@ class GuardedBatchVerifier:
             "ed25519", len(pubs),
             lambda: self.device.verify_ed25519_raw(pubs, msgs, sigs),
             lambda: self.host.verify_ed25519_raw(pubs, msgs, sigs),
-            lambda i: _ed.verify(pubs[i], msgs[i], sigs[i]),
+            lambda lanes: [(pubs[i], msgs[i], sigs[i]) for i in lanes],
         )
 
     def verify_secp256k1(self, items: Sequence[SigItem]) -> np.ndarray:
-        from tendermint_tpu.crypto import secp256k1 as _secp
-        from tendermint_tpu.crypto.hashing import sha256
-
         return self._guard(
             "secp256k1", len(items),
             lambda: self.device.verify_secp256k1(items),
             lambda: self.host.verify_secp256k1(items),
-            lambda i: _secp.verify(
-                items[i].pubkey, sha256(items[i].msg), items[i].sig
-            ),
+            lambda lanes: _item_rows(items, lanes),
         )
 
     # -- guard machinery -------------------------------------------------------
 
-    def _guard(self, algo, n, dev_call, host_call, oracle) -> np.ndarray:
+    def _guard(self, algo, n, dev_call, host_call, rows) -> np.ndarray:
+        """``rows(lanes)`` gives the sampled lanes' (pubkey, msg, sig), raw
+        as the caller holds them: the audit's oracle (``oracle_worker.
+        verify_rows``) may run in another process.
+
+        Every guarded dispatch takes one audit sequence number when it
+        starts, whether the device or the host completes it (breaker open,
+        time-out, error), and a retry re-uses it: the sample is a pure
+        function of (audit_seed, seq, n) and is drawn before the device is
+        asked, never from its answer."""
         if n == 0:
             return np.zeros((0,), dtype=bool)
         from tendermint_tpu.libs import breaker as _brk
 
-        # guard.call's own time (less verify.dispatch and guard.audit under
-        # it) is the worker thread's spawn, the join and the bookkeeping
+        audited = self.audit_rate > 0 and rows is not None
+        seq = None
+        if audited:
+            with self._mtx:
+                seq = self._dispatches
+                self._dispatches += 1
+        # guard.call's own time (less guard.submit, verify.dispatch and
+        # guard.audit under it) is the worker thread's spawn, the join and
+        # the bookkeeping
         with trace.span("guard.call", algo=algo, n=n, attempts=0) as sp:
             br = self.breaker
             if not br.allow():
@@ -480,55 +518,82 @@ class GuardedBatchVerifier:
                 )
                 self._note_fallback(reason, algo, n)
                 return np.asarray(host_call(), dtype=bool)
-            attempts = 0
-            while True:
-                sp.set(attempts=attempts + 1)
-                try:
-                    ok = _brk.supervised_call(
-                        dev_call, self.deadline, name=f"batch-{algo}"
-                    )
-                    ok = np.asarray(ok, dtype=bool)
-                except Exception as e:
-                    timeout = isinstance(e, _brk.DispatchTimeout)
-                    reason = "timeout" if timeout else "error"
-                    br.record_failure(reason)
-                    attempts += 1
-                    if attempts <= self.retries and br.allow():
-                        try:
-                            get_verify_metrics().device_retries.add(1.0)
-                        except Exception:
-                            pass
-                        continue
-                    self._note_fallback(reason, algo, n)
-                    return np.asarray(host_call(), dtype=bool)
-                if self._audit(algo, n, ok, oracle):
-                    # the device disagrees with the host oracle: safety bug.
-                    # Quarantine (latched) and recompute the WHOLE window on
-                    # the host — the sampled lanes say nothing about the rest.
-                    br.quarantine(f"audit_mismatch:{algo}")
-                    self._note_fallback("audit_mismatch", algo, n)
-                    return np.asarray(host_call(), dtype=bool)
-                br.record_success()
-                return ok
+            sample = self._submit_audit(algo, n, seq, rows) if audited else None
+            try:
+                attempts = 0
+                while True:
+                    sp.set(attempts=attempts + 1)
+                    t_call = time.monotonic()
+                    try:
+                        ok = _brk.supervised_call(
+                            dev_call, self.deadline, name=f"batch-{algo}"
+                        )
+                        ok = np.asarray(ok, dtype=bool)
+                    except Exception as e:
+                        timeout = isinstance(e, _brk.DispatchTimeout)
+                        reason = "timeout" if timeout else "error"
+                        br.record_failure(reason)
+                        attempts += 1
+                        if attempts <= self.retries and br.allow():
+                            try:
+                                get_verify_metrics().device_retries.add(1.0)
+                            except Exception:
+                                pass
+                            continue
+                        self._note_fallback(reason, algo, n)
+                        return np.asarray(host_call(), dtype=bool)
+                    if sample is not None and self._audit(
+                            algo, ok, sample, t_call):
+                        # the device disagrees with the host oracle: safety
+                        # bug.  Quarantine (latched) and recompute the WHOLE
+                        # window on the host: the sampled lanes say nothing
+                        # about the rest.
+                        br.quarantine(f"audit_mismatch:{algo}")
+                        self._note_fallback("audit_mismatch", algo, n)
+                        return np.asarray(host_call(), dtype=bool)
+                    br.record_success()
+                    return ok
+            finally:
+                # a dispatch that ended without collecting (host completion,
+                # an exception on its way out): the workers' answers are
+                # drained and dropped
+                if sample is not None and sample.ticket is not None:
+                    sample.ticket.abandon()
 
-    def _audit(self, algo, n, ok, oracle) -> bool:
-        """Cross-check k seeded-sampled lanes against the host oracle.
-        Returns True iff any lane disagrees."""
-        rate = self.audit_rate
-        if rate <= 0 or oracle is None:
-            return False
-        import math
-        import random
-
-        with self._mtx:
-            seq = self._dispatches
-            self._dispatches += 1
-        k = min(n, max(1, int(math.ceil(n * rate))))
-        t0 = time.perf_counter()
-        with trace.span("guard.audit", sampled=k) as sp:
+    def _submit_audit(self, algo, n, seq, rows) -> "_AuditSample":
+        """Draw the dispatch's k seeded lanes and, where there are enough of
+        them and the process has the cores, hand their rows to the oracle
+        workers, which verify them while the device call runs."""
+        k = min(n, max(1, int(math.ceil(n * self.audit_rate))))
+        with trace.span("guard.submit", sampled=k) as sp:
             rng = random.Random((self.audit_seed << 20) ^ seq)
             lanes = rng.sample(range(n), k)
-            bad = [i for i in lanes if bool(ok[i]) != bool(oracle(i))]
+            picked = rows(lanes)
+            pool = (_oracle_pool.get_oracle_pool()
+                    if k >= _oracle_pool.MIN_POOL_LANES else None)
+            ticket = None
+            if pool is not None:
+                ticket = pool.submit(algo, picked, _deadline_at(self.deadline))
+            sp.set(frames=len(ticket.parts) if ticket else 0)
+        return _AuditSample(lanes, picked, ticket)
+
+    def _audit(self, algo, ok, sample, t_call) -> bool:
+        """Cross-check the sampled lanes against the host oracle, after the
+        device has answered: collect the workers' verdicts (or run the
+        oracle here, for a small sample), compare lane by lane.  Every
+        sampled lane has its oracle verdict before this returns.  Returns
+        True iff any lane disagrees."""
+        lanes = sample.lanes
+        t0 = time.perf_counter()
+        with trace.span("guard.audit", sampled=len(lanes)) as sp:
+            if sample.ticket is not None:
+                verdicts, lost = sample.ticket.collect(
+                    _deadline_at(self.deadline, t_call))
+                where = {"pool": len(lanes) - lost, "inline_after_loss": lost}
+            else:
+                verdicts = _oracle_pool.verify_rows(algo, sample.rows)
+                where = {"inline": len(lanes)}
+            bad = [i for i, v in zip(lanes, verdicts) if bool(ok[i]) != v]
             sp.set(mismatches=len(bad))
         try:
             m = get_verify_metrics()
@@ -537,6 +602,9 @@ class GuardedBatchVerifier:
                 m.device_audit.add(float(len(lanes) - len(bad)), ("ok",))
             if bad:
                 m.device_audit.add(float(len(bad)), ("mismatch",))
+            for label, count in where.items():
+                if count:
+                    m.audit_oracle.add(float(count), (label,))
         except Exception:
             pass
         if bad:

@@ -390,8 +390,17 @@ class VerifyMetrics:
         )
         self.device_audit_seconds = r.histogram(
             "verify_device_audit_seconds",
-            "Silent-corruption audit wall seconds per device dispatch "
-            "(the sampled lanes re-verified one by one on the host)",
+            "Silent-corruption audit wall seconds per device dispatch, "
+            "after the device has answered (oracle verdicts collected from "
+            "the workers, or computed here for a small sample, and compared)",
+        )
+        self.audit_oracle = r.counter(
+            "verify_audit_oracle_total",
+            "Audited lanes by where their host-oracle verdict was computed: "
+            "pool (worker processes, beside the device call) | inline (the "
+            "calling thread, small samples) | inline_after_loss (the calling "
+            "thread, because a worker died or did not answer)",
+            label_names=("where",),
         )
         # whole-valset caches of the Pallas path (ops/ed25519_pallas): the
         # decompressed limbs on the host and their padded copies on the

@@ -496,8 +496,8 @@ class TestProgramSpans:
     """The spans the two served paths draw (names as PERF.md section 3)."""
 
     COMMIT_NAMES = ["commit.verify", "commit.collect", "verify.generic",
-                    "guard.call", "guard.audit", "verify.dispatch",
-                    "commit.tally"]
+                    "guard.call", "guard.submit", "guard.audit",
+                    "verify.dispatch", "commit.tally"]
 
     def test_one_verify_commit_is_one_tree(self, tracing, guarded_host):
         from tendermint_tpu.testutil.chain import build_commit
@@ -520,7 +520,15 @@ class TestProgramSpans:
         assert parent["verify.generic"] == sid["commit.verify"]
         assert parent["commit.tally"] == sid["commit.verify"]
         assert parent["guard.call"] == sid["verify.generic"]
-        assert parent["guard.audit"] == sid["guard.call"]
+        # the sample is drawn (and shipped) before the dispatch, compared
+        # after it: both under guard.call, on the caller's thread
+        assert parent["guard.submit"] == parent["guard.audit"] == sid["guard.call"]
+        submit, dispatch, audit = (
+            by[n][0] for n in ("guard.submit", "verify.dispatch", "guard.audit"))
+        assert submit["tid"] == audit["tid"] == by["guard.call"][0]["tid"]
+        assert submit["ts"] + submit["dur"] <= dispatch["ts"]
+        assert dispatch["ts"] + dispatch["dur"] <= audit["ts"]
+        assert arg["guard.submit"]["sampled"] == 2
         # the dispatch ran on the guard's worker thread, under guard.call
         assert parent["verify.dispatch"] == sid["guard.call"]
         assert by["verify.dispatch"][0]["tid"] != by["guard.call"][0]["tid"]
@@ -546,24 +554,32 @@ class TestProgramSpans:
         counts = {n: len(v) for n, v in by.items()}
         assert counts == {
             "fastsync.window": 1, "fastsync.precheck": 1, "planner.pack": 1,
-            "verify.generic": 1, "guard.call": 1, "verify.dispatch": 1,
-            "guard.audit": 1,
+            "verify.generic": 1, "guard.call": 1, "guard.submit": 1,
+            "verify.dispatch": 1, "guard.audit": 1,
         }
         assert by["fastsync.precheck"][0]["args"]["n"] == 8
         root = by["fastsync.window"][0]["args"]["span_id"]
         assert {e["args"]["root_id"] for e in events} == {root}
 
+    @pytest.mark.parametrize("n_vals, where", [(8, "inline"), (200, "pool")])
     def test_audit_seconds_are_observed_with_tracing_off(
-            self, guarded_host, verify_counters):
+            self, guarded_host, verify_counters, n_vals, where):
+        """One observation a dispatch, whether the oracle ran on the calling
+        thread (1 sampled lane of 8) or in the workers (10 of 200)."""
+        from tendermint_tpu.crypto import oracle_pool
         from tendermint_tpu.testutil.chain import build_commit
 
+        if where == "pool" and not oracle_pool.pool_size():
+            pytest.skip("too few cores here for oracle workers")
         family = "tendermint_verify_device_audit_seconds_count"
+        lanes = ("tendermint_verify_audit_oracle_total", {"where": where})
         assert not trace_mod.enabled()
-        before = verify_counters(family)
-        valset, block_id, commit = build_commit(8, height=3)
+        before, lanes_before = verify_counters(family), verify_counters(*lanes)
+        valset, block_id, commit = build_commit(n_vals, height=3)
         valset.verify_commit("bench-chain", block_id, 3, commit,
                              verifier=guarded_host)
         assert verify_counters(family) == before + 1
+        assert verify_counters(*lanes) == lanes_before + max(1, n_vals // 20)
 
     def test_device_launch_spans_and_valset_cache_counters(
             self, tracing, monkeypatch, verify_counters):
