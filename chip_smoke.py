@@ -269,10 +269,12 @@ def stage_fast_sync(checks: dict) -> None:
 
 
 def stage_secp256k1(checks: dict) -> None:
+    import math
     import random
 
     from tendermint_tpu.crypto import secp256k1 as secp
     from tendermint_tpu.crypto.keys import PrivKeySecp256k1, PubKeySecp256k1
+    from tendermint_tpu.libs.metrics import get_verify_metrics
     from tendermint_tpu.types import BlockID, PartSetHeader, SignedMsgType, Vote
     from tendermint_tpu.types.block import Commit
     from tendermint_tpu.types.validator_set import Validator, ValidatorSet
@@ -296,8 +298,26 @@ def stage_secp256k1(checks: dict) -> None:
         votes.append(vote.with_signature(
             by_addr[val.address].sign(vote.sign_bytes(chain_id))))
     commit = Commit(block_id=block_id, precommits=votes)
+    # what the cell secp256-stream asserts of every call in its window
+    # (benchmark/drivers/commit_stream_secp256k1.py), asserted here of the
+    # valid commit, so that the smoke and the cell cannot drift apart: every
+    # verdict is the device's, ceil(5 %) lanes are audited, by the workers
+    m = get_verify_metrics()
+    watched = (m.secp256k1_host_decided, m.device_audit, m.audit_oracle)
+    before = [c.snapshot() for c in watched]
     valset.verify_commit(chain_id, block_id, height, commit)
     checks["verify_commit_accepted"] = True
+    decided, audited, where = (
+        _delta(c.snapshot(), b) for c, b in zip(watched, before))
+    want = math.ceil(SECP_VALIDATORS * 0.05)
+    checks["valid_commit"] = {
+        "host_decided_lanes": sum(decided.values()),
+        "audited_lanes": sum(audited.values()),
+        "audited_on_the_oracle_workers": where.get(("pool",), 0),
+    }
+    assert not decided, f"host prologue decided lanes of a valid commit: {decided}"
+    assert audited == {("ok",): want}, f"audit of the valid commit: {audited}"
+    assert where == {("pool",): want}, f"audit oracle ran {where}, not on the workers"
 
     pubkeys, msgs, sigs, _ = valset.collect_commit_sigs(
         chain_id, block_id, height, commit)

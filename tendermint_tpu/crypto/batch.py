@@ -367,9 +367,11 @@ class TPUBatchVerifier:
         first = "secp256k1" not in self._warm
         with trace.span("verify.dispatch", backend=self.backend,
                         algo="secp256k1", n=len(items)):
-            pubs = [it.pubkey for it in items]
-            digs = [sha256(it.msg) for it in items]
-            sigs = [it.sig for it in items]
+            # the SHA-256 premix (secp256k1.go:140) and the column lists
+            with trace.span("dispatch.prepare", n=len(items)):
+                pubs = [it.pubkey for it in items]
+                digs = [sha256(it.msg) for it in items]
+                sigs = [it.sig for it in items]
             if self.backend == "pallas":
                 from tendermint_tpu.ops import secp256k1_pallas as _skp
 

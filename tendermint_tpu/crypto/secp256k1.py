@@ -180,25 +180,49 @@ def der_encode_sig(r: int, s: int) -> bytes:
     return b"\x30" + bytes([len(body)]) + body
 
 
+def _canonical_padding(b: bytes) -> bool:
+    """btcec's canonicalPadding: no sign bit on the first byte, and a
+    leading zero only where the next byte has its top bit set."""
+    if b[0] & 0x80:
+        return False
+    return not (len(b) > 1 and b[0] == 0 and not b[1] & 0x80)
+
+
 def der_decode_sig(sig: bytes) -> Optional[Tuple[int, int]]:
-    try:
-        if len(sig) < 8 or sig[0] != 0x30 or sig[1] != len(sig) - 2:
-            return None
-        i = 2
-        if sig[i] != 0x02:
-            return None
-        rl = sig[i + 1]
-        r = int.from_bytes(sig[i + 2 : i + 2 + rl], "big")
-        i += 2 + rl
-        if i >= len(sig) or sig[i] != 0x02:
-            return None
-        sl = sig[i + 1]
-        if i + 2 + sl != len(sig):
-            return None
-        s = int.from_bytes(sig[i + 2 :], "big")
-        return (r, s)
-    except (IndexError, ValueError):
+    """(r, s) as btcec.ParseDERSignature (signature.go parseSig, der=true,
+    the revision Tendermint v0.26.2 vendors) takes them, else None.  Rule by
+    rule: at least 8 bytes; 0x30; a one-byte length that does not run past
+    the buffer, after which the buffer is cut (bytes beyond the sequence
+    are dropped, as for Bitcoin's trailing hash type: btcec's "trailing
+    crap" vector is valid); 0x02, a length of 1 .. what leaves room for
+    ``02 len s``, r; 0x02, a length of 1 .. the rest, s, ending exactly at
+    the sequence's end; r and s each without a sign bit and without a
+    needless leading zero.  0 < r, s < n is the caller's check here (btcec
+    makes it inside the parse: the accept set is the same)."""
+    if len(sig) < 8 or sig[0] != 0x30:
         return None
+    end = sig[1] + 2
+    # btcec adds in a byte (254 and 255 wrap and then fault): refused here
+    if end > len(sig) or end > 255:
+        return None
+    if sig[2] != 0x02:
+        return None
+    rl = sig[3]
+    i = 4
+    if rl <= 0 or rl > end - i - 3:
+        return None
+    rb = sig[i:i + rl]
+    i += rl
+    if not _canonical_padding(rb) or sig[i] != 0x02:
+        return None
+    sl = sig[i + 1]
+    i += 2
+    if sl <= 0 or i + sl != end:
+        return None
+    sb = sig[i:end]
+    if not _canonical_padding(sb):
+        return None
+    return (int.from_bytes(rb, "big"), int.from_bytes(sb, "big"))
 
 
 # ---------------------------------------------------------------------------

@@ -404,13 +404,28 @@ class VerifyMetrics:
         )
         # whole-valset caches of the Pallas path (ops/ed25519_pallas): the
         # decompressed limbs on the host and their padded copies on the
-        # device, each keyed by the dispatch's whole pubkey array
+        # device, each keyed by the dispatch's whole pubkey array; and the
+        # secp256k1 prologue's per-key decompression cache
+        # (ops/secp256k1_verify._decompress_cached: one lookup a lane)
         self.valset_cache = r.counter(
             "verify_valset_cache_total",
-            "Pallas valset cache lookups by cache (host|device) and result "
-            "(hit|miss)",
+            "Verify-path key cache lookups by cache (host|device: a whole "
+            "ed25519 valset a dispatch; secp256k1_pubkey: one key a lane) "
+            "and result (hit|miss)",
             label_names=("cache", "result"),
         )
+        # secp256k1 lanes the host prologue (secp256k1_verify.prep_item)
+        # decided: they never reach the device, so the guard's audit, which
+        # samples the dispatch's answer, sees the host's verdict for them
+        self.secp256k1_host_decided = r.counter(
+            "verify_secp256k1_host_decided_total",
+            "secp256k1 lanes decided by the host prologue instead of the "
+            "device, by reason: malformed (key, DER, range or low-s refused) "
+            "| degenerate (u1 or u2 is 0: the host oracle verified it)",
+            label_names=("reason",),
+        )
+        for reason in ("malformed", "degenerate"):  # both series from 0
+            self.secp256k1_host_decided.add(0.0, (reason,))
         # how each look of the fast-sync loop ended (blockchain/reactor
         # _try_sync_window); one TRY_SYNC_INTERVAL sleep follows each
         self.sync_ticks = r.counter(

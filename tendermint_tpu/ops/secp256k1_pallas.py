@@ -46,6 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tendermint_tpu.crypto import secp256k1 as _s
+from tendermint_tpu.libs import trace
 from tendermint_tpu.ops import secp256k1_verify as _xla
 
 P = _xla.P
@@ -334,10 +335,19 @@ def _ladder_call(qx, qy, dig1, dig2, rl, rnl, rnok, *, interpret=False,
 
 _CONSTS = _build_g_table()
 
-_ladder_jit = partial(
-    jax.jit,
-    static_argnames=("interpret", "lanes", "fe_backend", "carry_mode"),
-)(_ladder_call)
+
+# The compiled entry of the real-device path, under a name of its own: the
+# profiler calls the operation after the jitted function, and ed25519_pallas
+# has a _ladder_call too, inside _device_verify_packed.
+@partial(jax.jit, static_argnames=("lanes", "fe_backend", "carry_mode"))
+def _device_verify_secp256k1(qx, qy, dig1, dig2, rl, rnl, rnok, lanes=LANES,
+                             fe_backend="vpu", carry_mode="lazy"):
+    """Lane-major as the host packs them: qx/qy/rl/rnl (b, 20), dig1/dig2
+    (b, 64), rnok (b,); turned limb-major for the kernel on the device."""
+    ok = _ladder_call(qx.T, qy.T, dig1.T, dig2.T, rl.T, rnl.T, rnok[None, :],
+                      lanes=lanes, fe_backend=fe_backend,
+                      carry_mode=carry_mode)
+    return ok[0]
 
 
 # ---------------------------------------------------------------------------
@@ -369,7 +379,12 @@ def verify_batch(
     same host prologue) as secp256k1_verify.verify_batch. `fe_backend`
     selects the limb multiplier (fe_common.FE_BACKENDS); `carry_mode`
     "lazy" (default) defers limb carries between point ops, "eager" keeps
-    the per-op full carry ripple; verdicts are bit-exact either way."""
+    the per-op full carry ripple; verdicts are bit-exact either way.
+
+    Its spans are children of the caller's ``verify.dispatch``, the shared
+    names meaning what they mean in ed25519_pallas: ``secp.prologue`` (the
+    per-lane ``prep_item`` loop, which ed25519 has no counterpart of),
+    ``dispatch.pack``, ``dispatch.launch``, ``dispatch.wait``."""
     fe_backend = _fc.normalize_backend(fe_backend)
     carry_mode = _fc.normalize_carry_mode(carry_mode)
     n = len(pubkeys)
@@ -378,42 +393,52 @@ def verify_batch(
     lanes = 8 if interpret else LANES
     b = _bucket(n, lanes)
 
-    qx = np.zeros((b, NLIMB), np.uint32)
-    qy = np.zeros((b, NLIMB), np.uint32)
-    d1 = np.zeros((b, NWIN), np.uint32)
-    d2 = np.zeros((b, NWIN), np.uint32)
-    rl = np.zeros((b, NLIMB), np.uint32)
-    rnl = np.zeros((b, NLIMB), np.uint32)
-    rnok = np.zeros((b,), np.uint32)
     forced = np.full((b,), -1, np.int8)
+    reasons = []
+    kernel_items = []  # (lane, item) for the lanes the device decides
+    with trace.span("secp.prologue", n=n) as sp:
+        for i in range(n):
+            item = _xla.prep_item(
+                bytes(pubkeys[i]), bytes(digests[i]), bytes(sigs[i]))
+            if item[0] == "forced":
+                forced[i] = item[1]
+                reasons.append(item[2])
+            else:
+                kernel_items.append((i, item))
+        sp.set(forced=len(reasons))
+    _xla.record_prologue(reasons)
 
-    for i in range(n):
-        item = _xla.prep_item(bytes(pubkeys[i]), bytes(digests[i]), bytes(sigs[i]))
-        if item[0] == "forced":
-            forced[i] = item[1]
-            continue
-        _, Q, u1, u2, r = item
-        qx[i], qy[i] = Q
-        d1[i] = _digits_msb(u1)
-        d2[i] = _digits_msb(u2)
-        rl[i] = int_to_limbs(r)
-        if r + N < P:
-            rnl[i] = int_to_limbs(r + N)
-            rnok[i] = 1
+    with trace.span("dispatch.pack", n=n, lanes=b):
+        qx = np.zeros((b, NLIMB), np.uint32)
+        qy = np.zeros((b, NLIMB), np.uint32)
+        d1 = np.zeros((b, NWIN), np.uint32)
+        d2 = np.zeros((b, NWIN), np.uint32)
+        rl = np.zeros((b, NLIMB), np.uint32)
+        rnl = np.zeros((b, NLIMB), np.uint32)
+        rnok = np.zeros((b,), np.uint32)
+        for i, (_, Q, u1, u2, r) in kernel_items:
+            qx[i], qy[i] = Q
+            d1[i] = _digits_msb(u1)
+            d2[i] = _digits_msb(u2)
+            rl[i] = int_to_limbs(r)
+            if r + N < P:
+                rnl[i] = int_to_limbs(r + N)
+                rnok[i] = 1
+        host = (qx, qy, d1, d2, rl, rnl, rnok)
 
-    args = [jnp.asarray(np.ascontiguousarray(a.T))
-            for a in (qx, qy, d1, d2, rl, rnl)]
-    args.append(jnp.asarray(rnok[None, :]))
-    if interpret:
-        ok = np.asarray(
-            _ladder_call(*args, interpret=True, lanes=lanes,
-                         fe_backend=fe_backend, carry_mode=carry_mode)
-        )[0, :n]
-    else:
-        ok = np.asarray(
-            call_jit(_ladder_jit, *args, lanes=lanes, fe_backend=fe_backend,
-                     carry_mode=carry_mode)
-        )[0, :n]
+    with trace.span("dispatch.launch", lanes=b):  # copies in + enqueue
+        if interpret:
+            out = _ladder_call(
+                *(jnp.asarray(a.T) for a in host[:6]),
+                jnp.asarray(rnok[None, :]), interpret=True, lanes=lanes,
+                fe_backend=fe_backend, carry_mode=carry_mode)[0]
+        else:
+            out = call_jit(_device_verify_secp256k1,
+                           *(jnp.asarray(a) for a in host), lanes=lanes,
+                           fe_backend=fe_backend, carry_mode=carry_mode)
+    # the device's run, the copy back and the wake of this thread
+    with trace.span("dispatch.wait", lanes=b):
+        ok = np.asarray(out)[:n]
 
     f = forced[:n]
     return np.where(f >= 0, f.astype(bool), ok.astype(bool))

@@ -25,6 +25,7 @@ Accept/reject is bit-exact with crypto/secp256k1.verify (the host oracle).
 from __future__ import annotations
 
 import sys
+import threading
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -34,6 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from tendermint_tpu.crypto import secp256k1 as _s
+from tendermint_tpu.libs.metrics import get_verify_metrics
 from tendermint_tpu.ops import fe_common as _fc
 from tendermint_tpu.ops.dispatch import call_jit
 
@@ -399,12 +401,18 @@ def _compiled_kernel(batch: int, mesh=None, fe_backend: str = "vpu",
 
 _decompress_cache: dict = {}
 _DECOMPRESS_CACHE_MAX = 1 << 16
+# lookups since the last flush, [hits, misses]: a dispatch looks up every
+# lane, so the counter family is fed once a dispatch, not once a lane
+_cache_looks = [0, 0]
+_cache_mtx = threading.Lock()
 
 
 def _decompress_cached(pub: bytes):
     hit = _decompress_cache.get(pub, False)
     if hit is not False:
+        _cache_looks[0] += 1
         return hit
+    _cache_looks[1] += 1
     xy = _s.decompress_pubkey(pub)
     if xy is None:
         out = None
@@ -414,6 +422,25 @@ def _decompress_cached(pub: bytes):
         _decompress_cache.clear()
     _decompress_cache[pub] = out
     return out
+
+
+def record_prologue(forced_reasons: Sequence[str]) -> None:
+    """One dispatch's prologue in VerifyMetrics: the pubkey cache's hits and
+    misses since the last flush, and the lanes ``prep_item`` decided on the
+    host, by reason.  Telemetry never takes down the verify path."""
+    with _cache_mtx:
+        hits, misses = _cache_looks
+        _cache_looks[0] = _cache_looks[1] = 0
+    try:
+        m = get_verify_metrics()
+        if hits:
+            m.valset_cache.add(float(hits), ("secp256k1_pubkey", "hit"))
+        if misses:
+            m.valset_cache.add(float(misses), ("secp256k1_pubkey", "miss"))
+        for reason in forced_reasons:
+            m.secp256k1_host_decided.add(1.0, (reason,))
+    except Exception:
+        pass
 
 
 def _scalar_words(x: int) -> np.ndarray:
@@ -440,16 +467,18 @@ def _bucket(n: int, mesh=None) -> int:
 def prep_item(pubkey: bytes, digest: bytes, sig: bytes):
     """Host prologue for ONE signature: strict-DER parse + range/low-s
     checks, w = s⁻¹ mod n, scalars, cached decompression. Returns either
-    ("forced", 0|1) for host-decided items or
-    ("kernel", (qx, qy), u1, u2, r) for device verification. Shared by the
-    XLA kernel and the Pallas pipeline so accept/reject can never drift."""
+    ("forced", 0|1, reason) for host-decided items (reason "malformed": key,
+    DER, range or low-s refused here; "degenerate": u1 or u2 is 0 and the
+    host oracle decided) or ("kernel", (qx, qy), u1, u2, r) for device
+    verification. Shared by the XLA kernel and the Pallas pipeline so
+    accept/reject can never drift."""
     Q = _decompress_cached(pubkey)
     parsed = _s.der_decode_sig(sig)
     if Q is None or parsed is None:
-        return ("forced", 0)
+        return ("forced", 0, "malformed")
     r, s = parsed
     if not (0 < r < N and 0 < s < N) or s > _s._HALF_N:
-        return ("forced", 0)
+        return ("forced", 0, "malformed")
     e = int.from_bytes(digest, "big")
     w = pow(s, N - 2, N)
     u1 = e * w % N
@@ -457,7 +486,7 @@ def prep_item(pubkey: bytes, digest: bytes, sig: bytes):
     if u1 == 0 or u2 == 0:
         # ladder degenerates to single-scalar — host decides (never
         # happens for honestly generated signatures)
-        return ("forced", int(_s.verify(pubkey, digest, sig)))
+        return ("forced", int(_s.verify(pubkey, digest, sig)), "degenerate")
     return ("kernel", Q, u1, u2, r)
 
 
@@ -490,11 +519,13 @@ def verify_batch(
     rn_ok = np.zeros((b,), bool)
     # -1 = decided on device, else the host-decided 0/1
     forced = np.full((b,), -1, np.int8)
+    reasons = []
 
     for i in range(n):
         item = prep_item(bytes(pubkeys[i]), bytes(digests[i]), bytes(sigs[i]))
         if item[0] == "forced":
             forced[i] = item[1]
+            reasons.append(item[2])
             continue
         _, Q, u1, u2, r = item
         qx[i], qy[i] = Q
@@ -504,6 +535,7 @@ def verify_batch(
         if r + N < P:
             rnl[i] = int_to_limbs(r + N)
             rn_ok[i] = True
+    record_prologue(reasons)
 
     kernel = _compiled_kernel(b, mesh, fe_backend, carry_mode)
     host = (qx, qy, u1w, u2w, rl, rnl, rn_ok)
