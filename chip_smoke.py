@@ -301,21 +301,25 @@ def stage_secp256k1(checks: dict) -> None:
     # what the cell secp256-stream asserts of every call in its window
     # (benchmark/drivers/commit_stream_secp256k1.py), asserted here of the
     # valid commit, so that the smoke and the cell cannot drift apart: every
-    # verdict is the device's, ceil(5 %) lanes are audited, by the workers
+    # verdict is the device's, ceil(5 %) lanes are audited, by the workers;
+    # and the host prologue inverted once for all 256 lanes
     m = get_verify_metrics()
-    watched = (m.secp256k1_host_decided, m.device_audit, m.audit_oracle)
+    watched = (m.secp256k1_host_decided, m.device_audit, m.audit_oracle,
+               m.secp256k1_inversions)
     before = [c.snapshot() for c in watched]
     valset.verify_commit(chain_id, block_id, height, commit)
     checks["verify_commit_accepted"] = True
-    decided, audited, where = (
+    decided, audited, where, inverted = (
         _delta(c.snapshot(), b) for c, b in zip(watched, before))
     want = math.ceil(SECP_VALIDATORS * 0.05)
     checks["valid_commit"] = {
         "host_decided_lanes": sum(decided.values()),
         "audited_lanes": sum(audited.values()),
         "audited_on_the_oracle_workers": where.get(("pool",), 0),
+        "prologue_inversions": sum(inverted.values()),
     }
     assert not decided, f"host prologue decided lanes of a valid commit: {decided}"
+    assert inverted == {(): 1}, f"prologue inversions of one dispatch: {inverted}"
     assert audited == {("ok",): want}, f"audit of the valid commit: {audited}"
     assert where == {("pool",): want}, f"audit oracle ran {where}, not on the workers"
 

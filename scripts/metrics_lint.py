@@ -314,6 +314,9 @@ def _self_check():
     vm.device_retries.add(1.0)
     vm.device_audit.add(8.0, ("ok",))
     vm.device_audit.add(1.0, ("mismatch",))
+    # the secp256k1 prologue's pair (ops/secp256k1_verify.record_prologue)
+    vm.secp256k1_host_decided.add(2.0, ("malformed",))
+    vm.secp256k1_inversions.add(1.0)
     # per-device shard attribution (mesh superdispatch) — device ids past
     # the label cap fold into "overflow", which must still lint
     vm.record_device_shards((0, 1), 128)
@@ -471,6 +474,10 @@ def _self_check():
         "tendermint_verify_device_audit_seconds",
         "tendermint_verify_valset_cache_total",
         "tendermint_verify_sync_ticks_total",
+        # the secp256k1 host prologue: lanes it decided itself, and the
+        # modular inversions it performed (one a dispatch)
+        "tendermint_verify_secp256k1_host_decided_total",
+        "tendermint_verify_secp256k1_inversions_total",
     )
     verify_text = vm.registry.expose_text()
     missing_dev = [

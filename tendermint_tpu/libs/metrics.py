@@ -414,7 +414,7 @@ class VerifyMetrics:
             "and result (hit|miss)",
             label_names=("cache", "result"),
         )
-        # secp256k1 lanes the host prologue (secp256k1_verify.prep_item)
+        # secp256k1 lanes the host prologue (secp256k1_verify.prep_batch)
         # decided: they never reach the device, so the guard's audit, which
         # samples the dispatch's answer, sees the host's verdict for them
         self.secp256k1_host_decided = r.counter(
@@ -426,6 +426,15 @@ class VerifyMetrics:
         )
         for reason in ("malformed", "degenerate"):  # both series from 0
             self.secp256k1_host_decided.add(0.0, (reason,))
+        # what says the prologue inverted once for the whole dispatch
+        # (Montgomery's trick) and not once a lane
+        self.secp256k1_inversions = r.counter(
+            "verify_secp256k1_inversions_total",
+            "Modular inversions the secp256k1 host prologue performed: 1 a "
+            "dispatch with a lane past the parse and range checks, whatever "
+            "its size; 0 for a dispatch whose every lane was refused there",
+        )
+        self.secp256k1_inversions.add(0.0)  # exposed from 0
         # how each look of the fast-sync loop ended (blockchain/reactor
         # _try_sync_window); one TRY_SYNC_INTERVAL sleep follows each
         self.sync_ticks = r.counter(

@@ -28,8 +28,9 @@ parity with the host oracle over randomized and adversarial batches is
 enforced by tests/test_ops_secp256k1.
 
 The host prologue is shared with the XLA kernel verbatim
-(secp256k1_verify.prep_item): strict-DER, low-s, w = s⁻¹ mod n, cached
-decompression — accept/reject cannot drift between backends.
+(secp256k1_verify.prep_batch): strict-DER, low-s, every lane's w = s⁻¹
+mod n from one inversion, cached decompression — accept/reject cannot
+drift between backends.
 """
 
 from __future__ import annotations
@@ -382,8 +383,9 @@ def verify_batch(
     the per-op full carry ripple; verdicts are bit-exact either way.
 
     Its spans are children of the caller's ``verify.dispatch``, the shared
-    names meaning what they mean in ed25519_pallas: ``secp.prologue`` (the
-    per-lane ``prep_item`` loop, which ed25519 has no counterpart of),
+    names meaning what they mean in ed25519_pallas: ``secp.prologue``
+    (``prep_batch``'s two passes round one inversion, which ed25519 has no
+    counterpart of),
     ``dispatch.pack``, ``dispatch.launch``, ``dispatch.wait``."""
     fe_backend = _fc.normalize_backend(fe_backend)
     carry_mode = _fc.normalize_carry_mode(carry_mode)
@@ -397,16 +399,15 @@ def verify_batch(
     reasons = []
     kernel_items = []  # (lane, item) for the lanes the device decides
     with trace.span("secp.prologue", n=n) as sp:
-        for i in range(n):
-            item = _xla.prep_item(
-                bytes(pubkeys[i]), bytes(digests[i]), bytes(sigs[i]))
+        items, inversions = _xla.prep_batch(pubkeys, digests, sigs)
+        for i, item in enumerate(items):
             if item[0] == "forced":
                 forced[i] = item[1]
                 reasons.append(item[2])
             else:
                 kernel_items.append((i, item))
-        sp.set(forced=len(reasons))
-    _xla.record_prologue(reasons)
+        sp.set(forced=len(reasons), inversions=inversions)
+    _xla.record_prologue(reasons, inversions)
 
     with trace.span("dispatch.pack", n=n, lanes=b):
         qx = np.zeros((b, NLIMB), np.uint32)

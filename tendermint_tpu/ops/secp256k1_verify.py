@@ -14,8 +14,9 @@ Same TPU-first skeleton as ops/ed25519_verify:
     curves (2016/1054 algorithm 7; b3 = 3·7 = 21). Complete = identity and
     doubling need no special cases, so the whole 256-iteration ladder is a
     single lax.fori_loop with pt_select, exactly like the ed25519 kernel;
-  * host prologue (cheap): strict-DER parse + low-s check, w = s⁻¹ mod n,
-    u1/u2, pubkey decompression with an LRU cache;
+  * host prologue (cheap): strict-DER parse + low-s check, w = s⁻¹ mod n
+    for all lanes from ONE modular inversion (Montgomery's trick), u1/u2,
+    pubkey decompression with a cache;
   * accept check: affine x ≡ r (mod n) done in limb space — x == r or
     x == r+n (the only two representatives below p), Z == 0 rejects.
 
@@ -424,10 +425,11 @@ def _decompress_cached(pub: bytes):
     return out
 
 
-def record_prologue(forced_reasons: Sequence[str]) -> None:
+def record_prologue(forced_reasons: Sequence[str], inversions: int = 0) -> None:
     """One dispatch's prologue in VerifyMetrics: the pubkey cache's hits and
-    misses since the last flush, and the lanes ``prep_item`` decided on the
-    host, by reason.  Telemetry never takes down the verify path."""
+    misses since the last flush, the lanes ``prep_batch`` decided on the
+    host, by reason, and the modular inversions it performed.  Telemetry
+    never takes down the verify path."""
     with _cache_mtx:
         hits, misses = _cache_looks
         _cache_looks[0] = _cache_looks[1] = 0
@@ -439,6 +441,8 @@ def record_prologue(forced_reasons: Sequence[str]) -> None:
             m.valset_cache.add(float(misses), ("secp256k1_pubkey", "miss"))
         for reason in forced_reasons:
             m.secp256k1_host_decided.add(1.0, (reason,))
+        if inversions:
+            m.secp256k1_inversions.add(float(inversions))
     except Exception:
         pass
 
@@ -464,30 +468,74 @@ def _bucket(n: int, mesh=None) -> int:
     return b
 
 
+_MALFORMED = ("forced", 0, "malformed")
+
+
+def _batch_inverse(xs: Sequence[int]) -> list:
+    """x⁻¹ mod n for every x by Montgomery's trick: prefix products, ONE
+    modular inversion of the total, the backward sweep.  Every x must be in
+    [1, n): n is prime, so the product of such factors is never 0 mod n."""
+    prefix = []
+    acc = 1
+    for x in xs:
+        prefix.append(acc)
+        acc = acc * x % N
+    inv = pow(acc, -1, N)
+    out = [0] * len(xs)
+    for i in range(len(xs) - 1, -1, -1):
+        out[i] = inv * prefix[i] % N
+        inv = inv * xs[i] % N
+    return out
+
+
+def prep_batch(pubkeys: Sequence[bytes], digests: Sequence[bytes],
+               sigs: Sequence[bytes]):
+    """Host prologue for one dispatch, in two passes round one inversion.
+    Pass one, a lane: cached decompression, strict-DER parse, range and
+    low-s checks; a lane refused there is ("forced", 0, "malformed") and
+    its s never enters the product.  Then every surviving lane's w = s⁻¹
+    mod n from ONE modular inversion (``_batch_inverse``: each s is in
+    [1, n/2]).  Pass two, a lane: u1 = e·w, u2 = r·w mod n; where either is
+    0 the ladder degenerates to a single scalar and the host oracle decides
+    (never happens for honestly generated signatures).
+
+    Returns (items, inversions): lane for lane either ("forced", 0|1,
+    reason) for host-decided items (reason "malformed" | "degenerate") or
+    ("kernel", (qx, qy), u1, u2, r) for device verification; and the
+    modular inversions performed, 1, or 0 when pass one refused every lane.
+    Shared by the XLA kernel and the Pallas pipeline so accept/reject can
+    never drift."""
+    n = len(pubkeys)
+    items = [_MALFORMED] * n
+    live = []  # (lane, Q, r) of the lanes pass one let through
+    ss = []
+    for i in range(n):
+        Q = _decompress_cached(bytes(pubkeys[i]))
+        parsed = _s.der_decode_sig(bytes(sigs[i]))
+        if Q is None or parsed is None:
+            continue
+        r, s = parsed
+        if not (0 < r < N and 0 < s < N) or s > _s._HALF_N:
+            continue
+        live.append((i, Q, r))
+        ss.append(s)
+    if not live:
+        return items, 0
+    for (i, Q, r), w in zip(live, _batch_inverse(ss)):
+        digest = bytes(digests[i])
+        u1 = int.from_bytes(digest, "big") * w % N
+        u2 = r * w % N
+        if u1 == 0 or u2 == 0:
+            ok = _s.verify(bytes(pubkeys[i]), digest, bytes(sigs[i]))
+            items[i] = ("forced", int(ok), "degenerate")
+        else:
+            items[i] = ("kernel", Q, u1, u2, r)
+    return items, 1
+
+
 def prep_item(pubkey: bytes, digest: bytes, sig: bytes):
-    """Host prologue for ONE signature: strict-DER parse + range/low-s
-    checks, w = s⁻¹ mod n, scalars, cached decompression. Returns either
-    ("forced", 0|1, reason) for host-decided items (reason "malformed": key,
-    DER, range or low-s refused here; "degenerate": u1 or u2 is 0 and the
-    host oracle decided) or ("kernel", (qx, qy), u1, u2, r) for device
-    verification. Shared by the XLA kernel and the Pallas pipeline so
-    accept/reject can never drift."""
-    Q = _decompress_cached(pubkey)
-    parsed = _s.der_decode_sig(sig)
-    if Q is None or parsed is None:
-        return ("forced", 0, "malformed")
-    r, s = parsed
-    if not (0 < r < N and 0 < s < N) or s > _s._HALF_N:
-        return ("forced", 0, "malformed")
-    e = int.from_bytes(digest, "big")
-    w = pow(s, N - 2, N)
-    u1 = e * w % N
-    u2 = r * w % N
-    if u1 == 0 or u2 == 0:
-        # ladder degenerates to single-scalar — host decides (never
-        # happens for honestly generated signatures)
-        return ("forced", int(_s.verify(pubkey, digest, sig)), "degenerate")
-    return ("kernel", Q, u1, u2, r)
+    """``prep_batch`` on one lane: its item."""
+    return prep_batch((pubkey,), (digest,), (sig,))[0][0]
 
 
 def verify_batch(
@@ -521,8 +569,8 @@ def verify_batch(
     forced = np.full((b,), -1, np.int8)
     reasons = []
 
-    for i in range(n):
-        item = prep_item(bytes(pubkeys[i]), bytes(digests[i]), bytes(sigs[i]))
+    items, inversions = prep_batch(pubkeys, digests, sigs)
+    for i, item in enumerate(items):
         if item[0] == "forced":
             forced[i] = item[1]
             reasons.append(item[2])
@@ -535,7 +583,7 @@ def verify_batch(
         if r + N < P:
             rnl[i] = int_to_limbs(r + N)
             rn_ok[i] = True
-    record_prologue(reasons)
+    record_prologue(reasons, inversions)
 
     kernel = _compiled_kernel(b, mesh, fe_backend, carry_mode)
     host = (qx, qy, u1w, u2w, rl, rnl, rn_ok)
