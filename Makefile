@@ -37,27 +37,23 @@ bench_fastsync:
 planner-bench:
 	JAX_PLATFORMS=cpu $(PYTHON) scripts/bench_fastsync.py --ragged-valsets
 
-# batched-verify throughput with the selected limb multiplier
-# (FE_BACKEND=vpu|mxu|mxu16); appends a round under build/pallas_bench and
+# batched-verify throughput; appends a round under build/pallas_bench and
 # gates ed25519_sigs_per_s (higher-is-better) plus the per-window ladder
 # slope (lower-is-better — the carry-schedule regression gate) against the
 # previous round.  Uses the Pallas kernel on a TPU; JAX_PLATFORMS=cpu asks
 # for the XLA kernel on the CPU instead (the round names backend and
-# platform), and with neither the script exits non-zero.  Only
-# FE_BACKEND=vpu lowers for TPU on the Pallas kernel.  The run also
+# platform), and with neither the script exits non-zero.  The run also
 # measures the one-MSM-per-window RLC path against the ladder at n=512
 # (ops/ed25519_msm) and gates its throughput, ed25519_msm_sigs_per_s, the
 # same way.
-FE_BACKEND ?= vpu
 pallas-bench:
-	$(PYTHON) scripts/profile_pallas.py \
-	  --fe-backend $(FE_BACKEND) --ed25519-path msm \
+	$(PYTHON) scripts/profile_pallas.py --ed25519-path msm \
 	  --round-dir build/pallas_bench \
 	  --metrics-out build/pallas_bench/verify_metrics.prom $(ARGS)
 	$(PYTHON) scripts/bench_check.py --dir build/pallas_bench \
-	  --metric "ed25519_sigs_per_s$(if $(filter-out vpu,$(FE_BACKEND)),_$(FE_BACKEND)):0.25:higher" \
-	  --metric "pallas_ladder_window_slope$(if $(filter-out vpu,$(FE_BACKEND)),_$(FE_BACKEND)):0.25:lower" \
-	  --metric "ed25519_msm_sigs_per_s$(if $(filter-out vpu,$(FE_BACKEND)),_$(FE_BACKEND)):0.25:higher"
+	  --metric "ed25519_sigs_per_s:0.25:higher" \
+	  --metric "pallas_ladder_window_slope:0.25:lower" \
+	  --metric "ed25519_msm_sigs_per_s:0.25:higher"
 
 bench_secp:
 	$(PYTHON) scripts/bench_secp.py 1024

@@ -295,12 +295,10 @@ def _self_check():
 
     vm = VerifyMetrics()
     vm.record_dispatch("host", "ed25519", 64, 0.012, rejects=1, first=True)
-    vm.record_dispatch("xla", "secp256k1", 128, 0.3, fe_backend="mxu",
-                       carry_mode="lazy")
-    vm.record_dispatch("pallas", "ed25519", 256, 0.1, fe_backend="vpu",
-                       carry_mode="eager")
+    vm.record_dispatch("xla", "secp256k1", 128, 0.3, carry_mode="lazy")
+    vm.record_dispatch("pallas", "ed25519", 256, 0.1, carry_mode="eager")
     # verify-strategy attribution ([verify] ed25519_path: ladder | msm)
-    vm.record_dispatch("planner_msm", "ed25519", 512, 0.05, fe_backend="vpu",
+    vm.record_dispatch("planner_msm", "ed25519", 512, 0.05,
                        carry_mode="lazy", ed25519_path="msm")
     vm.host_fallback.add(1.0, ("no_tpu",))
     vm.speculative.add(3.0, ("hit",))
@@ -462,9 +460,8 @@ def _self_check():
         "tendermint_verify_device_fallback_total",
         "tendermint_verify_device_retries_total",
         "tendermint_verify_device_audit_total",
-        # limb-multiplier backend + carry-schedule attribution
-        # ([verify] fe_backend / carry_mode label)
-        "tendermint_verify_fe_backend_total",
+        # carry-schedule + verify-strategy attribution of device dispatches
+        "tendermint_verify_path_total",
         # per-device lane/dispatch attribution (mesh superdispatch;
         # capped `device` label, excess ids fold into "overflow")
         "tendermint_verify_device_lanes_total",

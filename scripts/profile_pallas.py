@@ -11,16 +11,10 @@ Emits JSON lines:
   pallas_ladder_window_slope / pallas_ladder_fixed
                        — the w1/w16 least-cost split itself: slope is the
                          marginal cost of one Straus window (where the limb
-                         multiplier lives — the VPU-vs-MXU comparison row),
-                         fixed is table build + fe_inv + canonical compare
+                         multiplier lives), fixed is table build + fe_inv +
+                         canonical compare
   pallas_host_packing  — host-side packing with a warm decompression cache
   ed25519_sigs_per_s   — headline throughput (gated by scripts/bench_check.py)
-
-`--fe-backend {vpu,mxu,mxu16}` selects the limb multiplier ([verify]
-fe_backend); with a non-default backend every metric name is suffixed
-``_<backend>`` so a ledger keeps one row per backend.  Only "vpu" lowers for
-TPU on the Pallas path (crypto/batch.check_fe_backend_lowers); the MXU
-backends are measurable on the XLA kernel under JAX_PLATFORMS=cpu only.
 
 `--ed25519-path msm` ADDITIONALLY measures the one-MSM-per-window RLC
 path (ops/ed25519_msm) against the per-row ladder at n=512 on the XLA
@@ -87,7 +81,7 @@ def _make_corpus(n):
     return pubs, msgs, sigs
 
 
-def _profile_pallas(emit, fe_backend):
+def _profile_pallas(emit):
     import jax
     import jax.numpy as jnp
 
@@ -96,10 +90,10 @@ def _profile_pallas(emit, fe_backend):
     pubs, msgs, sigs = _make_corpus(N)
     print("# devices:", jax.devices(), file=sys.stderr)
 
-    ok = pk.verify_batch(pubs, msgs, sigs, fe_backend=fe_backend)  # warm
+    ok = pk.verify_batch(pubs, msgs, sigs)  # warm
     assert ok.all()
     e2e_ms = _median_ms(
-        lambda: pk.verify_batch(pubs, msgs, sigs, fe_backend=fe_backend)
+        lambda: pk.verify_batch(pubs, msgs, sigs)
     )
     emit("pallas_e2e_10k", e2e_ms)
 
@@ -128,7 +122,7 @@ def _profile_pallas(emit, fe_backend):
     prologue = jax.jit(lambda mw, sw: pk._prologue_call(mw, sw))
     ladder = jax.jit(
         lambda nx, ayy, digs, digh, rl, rs: pk._ladder_call(
-            nx, ayy, digs, digh, rl, rs, fe_backend=fe_backend
+            nx, ayy, digs, digh, rl, rs
         )
     )
 
@@ -158,7 +152,7 @@ def _profile_pallas(emit, fe_backend):
         digh_n = digh[:nwin]
         lad_n = jax.jit(
             lambda nx, ayy, dg, dh, rl, rs: pk._ladder_call(
-                nx, ayy, dg, dh, rl, rs, fe_backend=fe_backend
+                nx, ayy, dg, dh, rl, rs
             )
         )
         jax.block_until_ready(
@@ -171,8 +165,8 @@ def _profile_pallas(emit, fe_backend):
         )
         emit(f"pallas_ladder_w{nwin}", w_ms[nwin])
 
-    # the per-stage VPU/MXU comparison row: slope isolates the windowed
-    # point ops (where fe_mul lives), fixed the backend-invariant epilogue
+    # slope isolates the windowed point ops (where fe_mul lives), fixed
+    # the per-signature table build and epilogue
     slope = (w_ms[16] - w_ms[1]) / 15.0
     emit("pallas_ladder_window_slope", slope)
     emit("pallas_ladder_fixed", max(w_ms[1] - slope, 0.0))
@@ -190,14 +184,14 @@ def _profile_pallas(emit, fe_backend):
     return N, e2e_ms, "pallas"
 
 
-def _profile_xla(emit, fe_backend):
+def _profile_xla(emit):
     from tendermint_tpu.ops import ed25519_verify as xk
 
     pubs, msgs, sigs = _make_corpus(N_CPU)
-    ok = xk.verify_batch(pubs, msgs, sigs, fe_backend=fe_backend)  # compile
+    ok = xk.verify_batch(pubs, msgs, sigs)  # compile
     assert ok.all()
     e2e_ms = _median_ms(
-        lambda: xk.verify_batch(pubs, msgs, sigs, fe_backend=fe_backend),
+        lambda: xk.verify_batch(pubs, msgs, sigs),
         reps=3,
     )
     emit(f"xla_e2e_{N_CPU}", e2e_ms)
@@ -207,7 +201,7 @@ def _profile_xla(emit, fe_backend):
 N_MSM = 512
 
 
-def _profile_msm(emit, fe_backend):
+def _profile_msm(emit):
     """MSM-vs-ladder comparison at N_MSM rows on the XLA kernels.
 
     Both paths run on whatever platform jax resolved (the committed
@@ -218,21 +212,21 @@ def _profile_msm(emit, fe_backend):
     from tendermint_tpu.ops import ed25519_verify as xk
 
     pubs, msgs, sigs = _make_corpus(N_MSM)
-    ok = xk.verify_batch(pubs, msgs, sigs, fe_backend=fe_backend)  # compile
+    ok = xk.verify_batch(pubs, msgs, sigs)  # compile
     assert ok.all()
     lad_ms = _median_ms(
-        lambda: xk.verify_batch(pubs, msgs, sigs, fe_backend=fe_backend),
+        lambda: xk.verify_batch(pubs, msgs, sigs),
         reps=3,
     )
     emit(f"xla_ladder_{N_MSM}", lad_ms)
     seed = xk.rlc_seed(pubs, sigs)
     ok = xk.rlc_verify_batch(
-        pubs, msgs, sigs, fe_backend=fe_backend, seed=seed
+        pubs, msgs, sigs, seed=seed
     )  # compile
     assert ok.all()
     msm_ms = _median_ms(
         lambda: xk.rlc_verify_batch(
-            pubs, msgs, sigs, fe_backend=fe_backend, seed=seed
+            pubs, msgs, sigs, seed=seed
         ),
         reps=3,
     )
@@ -271,13 +265,9 @@ def main(argv=None):
         pop_metrics_out,
         write_snapshot,
     )
-    from tendermint_tpu.crypto.batch import check_fe_backend_lowers
 
     metrics_out = pop_metrics_out(argv)
     p = argparse.ArgumentParser(description=__doc__)
-    p.add_argument("--fe-backend", default="vpu",
-                   choices=("vpu", "mxu", "mxu16"),
-                   help="limb-multiplier backend ([verify] fe_backend)")
     p.add_argument("--ed25519-path", default="ladder",
                    choices=("ladder", "msm"),
                    help="msm: also bench the one-MSM-per-window RLC path "
@@ -286,65 +276,57 @@ def main(argv=None):
                    help="append a BENCH_rNN.json round under DIR "
                         "(for scripts/bench_check.py --dir DIR)")
     args = p.parse_args(argv)
-    be = args.fe_backend
-    suffix = "" if be == "vpu" else f"_{be}"
 
     def emit(metric, ms):
-        name = metric + suffix
-        _emitted[name] = round(ms, 3)
-        print(json.dumps({"metric": name, "value": round(ms, 3),
-                          "unit": "ms", "fe_backend": be}), flush=True)
+        _emitted[metric] = round(ms, 3)
+        print(json.dumps({"metric": metric, "value": round(ms, 3),
+                          "unit": "ms"}), flush=True)
 
     platform = bench_platform()
     if platform == "tpu":
-        check_fe_backend_lowers("pallas", be)
-        n, e2e_ms, kind = _profile_pallas(emit, be)
+        n, e2e_ms, kind = _profile_pallas(emit)
     else:
-        n, e2e_ms, kind = _profile_xla(emit, be)
+        n, e2e_ms, kind = _profile_xla(emit)
 
     sigs_per_s = round(n / (e2e_ms / 1e3), 1)
-    _emitted["ed25519_sigs_per_s" + suffix] = sigs_per_s
+    _emitted["ed25519_sigs_per_s"] = sigs_per_s
     # headline line: carries the metric under its own key too so the
     # driver's parsed-dict (last JSON line) gates by name in bench_check
     print(json.dumps({
-        "metric": "ed25519_sigs_per_s" + suffix,
+        "metric": "ed25519_sigs_per_s",
         "value": sigs_per_s,
         "unit": "sigs/s",
-        "fe_backend": be,
         "backend": kind,
         "platform": platform,
-        "ed25519_sigs_per_s" + suffix: sigs_per_s,
+        "ed25519_sigs_per_s": sigs_per_s,
     }), flush=True)
 
     if args.ed25519_path == "msm":
-        lad_ms, msm_ms = _profile_msm(emit, be)
+        lad_ms, msm_ms = _profile_msm(emit)
         lad_sps = round(N_MSM / (lad_ms / 1e3), 1)
         msm_sps = round(N_MSM / (msm_ms / 1e3), 1)
         speedup = round(lad_ms / msm_ms, 2) if msm_ms else 0.0
         for name, value, unit in (
-            (f"ed25519_ladder{N_MSM}_sigs_per_s" + suffix, lad_sps, "sigs/s"),
-            ("ed25519_msm_sigs_per_s" + suffix, msm_sps, "sigs/s"),
-            ("ed25519_msm_speedup" + suffix, speedup, "x"),
+            (f"ed25519_ladder{N_MSM}_sigs_per_s", lad_sps, "sigs/s"),
+            ("ed25519_msm_sigs_per_s", msm_sps, "sigs/s"),
+            ("ed25519_msm_speedup", speedup, "x"),
         ):
             _emitted[name] = value
             print(json.dumps({"metric": name, "value": value, "unit": unit,
-                              "fe_backend": be, name: value}), flush=True)
+                              name: value}), flush=True)
 
     try:
         from tendermint_tpu.libs.metrics import get_verify_metrics
 
         get_verify_metrics().record_dispatch(
-            kind, "ed25519", n, e2e_ms / 1e3, fe_backend=be,
-            # the kernels default to the lazy schedule; mxu16 has no lazy
-            # plan and degrades (fe_common.effective_carry_mode)
-            carry_mode="eager" if be == "mxu16" else "lazy",
+            kind, "ed25519", n, e2e_ms / 1e3,
+            carry_mode="lazy",  # the kernels' default schedule
             ed25519_path="ladder",
         )
         if args.ed25519_path == "msm":
             get_verify_metrics().record_dispatch(
-                "xla", "ed25519", N_MSM, msm_ms / 1e3, fe_backend=be,
-                carry_mode="eager" if be == "mxu16" else "lazy",
-                ed25519_path="msm",
+                "xla", "ed25519", N_MSM, msm_ms / 1e3,
+                carry_mode="lazy", ed25519_path="msm",
             )
     except Exception:
         pass

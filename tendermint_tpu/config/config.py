@@ -229,14 +229,6 @@ class VerifyConfig:
     audit_seed: int = 0
     # retries after a failed device dispatch before host fallback
     retries: int = 1
-    # limb-multiplier backend for the device verify kernels:
-    # "vpu" (elementwise schoolbook), "mxu" (int8-plane outer products), or
-    # "mxu16" (radix-2^16 repack; degrades to "mxu" on the XLA kernels).
-    # All are bit-exact, but only "vpu" lowers for TPU on the pallas
-    # backend: Mosaic rejects the MXU multipliers' dot_general, so
-    # pallas + mxu|mxu16 is refused at start with the compiler's reason
-    # (crypto/batch.check_fe_backend_lowers).  TM_FE_BACKEND env overrides.
-    fe_backend: str = "vpu"
     # device verify strategy: "ladder" (per-signature double-scalar
     # ladder, one lane per row) or "msm" (random-linear-combination
     # check — ONE Pippenger multi-scalar multiplication verifies the

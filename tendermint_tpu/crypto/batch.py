@@ -41,16 +41,16 @@ logger = logging.getLogger("tendermint_tpu.verify")
 
 
 def _record_dispatch(backend: str, algo: str, n: int, t0: float, ok,
-                     first: bool = False, fe_backend: str = "",
-                     carry_mode: str = "", ed25519_path: str = "") -> None:
+                     first: bool = False, carry_mode: str = "",
+                     ed25519_path: str = "") -> None:
     """One VerifyMetrics record per batch dispatch (size, latency, rejects,
-    and which limb-multiplier backend / carry schedule / verify strategy
-    served the window).  Telemetry must never take down the verify path."""
+    and which carry schedule / verify strategy served the window).
+    Telemetry must never take down the verify path."""
     try:
         get_verify_metrics().record_dispatch(
             backend, algo, n, time.perf_counter() - t0,
             rejects=n - int(np.count_nonzero(ok)), first=first,
-            fe_backend=fe_backend, carry_mode=carry_mode,
+            carry_mode=carry_mode,
             ed25519_path=ed25519_path,
         )
     except Exception:
@@ -63,30 +63,24 @@ class VerifyConfigError(ValueError):
     device fault and a quiet host run."""
 
 
-# limb-multiplier backends for the device kernels (ops/fe_common.FE_BACKENDS;
-# duplicated here so pure-host users never import jax through this module)
-_FE_BACKENDS = ("vpu", "mxu", "mxu16")
-_default_fe_backend: Optional[str] = None
-
-
-def set_default_fe_backend(value: Optional[str]) -> None:
-    """Install the process-wide [verify] fe_backend choice (node composition
-    root).  TM_FE_BACKEND still overrides per-process."""
-    global _default_fe_backend
-    _default_fe_backend = value or None
-
-
-def _resolve_fe_backend(explicit: Optional[str]) -> str:
-    v = explicit or os.environ.get("TM_FE_BACKEND", "") or \
-        _default_fe_backend or "vpu"
-    v = v.strip().lower()
-    if v in ("", "auto"):
-        return "vpu"
-    if v not in _FE_BACKENDS:
-        raise VerifyConfigError(
-            f"fe_backend must be one of {_FE_BACKENDS}, got {v!r}"
-        )
-    return v
+def check_removed_options(verify_section=None) -> None:
+    """Refuse an option this version removed, where it still arrives from
+    outside: ``fe_backend`` on a [verify] section written for an earlier
+    build, or ``TM_FE_BACKEND`` on a launch line.  The VPU schoolbook is the
+    only limb multiplier (``mxu`` and ``mxu16`` never lowered for TPU), so
+    absent, empty, ``auto`` or ``vpu`` asks for what runs and is ignored;
+    anything else stops the program with the reason — it never runs with a
+    different multiplier than the operator wrote."""
+    for where, value in (
+        ("TM_FE_BACKEND", os.environ.get("TM_FE_BACKEND")),
+        ("[verify] fe_backend", getattr(verify_section, "fe_backend", None)),
+    ):
+        if str(value or "").strip().lower() not in ("", "auto", "vpu"):
+            raise VerifyConfigError(
+                f"{where}={value!r}: the fe_backend option was removed in "
+                "this version; the VPU limb multiplier is the only one. "
+                "Drop the setting (or write vpu)."
+            )
 
 
 # device verify strategies (ops/ed25519_verify.verify_batch vs the
@@ -209,24 +203,10 @@ class RLCHostVerifier(HostBatchVerifier):
         return ok
 
 
-# Why the MXU limb multipliers cannot ride the Pallas kernels: quoted in the
-# refusal so the operator sees the compiler's reason, not a dispatch error.
-MXU_PALLAS_REFUSAL = (
-    "fe_backend={fe!r} does not lower for TPU on the pallas backend: "
-    "ops/fe_common._plane_outer issues a dot_general with a batch dimension "
-    "and no contracting dimension, which Mosaic rejects "
-    "(MLIRError: failed to parse 'lhs_contracting_dims' of "
-    "#tpu.dot_dimension_numbers<[],[],[0],[0],...>). "
-    "Use fe_backend=vpu, or the xla backend."
-)
-
-
-def check_fe_backend_lowers(backend: str, fe_backend: str) -> None:
-    """Refuse a (device backend, limb multiplier) pair the TPU compiler is
-    known to reject — at construction/config time, never at first dispatch
-    inside the guard, where it would read as a device fault."""
-    if backend == "pallas" and fe_backend in ("mxu", "mxu16"):
-        raise VerifyConfigError(MXU_PALLAS_REFUSAL.format(fe=fe_backend))
+# the carry schedule the device kernels trace with when called from here
+# (their default, which this module never overrides): the dispatch counter's
+# carry_mode label
+_KERNEL_CARRY_MODE = "lazy"
 
 
 class TPUBatchVerifier:
@@ -235,11 +215,6 @@ class TPUBatchVerifier:
     backend: "pallas" (fused kernel, needs a TPU as the default jax
     backend), "xla" (portable, mesh-shardable), or None = pallas when
     ``jax.devices()[0]`` is a TPU and no mesh was requested, else xla.
-
-    fe_backend: limb multiplier for the device kernels ("vpu" | "mxu" |
-    "mxu16"; ops/fe_common).  None = TM_FE_BACKEND env, then the [verify]
-    fe_backend config (set_default_fe_backend), then "vpu".  The MXU
-    multipliers exist on the xla backend only (check_fe_backend_lowers).
 
     ed25519_path: "ladder" verifies one signature per lane with the
     double-scalar ladder kernel; "msm" folds the whole window into ONE
@@ -253,15 +228,8 @@ class TPUBatchVerifier:
     name = "tpu"
 
     def __init__(self, mesh=None, backend: Optional[str] = None,
-                 fe_backend: Optional[str] = None,
                  ed25519_path: Optional[str] = None):
-        self.fe_backend = _resolve_fe_backend(fe_backend)
         self.ed25519_path = _resolve_ed25519_path(ed25519_path)
-        # carry schedule the kernels will trace with — the kernels default
-        # to lazy and degrade mxu16 to eager themselves
-        # (fe_common.effective_carry_mode); mirrored here, without the jax
-        # import, so telemetry labels match what actually ran
-        self.carry_mode = "eager" if self.fe_backend == "mxu16" else "lazy"
         self._mesh = mesh
         # deferred import: keep jax out of pure-host users
         from tendermint_tpu.ops import dispatch as _dispatch
@@ -275,7 +243,6 @@ class TPUBatchVerifier:
                 f"jax.devices()[0] is {_dispatch.device_info()} "
                 f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '')!r})"
             )
-        check_fe_backend_lowers(backend, self.fe_backend)
         self.backend = backend
         self.device = _dispatch.device_info()
         if backend == "pallas":
@@ -335,23 +302,19 @@ class TPUBatchVerifier:
                     if self.ed25519_path == "msm"
                     else self._kernel.verify_batch
                 )
-                ok = verify(pubs_a, msgs, sigs_a, fe_backend=self.fe_backend)
+                ok = verify(pubs_a, msgs, sigs_a)
             elif self.ed25519_path == "msm":
                 # the MSM folds the window into one point equation — there
                 # is no lane axis to shard, so the mesh is not consulted
-                ok = self._kernel.rlc_verify_batch(
-                    pubs_a, msgs, sigs_a, fe_backend=self.fe_backend,
-                )
+                ok = self._kernel.rlc_verify_batch(pubs_a, msgs, sigs_a)
             else:
                 ok = self._kernel.verify_batch(
                     pubs_a, msgs, sigs_a, mesh=self._mesh,
-                    fe_backend=self.fe_backend,
                 )
         ok = np.asarray(ok, dtype=bool)
         self._warm.add("ed25519")
         _record_dispatch(self.backend, "ed25519", len(pubs), t0, ok,
-                         first=first, fe_backend=self.fe_backend,
-                         carry_mode=self.carry_mode,
+                         first=first, carry_mode=_KERNEL_CARRY_MODE,
                          ed25519_path=self.ed25519_path)
         return ok
 
@@ -375,18 +338,15 @@ class TPUBatchVerifier:
             if self.backend == "pallas":
                 from tendermint_tpu.ops import secp256k1_pallas as _skp
 
-                ok = _skp.verify_batch(pubs, digs, sigs,
-                                       fe_backend=self.fe_backend)
+                ok = _skp.verify_batch(pubs, digs, sigs)
             else:
                 from tendermint_tpu.ops import secp256k1_verify as _sk
 
-                ok = _sk.verify_batch(pubs, digs, sigs, mesh=self._mesh,
-                                      fe_backend=self.fe_backend)
+                ok = _sk.verify_batch(pubs, digs, sigs, mesh=self._mesh)
         ok = np.asarray(ok, dtype=bool)
         self._warm.add("secp256k1")
         _record_dispatch(self.backend, "secp256k1", len(items), t0, ok,
-                         first=first, fe_backend=self.fe_backend,
-                         carry_mode=self.carry_mode)
+                         first=first, carry_mode=_KERNEL_CARRY_MODE)
         return ok
 
 
@@ -711,6 +671,7 @@ def get_batch_verifier(prefer_tpu: bool = True):
 
     with _lock:
         if _default is None:
+            check_removed_options()
             forced = os.environ.get("TM_BATCH_VERIFIER", "").lower()
             if forced == "host":
                 _default = HostBatchVerifier()

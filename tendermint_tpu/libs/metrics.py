@@ -443,17 +443,15 @@ class VerifyMetrics:
             "line), harvest (took a speculation), empty (under two blocks)",
             label_names=("result",),
         )
-        # limb-multiplier attribution: which fe backend (ops/fe_common)
-        # served each device window — vpu | mxu | mxu16 — which carry
-        # schedule it traced with (eager | lazy), and which verify
-        # strategy decided the window (ladder | msm; ops/ed25519_msm);
-        # host dispatches carry no fe backend and are not recorded here
-        self.fe_dispatch = r.counter(
-            "verify_fe_backend_total",
-            "Batch-verify device dispatches by limb-multiplier backend, "
-            "carry schedule and ed25519 verify path",
-            label_names=("backend", "fe_backend", "carry_mode",
-                         "ed25519_path"),
+        # path attribution: which carry schedule each device window traced
+        # with (eager | lazy; ops/fe_common) and which verify strategy
+        # decided it (ladder | msm; ops/ed25519_msm); host dispatches carry
+        # no carry mode and are not recorded here
+        self.path_dispatch = r.counter(
+            "verify_path_total",
+            "Batch-verify device dispatches by carry schedule and ed25519 "
+            "verify path",
+            label_names=("backend", "carry_mode", "ed25519_path"),
         )
         # per-device attribution of mesh superdispatches: which devices the
         # lane tile sharded across and how many lanes each shard carried.
@@ -494,8 +492,7 @@ class VerifyMetrics:
 
     def record_dispatch(self, backend: str, algo: str, n: int,
                         seconds: float, rejects: int = 0,
-                        first: bool = False, fe_backend: str = "",
-                        carry_mode: str = "",
+                        first: bool = False, carry_mode: str = "",
                         ed25519_path: str = "") -> None:
         """One batch dispatch: size + latency + outcome in one call so the
         instrumented hot paths stay one-liners."""
@@ -507,10 +504,9 @@ class VerifyMetrics:
         self.sigs.add(float(n), (backend, algo))
         if rejects:
             self.rejects.add(float(rejects), (backend, algo))
-        if fe_backend:
-            self.fe_dispatch.add(
-                1.0,
-                (backend, fe_backend, carry_mode, ed25519_path or "ladder"),
+        if carry_mode:
+            self.path_dispatch.add(
+                1.0, (backend, carry_mode, ed25519_path or "ladder"),
             )
 
     def record_planner(self, present: int, dispatched: int,

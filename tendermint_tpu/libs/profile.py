@@ -93,7 +93,6 @@ class Profiler:
         run_seconds: float = 0.0,
         compiled: bool = False,
         bytes_to_device: int = 0,
-        fe_backend: str = "",
         carry_mode: str = "",
         ed25519_path: str = "",
         n_windows: int = 1,
@@ -106,12 +105,8 @@ class Profiler:
             # dispatch and mesh devices the lane tile sharded across
             "n_windows": int(n_windows),
             "n_devices": int(n_devices),
-            # limb-multiplier backend that served this dispatch
-            # (ops/fe_common: vpu | mxu | mxu16; "" = host / not applicable)
-            "fe_backend": str(fe_backend),
             # carry schedule the dispatch traced with (eager | lazy;
-            # "" = host / not applicable) — the effective mode after
-            # fe_common.effective_carry_mode's mxu16 degrade
+            # "" = host / not applicable)
             "carry_mode": str(carry_mode),
             # verify strategy (ladder | msm; "" = host / not applicable):
             # msm = one RLC Pippenger MSM per window (ops/ed25519_msm)
@@ -201,7 +196,6 @@ class Profiler:
                     "windows": 0,
                     "n_devices": 1,
                     "kinds": [],
-                    "fe_backends": [],
                     "carry_modes": [],
                     "ed25519_paths": [],
                     "buckets": [],
@@ -220,9 +214,6 @@ class Profiler:
             row["n_devices"] = max(row["n_devices"], e.get("n_devices", 1))
             if e["kind"] not in row["kinds"]:
                 row["kinds"].append(e["kind"])
-            fb = e.get("fe_backend", "")
-            if fb and fb not in row["fe_backends"]:
-                row["fe_backends"].append(fb)
             cm = e.get("carry_mode", "")
             if cm and cm not in row["carry_modes"]:
                 row["carry_modes"].append(cm)

@@ -173,14 +173,14 @@ class Node(BaseService):
 
         configure_device_guard(config.verify)
 
-        # [verify] fe_backend: which limb multiplier serves device verify
-        # windows (vpu schoolbook vs MXU int8-plane matmuls; ops/fe_common)
         from tendermint_tpu.crypto.batch import (
+            check_removed_options,
             set_default_ed25519_path,
-            set_default_fe_backend,
         )
 
-        set_default_fe_backend(getattr(config.verify, "fe_backend", None))
+        # a [verify] section written for an earlier build may still name an
+        # option this one removed: refused here, with the reason
+        check_removed_options(config.verify)
         # [verify] ed25519_path: per-row ladder vs one-MSM-per-window RLC
         set_default_ed25519_path(getattr(config.verify, "ed25519_path", None))
 
@@ -192,11 +192,10 @@ class Node(BaseService):
 
         # the batch verifier is chosen ONCE, here, from the environment
         # (TM_BATCH_VERIFIER, then jax.devices() under JAX_PLATFORMS) — not
-        # lazily at the first commit.  A [verify] combination the TPU
-        # compiler rejects (fe_backend = mxu on the pallas backend) raises
-        # now, with the reason; a chipless host gets the host verifier and
-        # a logged no_tpu.  cmd/tendermint prints the line beside "Node
-        # started"; /status serves verifier_info().
+        # lazily at the first commit.  A [verify] / TM_* choice that cannot
+        # be served raises now, with the reason; a chipless host gets the
+        # host verifier and a logged no_tpu.  cmd/tendermint prints the
+        # line beside "Node started"; /status serves verifier_info().
         from tendermint_tpu.crypto.batch import (
             describe_verifier,
             get_batch_verifier,

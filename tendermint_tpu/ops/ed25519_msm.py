@@ -44,9 +44,8 @@ device graph is static:
     projective identity check (canonical X == 0 and Y == Z).
 
 Index arrays ride as DYNAMIC jit arguments, so the compile cache keys only
-on shapes + (fe_backend, carry_mode); level widths are padded to the
-power-of-two/1024 ladder to keep those shapes stable across RLC coefficient
-draws.  Scalars are sampled from a seeded ``random.Random`` so the
+on shapes + carry_mode; level widths are padded to the power-of-two/1024
+ladder to keep those shapes stable across RLC coefficient draws.  Scalars are sampled from a seeded ``random.Random`` so the
 audit/replay paths stay deterministic.
 
 Localization mirrors the host verifier: an MSM-rejected window re-runs
@@ -318,19 +317,14 @@ def _msm_kernel(pool, ias, ibs, bkt_idx, sb_digs):
 _msm_cache: dict = {}
 
 
-def _compiled_msm(fe_backend: str, carry_mode: str):
-    """One jitted kernel per (fe_backend, carry_mode) — jax.jit's own cache
-    keys the shape side (pool width, level widths, window count), so index
-    schedules ride as dynamic arguments without retraces."""
-    carry_mode = _fc.effective_carry_mode(fe_backend, carry_mode)
-    if fe_backend not in ("vpu", "mxu"):
-        fe_backend = "mxu" if fe_backend == "mxu16" else "vpu"
-    key = (fe_backend, carry_mode)
-    fn = _msm_cache.get(key)
+def _compiled_msm(carry_mode: str):
+    """One jitted kernel per carry mode — jax.jit's own cache keys the shape
+    side (pool width, level widths, window count), so index schedules ride
+    as dynamic arguments without retraces."""
+    fn = _msm_cache.get(carry_mode)
     if fn is None:
-        fn = jax.jit(_fc.trace_with_modes(_xla, _msm_kernel,
-                                          fe_backend, carry_mode))
-        _msm_cache[key] = fn
+        fn = jax.jit(_fc.trace_with_modes(_xla, _msm_kernel, carry_mode))
+        _msm_cache[carry_mode] = fn
     return fn
 
 
@@ -339,7 +333,7 @@ def _compiled_msm(fe_backend: str, carry_mode: str):
 # ---------------------------------------------------------------------------
 
 
-def _device_rlc(rows, rng, fe_backend: str, carry_mode: str) -> bool:
+def _device_rlc(rows, rng, carry_mode: str) -> bool:
     """One RLC over parsed rows [(neg_a, neg_r, h, s), ...] (extended-point
     int tuples) as a single device MSM dispatch.  z_i are drawn from ``rng``
     (seeded upstream — deterministic replay)."""
@@ -373,7 +367,7 @@ def _device_rlc(rows, rng, fe_backend: str, carry_mode: str) -> bool:
         np.uint32,
     )
     ok = call_jit(
-        _compiled_msm(fe_backend, carry_mode),
+        _compiled_msm(carry_mode),
         jnp.asarray(pool),
         [jnp.asarray(a) for a in sched.ias],
         [jnp.asarray(b) for b in sched.ibs],
@@ -404,7 +398,6 @@ def rlc_resolve(
     ladder_fn: Callable[[List[int]], np.ndarray],
     *,
     seed: int,
-    fe_backend: str = "vpu",
     carry_mode: str = "lazy",
 ) -> None:
     """Verdict strategy for one window: device MSM accept-all on the clean
@@ -416,7 +409,7 @@ def rlc_resolve(
         return
     rng = random.Random(seed)
     rows = [(na, nr, h, s) for (_, na, nr, h, s) in parsed]
-    if _device_rlc(rows, rng, fe_backend, carry_mode):
+    if _device_rlc(rows, rng, carry_mode):
         for item in parsed:
             out[item[0]] = True
         return

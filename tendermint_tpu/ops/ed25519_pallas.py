@@ -13,13 +13,11 @@ kernel (the XLA version materializes every field-op intermediate to HBM).
 Algorithm (per 128-lane block, batch on lanes, limbs on sublanes):
 
   * Field arithmetic over GF(2^255-19), 20 radix-2^13 uint32 limbs in a
-    (20, B) layout — shared with the secp256k1 kernel via ops/fe_common.py,
-    which also provides the MXU limb multiplier (int8-plane fe_mul behind
-    the [verify] fe_backend knob; the VPU schoolbook remains the default).
-    Overflow bounds are no longer hand-stated here: fe_common's bound_*
-    propagators recompute the closed set mechanically for every backend,
-    and tests/test_fe_common.py asserts closure (carried limbs <= 13000)
-    and that no intermediate reaches 2^32.
+    (20, B) layout — shared with the secp256k1 kernel via ops/fe_common.py
+    (the VPU schoolbook multiplier).  Overflow bounds are not hand-stated
+    here: fe_common's bound_* propagators recompute the closed set
+    mechanically, and tests/test_fe_common.py asserts closure (carried
+    limbs <= 13000) and that no intermediate reaches 2^32.
   * Double-scalar mult R' = [s]B + [h](-A) via 4-bit windowed Straus:
     64 MSB-first windows sharing 252 doublings; per window one mixed add
     from a constant niels table [0..15]B (affine, identity at digit 0) and
@@ -74,20 +72,18 @@ _D2_LIMBS = _xla._D2_LIMBS
 
 int_to_limbs = _xla.int_to_limbs
 
-# Field ops live in ops/fe_common.py now (one copy serves both curves and
-# all fe backends); these module-level names keep the original surface.
-# Namespaces are built on demand per (backend, carry mode) — the lazy ones
-# run derive_carry_plan's chain certification on first use.
-_FE = {(b, "eager"): _fc.make_fe("ed25519", b) for b in _fc.FE_BACKENDS}
-_FE_VPU = _FE[("vpu", "eager")]
+# Field ops live in ops/fe_common.py (one copy serves both curves); these
+# module-level names keep the original surface.  Namespaces are built on
+# demand per carry mode — the lazy one runs derive_carry_plan's chain
+# certification on first use.
+_FE = {"eager": _fc.make_fe("ed25519")}
+_FE_EAGER = _FE["eager"]
 
 
-def _get_fe(backend: str, carry_mode: str = "eager"):
-    mode = _fc.effective_carry_mode(backend, carry_mode)
-    key = (backend, mode)
-    if key not in _FE:
-        _FE[key] = _fc.make_fe("ed25519", backend, carry_mode=mode)
-    return _FE[key]
+def _get_fe(carry_mode: str = "eager"):
+    if carry_mode not in _FE:
+        _FE[carry_mode] = _fc.make_fe("ed25519", carry_mode=carry_mode)
+    return _FE[carry_mode]
 
 _shift_rows_down = _fc.shift_rows_down
 fe_carry1 = _fc.ed_fe_carry1
@@ -103,7 +99,7 @@ fe_inv = _fc.ed_fe_inv
 # ---------------------------------------------------------------------------
 
 
-def pt_add(p, q, d2, ksub, fe=_FE_VPU, kd=None):
+def pt_add(p, q, d2, ksub, fe=_FE_EAGER, kd=None):
     X1, Y1, Z1, T1 = p
     X2, Y2, Z2, T2 = q
     if fe.carry_mode == "lazy":
@@ -132,7 +128,7 @@ def pt_add(p, q, d2, ksub, fe=_FE_VPU, kd=None):
     return fe.mul(E, F), fe.mul(G, H), fe.mul(F, G), fe.mul(E, H)
 
 
-def pt_madd(p, ypx, ymx, t2d, ksub, fe=_FE_VPU, kd=None):
+def pt_madd(p, ypx, ymx, t2d, ksub, fe=_FE_EAGER, kd=None):
     """Mixed add with a precomputed niels point (y+x, y-x, 2dxy), Z=1.
     Digit 0 maps to (1, 1, 0) and yields p unchanged (scaled) — identity-safe."""
     X1, Y1, Z1, T1 = p
@@ -182,7 +178,7 @@ def pt_to_cached(p, d2, ksub, fe):
     return fe.add(Y, X), fe.sub(Y, X, ksub), Z, fe.mul(T, d2)
 
 
-def pt_double(p, ksub, fe=_FE_VPU, kd=None):
+def pt_double(p, ksub, fe=_FE_EAGER, kd=None):
     X1, Y1, Z1, _ = p
     if fe.carry_mode == "lazy":
         A = fe.mul_lazy(X1, X1)
@@ -283,8 +279,7 @@ def _canonical_ref(v, s1, s2):
 
 
 def ladder_math(consts, negax, ay, digs_get, digh_get, nwin: int = NWIN,
-                loop=lax.fori_loop, fe_backend: str = "vpu",
-                carry_mode: str = "lazy"):
+                loop=lax.fori_loop, carry_mode: str = "lazy"):
     """The windowed-Straus double-scalar multiply [s]B + [h](-A) — pure jnp,
     shared by the pallas kernel (on ref values) and the CPU parity tests
     (tests/test_pallas_interpret.py).  digs_get/digh_get: t -> (1, B)
@@ -292,14 +287,12 @@ def ladder_math(consts, negax, ay, digs_get, digh_get, nwin: int = NWIN,
     nwin < NWIN drives the identical code with small scalars; tests also
     swap `loop` for a plain Python loop so the whole thing evaluates
     eagerly (XLA's CPU compile of these graphs runs minutes — its
-    simplifier thrashes on the carry patterns).  fe_backend picks the limb
-    multiplier (fe_common.FE_BACKENDS); carry_mode picks eager (one carry
-    ripple per field op) or lazy (one per point op; the default — mxu16
-    degrades to eager).  Returns (X, Y, Z, T) with limbs in the certified
-    carried class of the active mode (congruent mod p across modes)."""
-    mode = _fc.effective_carry_mode(fe_backend, carry_mode)
-    fe = _get_fe(fe_backend, mode)
-    lazy = mode == "lazy"
+    simplifier thrashes on the carry patterns).  carry_mode picks eager (one
+    carry ripple per field op) or lazy (one per point op; the default).
+    Returns (X, Y, Z, T) with limbs in the certified carried class of the
+    active mode (congruent mod p across modes)."""
+    fe = _get_fe(carry_mode)
+    lazy = carry_mode == "lazy"
     B = negax.shape[1]
     zero = jnp.zeros((NLIMB, B), jnp.uint32)
     one = jnp.pad(jnp.ones((1, B), jnp.uint32), ((0, NLIMB - 1), (0, 0)))
@@ -354,7 +347,7 @@ def ladder_math(consts, negax, ay, digs_get, digh_get, nwin: int = NWIN,
 
 def _ladder_kernel(consts_ref, negax_ref, ay_ref, digs_ref, digh_ref,
                    rlimb_ref, rsign_ref, out_ref, s1, s2,
-                   fe_backend: str = "vpu", carry_mode: str = "lazy"):
+                   carry_mode: str = "lazy"):
     # window count comes from the digit rows: production always passes
     # (NWIN, B), while reduced parity tests drive the identical math with
     # fewer windows (small scalars)
@@ -363,13 +356,12 @@ def _ladder_kernel(consts_ref, negax_ref, ay_ref, digs_ref, digh_ref,
         lambda t: digs_ref[pl.ds(t, 1), :],
         lambda t: digh_ref[pl.ds(t, 1), :],
         nwin=digs_ref.shape[0],
-        fe_backend=fe_backend,
         carry_mode=carry_mode,
     )
 
     # Under lazy, fe.inv/fe.mul run on mulF and keep the epilogue inside the
     # certified class C (max limb < M), so _canonical_ref's domain holds.
-    fe = _get_fe(fe_backend, carry_mode)
+    fe = _get_fe(carry_mode)
     zinv = fe.inv(Z)
     x = _canonical_ref(fe.mul(X, zinv), s1, s2)
     y = _canonical_ref(fe.mul(Y, zinv), s1, s2)
@@ -379,7 +371,7 @@ def _ladder_kernel(consts_ref, negax_ref, ay_ref, digs_ref, digh_ref,
 
 
 def _ladder_call(negax, ay, digs, digh, rlimb, rsign, *, interpret=False,
-                 lanes=LANES, fe_backend="vpu", carry_mode="lazy"):
+                 lanes=LANES, carry_mode="lazy"):
     """negax/ay/rlimb (20, N), digs/digh (nwin, N) — NWIN=64 in production,
     fewer in the reduced interpret tests — rsign (1, N); N % lanes == 0."""
     n = negax.shape[1]
@@ -389,7 +381,7 @@ def _ladder_call(negax, ay, digs, digh, rlimb, rsign, *, interpret=False,
     spec64 = pl.BlockSpec((nwin, lanes), lambda i: (0, i), memory_space=pltpu.VMEM)
     spec1 = pl.BlockSpec((1, lanes), lambda i: (0, i), memory_space=pltpu.VMEM)
     return pl.pallas_call(
-        partial(_ladder_kernel, fe_backend=fe_backend, carry_mode=carry_mode),
+        partial(_ladder_kernel, carry_mode=carry_mode),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.uint32),
         grid=(n // lanes,),
         in_specs=[cspec, spec20, spec20, spec64, spec64, spec20, spec1],
@@ -413,7 +405,33 @@ _H0_PAIRS = np.array(
         0x1F83D9ABFB41BD6B, 0x5BE0CD19137E2179)],
     dtype=np.uint32,
 )
-from tendermint_tpu.ops.sha512_batch import _K as _K64  # round constants
+
+# SHA-512 round constants (FIPS 180-4).
+_K64 = np.array(
+    [
+        0x428A2F98D728AE22, 0x7137449123EF65CD, 0xB5C0FBCFEC4D3B2F, 0xE9B5DBA58189DBBC,
+        0x3956C25BF348B538, 0x59F111F1B605D019, 0x923F82A4AF194F9B, 0xAB1C5ED5DA6D8118,
+        0xD807AA98A3030242, 0x12835B0145706FBE, 0x243185BE4EE4B28C, 0x550C7DC3D5FFB4E2,
+        0x72BE5D74F27B896F, 0x80DEB1FE3B1696B1, 0x9BDC06A725C71235, 0xC19BF174CF692694,
+        0xE49B69C19EF14AD2, 0xEFBE4786384F25E3, 0x0FC19DC68B8CD5B5, 0x240CA1CC77AC9C65,
+        0x2DE92C6F592B0275, 0x4A7484AA6EA6E483, 0x5CB0A9DCBD41FBD4, 0x76F988DA831153B5,
+        0x983E5152EE66DFAB, 0xA831C66D2DB43210, 0xB00327C898FB213F, 0xBF597FC7BEEF0EE4,
+        0xC6E00BF33DA88FC2, 0xD5A79147930AA725, 0x06CA6351E003826F, 0x142929670A0E6E70,
+        0x27B70A8546D22FFC, 0x2E1B21385C26C926, 0x4D2C6DFC5AC42AED, 0x53380D139D95B3DF,
+        0x650A73548BAF63DE, 0x766A0ABB3C77B2A8, 0x81C2C92E47EDAEE6, 0x92722C851482353B,
+        0xA2BFE8A14CF10364, 0xA81A664BBC423001, 0xC24B8B70D0F89791, 0xC76C51A30654BE30,
+        0xD192E819D6EF5218, 0xD69906245565A910, 0xF40E35855771202A, 0x106AA07032BBD1B8,
+        0x19A4C116B8D2D0C8, 0x1E376C085141AB53, 0x2748774CDF8EEB99, 0x34B0BCB5E19B48A8,
+        0x391C0CB3C5C95A63, 0x4ED8AA4AE3418ACB, 0x5B9CCA4F7763E373, 0x682E6FF3D6B2B8A3,
+        0x748F82EE5DEFB2FC, 0x78A5636F43172F60, 0x84C87814A1F0AB72, 0x8CC702081A6439EC,
+        0x90BEFFFA23631E28, 0xA4506CEBDE82BDE9, 0xBEF9A3F7B2C67915, 0xC67178F2E372532B,
+        0xCA273ECEEA26619C, 0xD186B8C721C0C207, 0xEADA7DD6CDE0EB1E, 0xF57D4F7FEE6ED178,
+        0x06F067AA72176FBA, 0x0A637DC5A2C898A6, 0x113F9804BEF90DAE, 0x1B710B35131C471B,
+        0x28DB77F523047D84, 0x32CAAB7B40C72493, 0x3C9EBE0A15C9BEBC, 0x431D67C49C100D4C,
+        0x4CC5D4BECB3E42B6, 0x597F299CFC657E2A, 0x5FCB6FAB3AD6FAEC, 0x6C44198C4A475817,
+    ],
+    dtype=np.uint64,
+)
 
 _K_PAIRS = np.stack([(_K64 >> np.uint64(32)).astype(np.uint32),
                      (_K64 & np.uint64(0xFFFFFFFF)).astype(np.uint32)], axis=1)
@@ -528,7 +546,7 @@ def _digest_byte(state, m):
     return (src >> shift) & 0xFF
 
 
-# Barrett constants in radix-2^13 (see sha512_batch.py for the host mirror)
+# Barrett constants in radix-2^13
 _QL = 21
 _MU_LIMBS_D = np.array(
     [( ((1 << (BITS * 40)) // L_ORDER) >> (BITS * i)) & MASK for i in range(_QL + 1)],
@@ -675,7 +693,7 @@ def _prologue_call(msg_words, sig_words, *, interpret=False, lanes=LANES):
 
 
 def _device_verify(negax, ay, sig_words, msg_words, interpret=False,
-                   lanes=LANES, fe_backend="vpu", carry_mode="lazy"):
+                   lanes=LANES, carry_mode="lazy"):
     """negax/ay (N, 20) uint32; sig_words (N, 16) uint32 LE; msg_words
     (N, nblocks*32) uint32 BE padded SHA-512 input. Returns (N,) bool."""
     digs, digh, rlimb, rsign = _prologue_call(
@@ -683,7 +701,7 @@ def _device_verify(negax, ay, sig_words, msg_words, interpret=False,
     )
     ok = _ladder_call(
         negax.T, ay.T, digs, digh, rlimb, rsign, interpret=interpret,
-        lanes=lanes, fe_backend=fe_backend, carry_mode=carry_mode,
+        lanes=lanes, carry_mode=carry_mode,
     )
     return ok[0].astype(bool)
 
@@ -692,13 +710,13 @@ def _device_verify(negax, ay, sig_words, msg_words, interpret=False,
 # function is called eagerly instead: tracing the interpreted kernels into one
 # jit graph explodes into thousands of scalar XLA ops (a 6-minute CPU compile).
 _device_verify_jit = partial(
-    jax.jit, static_argnames=("interpret", "lanes", "fe_backend", "carry_mode")
+    jax.jit, static_argnames=("interpret", "lanes", "carry_mode")
 )(_device_verify)
 
 
-@partial(jax.jit, static_argnames=("lanes", "fe_backend", "carry_mode"))
+@partial(jax.jit, static_argnames=("lanes", "carry_mode"))
 def _device_verify_packed(negax, ay, pub_words, sig_words, tmpl, vidx, vwords,
-                          lanes=LANES, fe_backend="vpu", carry_mode="lazy"):
+                          lanes=LANES, carry_mode="lazy"):
     """Transfer-minimizing verify: the padded SHA-512 input is ASSEMBLED ON
     DEVICE instead of shipped from the host.
 
@@ -726,7 +744,7 @@ def _device_verify_packed(negax, ay, pub_words, sig_words, tmpl, vidx, vwords,
     mw = mw.at[vidx, :].set(vwords.T)
     digs, digh, rlimb, rsign = _prologue_call(mw, sig_words.T, lanes=lanes)
     ok = _ladder_call(negax.T, ay.T, digs, digh, rlimb, rsign, lanes=lanes,
-                      fe_backend=fe_backend, carry_mode=carry_mode)
+                      carry_mode=carry_mode)
     return ok[0].astype(bool)
 
 
@@ -813,16 +831,11 @@ def _bucket(n: int, lanes: int = LANES) -> int:
 
 def verify_batch(pubs: np.ndarray, msgs: Sequence[bytes], sigs: np.ndarray,
                  interpret: bool = False,
-                 fe_backend: str = "vpu",
                  carry_mode: str = "lazy") -> np.ndarray:
     """Go-exact batched verify on the Pallas path, on the default jax
-    device. Same contract as ops.ed25519_verify.verify_batch. `fe_backend`
-    selects the limb multiplier (fe_common.FE_BACKENDS); every backend is
-    bit-exact in interpret mode, but only "vpu" lowers for TPU
-    (crypto/batch.check_fe_backend_lowers refuses the rest up front).
+    device. Same contract as ops.ed25519_verify.verify_batch.
     `carry_mode` picks the eager or deferred (lazy) carry schedule — both
-    bit-exact at the canonical boundary; mxu16 silently runs eager."""
-    fe_backend = _fc.normalize_backend(fe_backend)
+    bit-exact at the canonical boundary."""
     carry_mode = _fc.normalize_carry_mode(carry_mode)
     pubs = np.ascontiguousarray(pubs, dtype=np.uint8)
     sigs = np.ascontiguousarray(sigs, dtype=np.uint8)
@@ -845,7 +858,7 @@ def verify_batch(pubs: np.ndarray, msgs: Sequence[bytes], sigs: np.ndarray,
             )))
     out = np.zeros((n,), dtype=bool)
     for idx, cols in groups:
-        out[idx] = _verify_uniform(*cols, interpret, fe_backend, carry_mode)
+        out[idx] = _verify_uniform(*cols, interpret, carry_mode)
     return out
 
 
@@ -898,7 +911,7 @@ def _prologue_h(pubs, msgs, sigs, interpret=False) -> list:
 
 def rlc_verify_batch(pubs: np.ndarray, msgs: Sequence[bytes],
                      sigs: np.ndarray, interpret: bool = False,
-                     fe_backend: str = "vpu", carry_mode: str = "lazy",
+                     carry_mode: str = "lazy",
                      seed: Optional[int] = None) -> np.ndarray:
     """Batched Go-exact verify via ONE multi-scalar multiplication on the
     Pallas path: the SHA-512/mod-L stage runs in the existing prologue
@@ -908,7 +921,6 @@ def rlc_verify_batch(pubs: np.ndarray, msgs: Sequence[bytes],
     contract as ``verify_batch``; ``seed`` pins the RLC coefficients."""
     from tendermint_tpu.ops import ed25519_msm as _msm
 
-    fe_backend = _fc.normalize_backend(fe_backend)
     carry_mode = _fc.normalize_carry_mode(carry_mode)
     pubs = np.ascontiguousarray(pubs, dtype=np.uint8)
     sigs = np.ascontiguousarray(sigs, dtype=np.uint8)
@@ -928,11 +940,11 @@ def rlc_verify_batch(pubs: np.ndarray, msgs: Sequence[bytes],
         return verify_batch(
             pubs[idx], [msgs[i] for i in idx], sigs[idx],
             interpret=interpret,
-            fe_backend=fe_backend, carry_mode=carry_mode,
+            carry_mode=carry_mode,
         )
 
     _msm.rlc_resolve(parsed, out, ladder_fn, seed=seed,
-                     fe_backend=fe_backend, carry_mode=carry_mode)
+                     carry_mode=carry_mode)
     return np.asarray(out, dtype=bool)
 
 
@@ -995,7 +1007,7 @@ def _sig_words(sigs, valid) -> np.ndarray:
 
 
 def _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln, interpret,
-                    fe_backend="vpu", carry_mode="lazy"):
+                    carry_mode="lazy"):
     n = pubs.shape[0]
     # interpret mode (CPU tests) has no tile-alignment constraint: shrink the
     # lane count so the eager interpreter does 16x less padded work.
@@ -1019,7 +1031,7 @@ def _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln, interpret,
                 negax_d, ay_d, pubw_d,
                 jnp.asarray(sig_words),
                 jnp.asarray(tmpl), jnp.asarray(vrows), jnp.asarray(vwords),
-                lanes=lanes, fe_backend=fe_backend, carry_mode=carry_mode,
+                lanes=lanes, carry_mode=carry_mode,
             )
         # the device's run, the copy back and the wake of this thread
         with trace.span("dispatch.wait", lanes=b):
@@ -1048,7 +1060,6 @@ def _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln, interpret,
             jnp.asarray(msg_words),
             interpret=interpret,
             lanes=lanes,
-            fe_backend=fe_backend,
             carry_mode=carry_mode,
         )
     )[:n]

@@ -113,18 +113,12 @@ def fe_sub(a, b):
     return fe_carry(a + _K_SUB - b, rounds=2)
 
 
-# Limb-multiplier backend for this module's kernels: "vpu" is the shifted
-# multiply-accumulate schoolbook below; "mxu" computes the same columns as 4
-# int8 matmuls (fe_common.mul_columns_batch) so the 400 row-products land on
-# the matrix unit. Set only via _compiled_kernel's trace-time wrapper — the
-# jit cache is keyed on it, so each backend traces its own kernel.
-_FE_BACKEND = "vpu"
-
 # Carry schedule for the ladder's point ops: "eager" is the full per-op
 # ripple below; "lazy" defers carries per fe_common.derive_carry_plan (one
-# reduction per point op). Swapped the same trace-time way as _FE_BACKEND
-# (fe_common.trace_with_modes); module-level fe_mul/fe_add/fe_sub are always
-# the eager ops regardless.
+# reduction per point op). Set only via _compiled_kernel's trace-time wrapper
+# (fe_common.trace_with_modes) — the jit cache is keyed on it, so each mode
+# traces its own kernel; module-level fe_mul/fe_add/fe_sub are always the
+# eager ops regardless.
 _CARRY_MODE = "eager"
 
 _PLAN = _fc.derive_carry_plan("ed25519")
@@ -145,15 +139,7 @@ def fe_mul(a, b):
     at the margin, e.g. top limbs 8192·8192 = 2^26 — would be silently
     dropped (the same mechanism as the secp bug fixed in
     secp256k1_verify.fe_mul). Row 40 folds as 2^520 ≡ 608² (mod p)."""
-    if _FE_BACKEND != "vpu":
-        # identical integers per column (exact int32 recombination), so the
-        # carry/fold tail below is untouched — bit-exact with the VPU path
-        prod = _fc.mul_columns_batch(a, b, 2 * NLIMB + 1, split=7)
-    else:
-        shape = jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1])
-        prod = jnp.zeros(shape + (2 * NLIMB + 1,), dtype=jnp.uint32)
-        for i in range(NLIMB):
-            prod = prod.at[..., i : i + NLIMB].add(a[..., i : i + 1] * b)
+    prod = _mul_cols(a, b, 2 * NLIMB + 1)
     # local carries inside the 41-limb product (no wrap needed: value < 2^520)
     for _ in range(3):
         c = prod >> BITS
@@ -170,13 +156,11 @@ def fe_sq(a):
 
 # --- deferred-carry (lazy) ops: batch-leading twins of the Pallas row ops,
 # used by the ladder's point ops when _CARRY_MODE == "lazy".  Operand-class
-# bounds are certified at import by fe_common.derive_carry_plan; lazy-mode
-# operands exceed the int8 plane bound, so mxu uses uint8 planes (split=8).
+# bounds are certified at import by fe_common.derive_carry_plan.
 
 
 def _mul_cols(a, b, out_cols):
-    if _FE_BACKEND != "vpu":
-        return _fc.mul_columns_batch(a, b, out_cols, split=8)
+    """Schoolbook product columns: 20 shifted multiply-accumulates."""
     shape = jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1])
     prod = jnp.zeros(shape + (out_cols,), dtype=jnp.uint32)
     for i in range(NLIMB):
@@ -391,18 +375,14 @@ def _verify_kernel(neg_ax, ay, s_words, h_words, r_limbs, r_sign):
 _kernel_cache = {}
 
 
-def _compiled_kernel(batch: int, mesh=None, fe_backend: str = "vpu",
-                     carry_mode: str = "eager"):
+def _compiled_kernel(batch: int, mesh=None, carry_mode: str = "eager"):
     # Mesh hashes by devices+axis_names — safe cache key (id() could be reused
     # by a new Mesh after gc and serve a stale sharding)
-    carry_mode = _fc.effective_carry_mode(fe_backend, carry_mode)
-    if fe_backend not in ("vpu", "mxu"):
-        fe_backend = "mxu" if fe_backend == "mxu16" else "vpu"
-    key = (batch, mesh, fe_backend, carry_mode)
+    key = (batch, mesh, carry_mode)
     fn = _kernel_cache.get(key)
     if fn is None:
         kernel = _fc.trace_with_modes(
-            sys.modules[__name__], _verify_kernel, fe_backend, carry_mode
+            sys.modules[__name__], _verify_kernel, carry_mode
         )
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as PS
@@ -519,20 +499,16 @@ def verify_batch(
     msgs: Sequence[bytes],
     sigs: np.ndarray,
     mesh=None,
-    fe_backend: str = "vpu",
     carry_mode: str = "lazy",
 ) -> np.ndarray:
     """Batched Go-exact ed25519 verify.
 
     pubs (N, 32) uint8, msgs list of N byte strings, sigs (N, 64) uint8.
     Returns (N,) bool.  One device dispatch per call (padded to a size bucket
-    to bound recompiles).  fe_backend picks the limb multiplier ("vpu" |
-    "mxu"; "mxu16" degrades to "mxu" here — the 16-limb repack is row-layout
-    only); carry_mode "lazy" (default) defers limb carries between the
-    ladder's point ops, "eager" keeps the full per-op ripple; every
-    combination is bit-exact.
+    to bound recompiles).  carry_mode "lazy" (default) defers limb carries
+    between the ladder's point ops, "eager" keeps the full per-op ripple;
+    both are bit-exact.
     """
-    fe_backend = _fc.normalize_backend(fe_backend)
     carry_mode = _fc.normalize_carry_mode(carry_mode)
     n = len(pubs)
     if n == 0:
@@ -561,7 +537,7 @@ def verify_batch(
         data = NamedSharding(mesh, PS(mesh.axis_names[0]))
         args = [jax.device_put(a, data) for a in args]
     ok = np.asarray(
-        call_jit(_compiled_kernel(b, mesh, fe_backend, carry_mode), *args)
+        call_jit(_compiled_kernel(b, mesh, carry_mode), *args)
     )[:n]
     return ok & valid
 
@@ -582,7 +558,6 @@ def rlc_verify_batch(
     pubs: np.ndarray,
     msgs: Sequence[bytes],
     sigs: np.ndarray,
-    fe_backend: str = "vpu",
     carry_mode: str = "lazy",
     seed: Optional[int] = None,
 ) -> np.ndarray:
@@ -596,7 +571,6 @@ def rlc_verify_batch(
     (default: derived from the batch content — deterministic replay)."""
     from tendermint_tpu.ops import ed25519_msm as _msm
 
-    fe_backend = _fc.normalize_backend(fe_backend)
     carry_mode = _fc.normalize_carry_mode(carry_mode)
     pubs = np.ascontiguousarray(pubs, dtype=np.uint8)
     sigs = np.ascontiguousarray(sigs, dtype=np.uint8)
@@ -612,9 +586,9 @@ def rlc_verify_batch(
     def ladder_fn(idx: List[int]) -> np.ndarray:
         return verify_batch(
             pubs[idx], [msgs[i] for i in idx], sigs[idx],
-            fe_backend=fe_backend, carry_mode=carry_mode,
+            carry_mode=carry_mode,
         )
 
     _msm.rlc_resolve(parsed, out, ladder_fn, seed=seed,
-                     fe_backend=fe_backend, carry_mode=carry_mode)
+                     carry_mode=carry_mode)
     return np.asarray(out, dtype=bool)

@@ -1,29 +1,12 @@
 """Shared radix-2^13 field arithmetic for the ed25519/secp256k1 kernels.
 
-Both Pallas ladders (ops/ed25519_pallas.py, ops/secp256k1_pallas.py) used to
-carry their own copy of the row-layout field ops; this module owns them now,
-plus the MXU limb multiplier that serves both curves:
-
-  backend "vpu"    broadcast schoolbook row-products — 400 uint32 multiplies
-                   per fe_mul, all on the vector unit (the original path).
-  backend "mxu"    each 13-bit limb splits into two int8 planes
-                   (lo = a & 0x7F, hi = a >> 7; hi <= 101 for carried limbs)
-                   and the 400 row-products become 4 int8 batched outer
-                   products via lax.dot_general with int32 accumulation.
-                   The recombined columns are *identical integers* to the
-                   VPU columns, so the existing carry/fold tails produce
-                   bit-identical limbs.
-  backend "mxu16"  radix-2^16 repack: operands fold below 2^256, repack to
-                   16 rows of 16 bits, multiply as 4 uint8-plane outer
-                   products (256 row-products per plane pair, -36% vs the
-                   20-limb mapping), fold/carry in radix-16, convert back.
-                   Congruent mod p (same residue, possibly a different
-                   in-range representative) — the property suite checks it
-                   against the bignum oracle, and canonical encoding is
-                   unchanged.
+Both Pallas ladders (ops/ed25519_pallas.py, ops/secp256k1_pallas.py) trace
+the row-layout field ops of this module.  There is one limb multiplier: the
+broadcast schoolbook row-products, 400 uint32 multiplies per fe_mul, all on
+the vector unit (_columns_vpu_rows).
 
 Layouts: row (NLIMB, B) — limbs on sublanes, batch on lanes (Pallas);
-batch-leading (..., NLIMB) for the XLA kernels (mul_columns_batch).
+batch-leading (..., NLIMB) for the XLA kernels (the *_batch twins).
 
 Every bound claimed here is recomputed mechanically by the pure-Python
 propagators at the bottom (bound_*), which mirror the jnp code step by step
@@ -55,13 +38,6 @@ ED_FOLD = 19 << 5  # 608
 SECP_FOLD_SMALL = 15632
 SECP_FOLD_SHIFT = 10  # ... + 2^36 = (c << 10) two rows up
 
-# 2^256 mod p, used by the mxu16 pre-fold and radix-16 wraps:
-# list of (row, multiplier, shift) placements in the target radix.
-ED_FOLD256_13 = ((0, 19 << 1, 0),)  # 2^256 = 38 (mod p), radix-13 row 0
-SECP_FOLD256_13 = ((0, 977, 0), (2, 1, 6))  # 2^32 = 2^(13*2) * 2^6
-ED_FOLD256_16 = ((0, 38, 0),)
-SECP_FOLD256_16 = ((0, 977, 0), (2, 1, 0))  # 2^32 = 2^(16*2)
-
 ED_M = 13000  # uniform carried-limb bound (closed set, asserted in tests)
 
 # Carry wraps as (row, multiplier, shift) placements — the single source the
@@ -80,11 +56,7 @@ SECP_MUL_TAIL_ROUNDS = 3  # was 5: the propagators prove 2 rounds were wasted
 SECP_ADD_ROUNDS = 3
 SECP_MUL_SMALL_ROUNDS = 3  # was 4, same derivation
 
-FE_BACKENDS = ("vpu", "mxu", "mxu16")
 CARRY_MODES = ("eager", "lazy")
-
-_R16 = 16  # radix-2^16 rows covering a value < 2^256
-MASK16 = (1 << 16) - 1
 
 
 def shift_rows_down(x, k=1):
@@ -97,7 +69,7 @@ def _pad_row(x, row, nrows):
 
 
 # ---------------------------------------------------------------------------
-# Product columns — the only part of fe_mul that differs between backends.
+# Product columns.
 # cols[k] = sum_{i+j=k} a_i * b_j, exact in uint32 (callers guarantee the
 # column bound; see bound_mul_columns).
 # ---------------------------------------------------------------------------
@@ -111,132 +83,25 @@ def _columns_vpu_rows(a, b, out_rows):
     return sum(terms)
 
 
-def _plane_outer(a_lo, a_hi, b_lo, b_hi, batch_axis):
-    """4 batched outer products on the plane pairs, int32 accumulation.
-    Returns (ll, lh, hl, hh), each (B, n, n) with batch dims leading."""
-    dn = (((), ()), ((batch_axis,), (batch_axis,)))
-    dot = partial(lax.dot_general, dimension_numbers=dn,
-                  preferred_element_type=jnp.int32)
-    return (dot(a_lo, b_lo), dot(a_lo, b_hi),
-            dot(a_hi, b_lo), dot(a_hi, b_hi))
-
-
-def _bcast_lanes(a, b):
-    """Broadcast a (rows, 1) constant operand against a (rows, B) one — the
-    VPU elementwise path broadcasts implicitly, but dot_general batch dims
-    must match exactly."""
-    if a.shape[-1] != b.shape[-1]:
-        B = max(a.shape[-1], b.shape[-1])
-        a = jnp.broadcast_to(a, a.shape[:-1] + (B,))
-        b = jnp.broadcast_to(b, b.shape[:-1] + (B,))
-    return a, b
-
-
-def _columns_mxu_rows(a, b, out_rows, split=7):
-    """Same columns as _columns_vpu_rows via the MXU mapping.  With split=7
-    the planes are int8 (lo = x & 0x7F, hi = x >> 7; hi <= 127 needs limbs
-    <= 16383 — the ed25519 carried set qualifies).  secp256k1's carried
-    limb 0 can reach ~24k (the 15632 fold re-entry), so it uses split=8
-    with uint8 planes (hi <= 93) — the MXU takes s8 and u8 operands alike.
-    Recombination is exact in int32 either way:
-    a_i*b_j = ll + ((lh + hl) << split) + (hh << 2*split) < 2^31."""
-    a, b = _bcast_lanes(a, b)
-    dt = jnp.int8 if split == 7 else jnp.uint8
-    m = (1 << split) - 1
-    a_lo = (a & m).astype(dt)
-    a_hi = (a >> split).astype(dt)
-    b_lo = (b & m).astype(dt)
-    b_hi = (b >> split).astype(dt)
-    ll, lh, hl, hh = _plane_outer(a_lo, a_hi, b_lo, b_hi, batch_axis=1)
-    op = (ll + ((lh + hl) << split) + (hh << (2 * split))).astype(jnp.uint32)
-    op = jnp.transpose(op, (1, 2, 0))  # (i, j, B): op[i] == a_i * b (rows j)
-    cols = jnp.zeros((out_rows, a.shape[1]), jnp.uint32)
-    for i in range(NLIMB):
-        cols = cols + jnp.pad(op[i], ((i, out_rows - NLIMB - i), (0, 0)))
-    return cols
-
-
-def mul_columns_rows(a, b, out_rows, backend="vpu", split=7):
-    """(NLIMB, B) x (NLIMB, B) -> (out_rows, B) schoolbook product columns."""
-    if backend == "vpu":
-        return _columns_vpu_rows(a, b, out_rows)
-    if backend == "mxu":
-        return _columns_mxu_rows(a, b, out_rows, split=split)
-    raise ValueError(f"unknown fe backend {backend!r}")
-
-
-def trace_with_backend(mod, kernel, fe_backend):
-    """Wrap `kernel` so its trace runs with mod._FE_BACKEND = fe_backend.
+def trace_with_modes(mod, kernel, carry_mode):
+    """Wrap `kernel` so its trace runs with mod._CARRY_MODE = carry_mode.
 
     The XLA verify modules branch on a module global inside fe_mul while
     BUILDING the graph (threading a parameter through every pt_* helper
     would churn their whole call tree); callers key their jit cache on the
-    backend so each compiled artifact deterministically embeds one choice."""
-    if fe_backend == "vpu":
-        return kernel
+    mode so each compiled artifact deterministically embeds one choice.
+    Always wraps (even for the lazy default) so the restore is
+    unconditional."""
 
     def traced(*args):
-        prev = mod._FE_BACKEND
-        mod._FE_BACKEND = fe_backend
-        try:
-            return kernel(*args)
-        finally:
-            mod._FE_BACKEND = prev
-
-    return traced
-
-
-def trace_with_modes(mod, kernel, fe_backend, carry_mode):
-    """Like trace_with_backend, but also swaps mod._CARRY_MODE — the XLA
-    verify modules branch on both globals while building the graph.  Always
-    wraps (even for vpu/lazy defaults) so the restore is unconditional."""
-
-    def traced(*args):
-        prev_be = mod._FE_BACKEND
-        prev_cm = mod._CARRY_MODE
-        mod._FE_BACKEND = fe_backend
+        prev = mod._CARRY_MODE
         mod._CARRY_MODE = carry_mode
         try:
             return kernel(*args)
         finally:
-            mod._FE_BACKEND = prev_be
-            mod._CARRY_MODE = prev_cm
+            mod._CARRY_MODE = prev
 
     return traced
-
-
-def mul_columns_batch(a, b, out_cols, backend="mxu", split=7):
-    """Batch-leading variant for the XLA kernels: (..., NLIMB) operands ->
-    (..., out_cols) columns.  Only the MXU mapping lives here — the XLA
-    kernels keep their own VPU-style column code.  split follows the same
-    per-curve rule as _columns_mxu_rows (7 -> int8 planes for ed25519,
-    8 -> uint8 planes for secp256k1's taller carried limbs)."""
-    if backend != "mxu":
-        raise ValueError(f"mul_columns_batch serves backend 'mxu', not {backend!r}")
-    if a.shape != b.shape:
-        shp = jnp.broadcast_shapes(a.shape, b.shape)
-        a = jnp.broadcast_to(a, shp)
-        b = jnp.broadcast_to(b, shp)
-    dt = jnp.int8 if split == 7 else jnp.uint8
-    m = (1 << split) - 1
-    a_lo = (a & m).astype(dt)
-    a_hi = (a >> split).astype(dt)
-    b_lo = (b & m).astype(dt)
-    b_hi = (b >> split).astype(dt)
-    nb = a.ndim - 1
-    dn = (((), ()), (tuple(range(nb)), tuple(range(nb))))
-    dot = partial(lax.dot_general, dimension_numbers=dn,
-                  preferred_element_type=jnp.int32)
-    ll = dot(a_lo, b_lo)
-    lh = dot(a_lo, b_hi)
-    hl = dot(a_hi, b_lo)
-    hh = dot(a_hi, b_hi)
-    op = (ll + ((lh + hl) << split)
-          + (hh << (2 * split))).astype(jnp.uint32)  # (..., i, j)
-    cols = jnp.zeros(a.shape[:-1] + (out_cols,), jnp.uint32)
-    for i in range(NLIMB):
-        cols = cols.at[..., i : i + NLIMB].add(op[..., i, :])
-    return cols
 
 
 # ---------------------------------------------------------------------------
@@ -268,12 +133,10 @@ def ed_fe_sub(a, b, ksub):
     return x
 
 
-def ed_fe_mul(a, b, backend="vpu"):
+def ed_fe_mul(a, b):
     """(NLIMB, B) x (NLIMB, B) -> carried limbs (<= M_ED; bound_fe_mul
     recomputes the chain mechanically)."""
-    if backend == "mxu16":
-        return _mul16_rows(a, b, ED_FOLD256_13, ED_FOLD256_16, ed_fe_carry1, 3)
-    prod = mul_columns_rows(a, b, 2 * NLIMB, backend, split=7)  # (40, B)
+    prod = _columns_vpu_rows(a, b, 2 * NLIMB)  # (40, B)
     c = prod >> BITS
     prod = (prod & MASK) + shift_rows_down(c)  # carry within 40 limbs
     lo = prod[:NLIMB, :] + prod[NLIMB:, :] * ED_FOLD
@@ -282,17 +145,14 @@ def ed_fe_mul(a, b, backend="vpu"):
     return lo
 
 
-def ed_fe_sq(a, backend="vpu"):
-    return ed_fe_mul(a, a, backend)
+def ed_fe_sq(a):
+    return ed_fe_mul(a, a)
 
 
-def ed_fe_inv(z, backend="vpu", mul=None, sq=None):
+def ed_fe_inv(z, mul=ed_fe_mul, sq=ed_fe_sq):
     """z^(p-2) via the standard curve25519 addition chain: 254 sq + 11 mul.
     mul/sq overrides let the lazy namespaces run the chain on their fully
     reduced mulF (output class C stays closed under the chain)."""
-    sq = sq if sq is not None else partial(ed_fe_sq, backend=backend)
-    mul = mul if mul is not None else partial(ed_fe_mul, backend=backend)
-
     def sqn(x, n):
         return lax.fori_loop(0, n, lambda _, v: sq(v), x)
 
@@ -344,16 +204,11 @@ def secp_fe_sub(a, b, ksub):
     return secp_fe_carry(a + ksub - b, rounds=SECP_ADD_ROUNDS)
 
 
-def secp_fe_mul(a, b, backend="vpu"):
+def secp_fe_mul(a, b):
     """Row port of secp256k1_verify.fe_mul (41-row product, 24-row fold
     temp — that docstring holds the ripple-carry proof; bound_fe_mul
-    recomputes it for every backend)."""
-    if backend == "mxu16":
-        return _mul16_rows(
-            a, b, SECP_FOLD256_13, SECP_FOLD256_16,
-            partial(secp_fe_carry, rounds=1), 5,
-        )
-    prod = mul_columns_rows(a, b, 2 * NLIMB + 1, backend, split=8)  # (41, B)
+    recomputes it)."""
+    prod = _columns_vpu_rows(a, b, 2 * NLIMB + 1)  # (41, B)
     for _ in range(3):
         c = prod >> BITS
         prod = (prod & MASK) + shift_rows_down(c)
@@ -376,18 +231,17 @@ def secp_fe_mul(a, b, backend="vpu"):
     return secp_fe_carry(lo, rounds=SECP_MUL_TAIL_ROUNDS)
 
 
-def secp_fe_sq(a, backend="vpu"):
-    return secp_fe_mul(a, a, backend)
+def secp_fe_sq(a):
+    return secp_fe_mul(a, a)
 
 
 def secp_fe_mul_small(a, k: int):
     return secp_fe_carry(a * jnp.uint32(k), rounds=SECP_MUL_SMALL_ROUNDS)
 
 
-def secp_fe_inv(z, backend="vpu", mul=None):
+def secp_fe_inv(z, mul=secp_fe_mul):
     """z^(p-2), plain MSB-first square-and-multiply (tests only — the secp
     ladder kernel eliminated inversion; see secp256k1_pallas)."""
-    mul = mul if mul is not None else partial(secp_fe_mul, backend=backend)
     e = SECP_P - 2
     acc = z
     for bit in bin(e)[3:]:  # skip the leading 1
@@ -395,125 +249,6 @@ def secp_fe_inv(z, backend="vpu", mul=None):
         if bit == "1":
             acc = mul(acc, z)
     return acc
-
-
-# ---------------------------------------------------------------------------
-# mxu16 — radix-2^16 repack shared by both curves
-# ---------------------------------------------------------------------------
-
-
-def _fold_bits256_13(a, terms):
-    """Fold bits >= 256 of a radix-13 element (limb 19 covers bits 247..):
-    value becomes < 2^256 with limbs <= ~max(in) + t*mult (exact)."""
-    t = a[NLIMB - 1 :, :] >> 9
-    out = a - _pad_row(t << 9, NLIMB - 1, NLIMB)
-    for row, mult, shift in terms:
-        out = out + _pad_row((t * mult) << shift, row, NLIMB)
-    return out
-
-
-def _seq_carry16(w):
-    """Exact sequential carry over the 16 rows, 15 steps on the VPU."""
-    for k in range(_R16 - 1):
-        c = w[k : k + 1, :] >> 16
-        w = w - _pad_row(c << 16, k, _R16) + _pad_row(c, k + 1, _R16)
-    return w
-
-
-def _repack_13to16(a, fold256_16):
-    """(NLIMB, B) radix-13 rows (value < 2^256 + eps after the prefold) ->
-    (16, B) radix-16 rows, each < 2^16.  The prefold clears bits >= 256 of
-    limb 19, but the lower limbs can still sum just past 2^256 (all-MASK
-    input is 2^260 - 1), so the carry out of row 15 — at most a couple of
-    units — wraps through the 2^256 fold terms and a second sequential
-    pass settles it; the value then provably fits 256 bits."""
-    w = jnp.zeros((_R16, a.shape[1]), jnp.uint32)
-    for i in range(NLIMB):
-        q, r = divmod(BITS * i, 16)
-        w = w + _pad_row(a[i : i + 1, :] << r, q, _R16)
-    w = _seq_carry16(w)
-    c = w[_R16 - 1 :, :] >> 16
-    w = w - _pad_row(c << 16, _R16 - 1, _R16)
-    for row, mult, shift in fold256_16:
-        w = w + _pad_row((c * mult) << shift, row, _R16)
-    return _seq_carry16(w)
-
-
-def _columns16_mxu(wa, wb):
-    """(16, B)^2 radix-16 rows -> (33, B) uint32 product columns.  uint8
-    planes lo = w & 0xFF, hi = w >> 8; the hh plane re-enters one row up
-    (hh << 16 is exactly one radix-16 limb) so no column crosses 2^32:
-    col <= 16 * (255^2 + 2*255^2*256) + 16*255^2 ~ 5.4e8."""
-    wa, wb = _bcast_lanes(wa, wb)
-    a_lo = (wa & 0xFF).astype(jnp.uint8)
-    a_hi = (wa >> 8).astype(jnp.uint8)
-    b_lo = (wb & 0xFF).astype(jnp.uint8)
-    b_hi = (wb >> 8).astype(jnp.uint8)
-    ll, lh, hl, hh = _plane_outer(a_lo, a_hi, b_lo, b_hi, batch_axis=1)
-    low = (ll + ((lh + hl) << 8)).astype(jnp.uint32)
-    hh = hh.astype(jnp.uint32)
-    low = jnp.transpose(low, (1, 2, 0))  # (i, j, B)
-    hh = jnp.transpose(hh, (1, 2, 0))
-    nrows = 2 * _R16 + 1  # 33: columns 0..30 plus the hh/carry spill row
-    cols = jnp.zeros((nrows, wa.shape[1]), jnp.uint32)
-    for i in range(_R16):
-        cols = cols + jnp.pad(low[i], ((i, nrows - _R16 - i), (0, 0)))
-        cols = cols + jnp.pad(hh[i], ((i + 1, nrows - _R16 - i - 1), (0, 0)))
-    return cols
-
-
-def _carry16(x, rounds, wrap_terms=()):
-    """Parallel radix-16 carry rounds.  With wrap_terms (16 rows = a value
-    mod 2^256) the carry out of row 15 re-enters as 2^256's placements;
-    without them the top row keeps its excess bits (nothing is dropped —
-    exactness over tidiness for the intermediate stacks)."""
-    nrows = x.shape[0]
-    for _ in range(rounds):
-        c = x >> 16
-        if wrap_terms:
-            x = (x & MASK16) + shift_rows_down(c)
-            for row, mult, shift in wrap_terms:
-                x = x + _pad_row((c[nrows - 1 :, :] * mult) << shift, row, nrows)
-        else:
-            keep = _pad_row(c[nrows - 1 :, :] << 16, nrows - 1, nrows)
-            x = (x & MASK16) + shift_rows_down(c) + keep
-    return x
-
-
-def _fold16(cols, terms):
-    """Fold rows >= 16 of the (33, B) column stack back under 2^256 using
-    2^256 = sum(mult << 16*row) placements; returns (16, B).  Two passes:
-    the first can land past row 15 again (secp's +2^32 term), so it carries
-    and folds once more — bounded because the second-pass rows are small."""
-    spill = max(row for row, _, _ in terms) + 1  # rows >= 16 after pass one
-    hi = cols[_R16:, :]  # (17, B): multiples of 2^256
-    lo = jnp.pad(cols[:_R16, :], ((0, spill), (0, 0)))  # (16+spill, B)
-    for row, mult, _ in terms:
-        lo = lo + jnp.pad(hi * mult, ((row, spill - row - 1), (0, 0)))
-    lo = _carry16(lo, rounds=2)  # keeps the second fold's products < 2^32
-    out = lo[:_R16, :]
-    for j in range(spill):
-        h = lo[_R16 + j : _R16 + j + 1, :]
-        for row, mult, _ in terms:
-            out = out + _pad_row(h * mult, row + j, _R16)
-    return out
-
-
-def _mul16_rows(a, b, fold256_13, fold256_16, carry13_1, tail_rounds):
-    """The radix-2^16 fe_mul: pre-fold below 2^256, repack, uint8-plane
-    multiply, radix-16 fold/carry, convert back to radix-13, final carry."""
-    wa = _repack_13to16(_fold_bits256_13(a, fold256_13), fold256_16)
-    wb = _repack_13to16(_fold_bits256_13(b, fold256_13), fold256_16)
-    cols = _carry16(_columns16_mxu(wa, wb), rounds=2)
-    w = _carry16(_fold16(cols, fold256_16), rounds=2, wrap_terms=fold256_16)
-    out = jnp.zeros((NLIMB, a.shape[1]), jnp.uint32)
-    for k in range(_R16):
-        q, r = divmod(16 * k, BITS)
-        out = out + _pad_row(w[k : k + 1, :] << r, q, NLIMB)
-    x = out
-    for _ in range(tail_rounds):
-        x = carry13_1(x)
-    return x
 
 
 # ---------------------------------------------------------------------------
@@ -590,13 +325,11 @@ def ed_fold_fused_rows(cols):
     return lo + shift_rows_down((hi >> BITS) * ED_FOLD)
 
 
-def ed_fe_mul_lazy(a, b, wide, fix=(0,), backend="vpu"):
+def ed_fe_mul_lazy(a, b, wide, fix=(0,)):
     """Deferred-carry ed25519 multiply: fused fold + `wide` wide rounds +
     row fixups.  wide/fix come from derive_carry_plan — mulf_wide for the
-    fully reduced class C, mull_wide (1) for the lazy class D.  Lazy-mode
-    operands can exceed the int8 plane bound, so mxu uses uint8 (split=8) —
-    columns are identical integers either way."""
-    cols = mul_columns_rows(a, b, 2 * NLIMB, backend, split=8)
+    fully reduced class C, mull_wide (1) for the lazy class D."""
+    cols = _columns_vpu_rows(a, b, 2 * NLIMB)
     lo = ed_fold_fused_rows(cols)
     for _ in range(wide):
         lo = wide_carry_rows(lo, ED_WRAP)
@@ -650,16 +383,15 @@ def fix_rows_stacked(x, fix):
     return x
 
 
-def ed_fe_mul4_lazy(pairs, wide, fix=(0,), backend="vpu"):
+def ed_fe_mul4_lazy(pairs, wide, fix=(0,)):
     """Four deferred-carry multiplies sharing ONE stacked carry tail: the
-    product columns and fold stay per-product (MXU/VPU bound), but the
-    `wide` rounds and row fixups — the ~40% carry tail — run once over the
-    (4·NLIMB, B) concatenation.  The four output products of a point op
+    product columns and fold stay per-product, but the `wide` rounds and
+    row fixups — the ~40% carry tail — run once over the (4·NLIMB, B)
+    concatenation.  The four output products of a point op
     share the exact same schedule, which is what makes the stacking sound;
     bit-identical to four ed_fe_mul_lazy calls."""
     lo = jnp.concatenate(
-        [ed_fold_fused_rows(mul_columns_rows(a, b, 2 * NLIMB, backend,
-                                             split=8))
+        [ed_fold_fused_rows(_columns_vpu_rows(a, b, 2 * NLIMB))
          for a, b in pairs],
         axis=0,
     )
@@ -703,10 +435,10 @@ def secp_fold2_rows(tmp):
     return lo
 
 
-def secp_fe_mul_lazy(a, b, wide, fix=(0, 1, 2, 3), backend="vpu", mid=1):
+def secp_fe_mul_lazy(a, b, wide, fix=(0, 1, 2, 3), mid=1):
     """Deferred-carry secp256k1 multiply: two-level fused fold with `mid`
     dropped-top rounds over the 24-row temp between the levels."""
-    cols = mul_columns_rows(a, b, 2 * NLIMB + 1, backend, split=8)
+    cols = _columns_vpu_rows(a, b, 2 * NLIMB + 1)
     tmp = secp_fold_fused_rows(cols)
     for _ in range(mid):
         tmp = carry_drop_top_rows(tmp)
@@ -788,48 +520,38 @@ def secp_fold2_batch(tmp):
 
 
 # ---------------------------------------------------------------------------
-# Backend namespaces — what the Pallas kernels thread through their point ops
+# Op namespaces — what the Pallas kernels thread through their point ops
 # ---------------------------------------------------------------------------
 
 
-def make_fe(curve: str, backend: str = "vpu",
-            carry_mode: str = "eager") -> SimpleNamespace:
+def make_fe(curve: str, carry_mode: str = "eager") -> SimpleNamespace:
     """Uniform op namespace: mul/sq/add/sub/inv/carry (+ mul_small on secp).
-    add/sub/carry are backend-independent (pure VPU); mul/sq/inv honor the
-    backend.
 
     carry_mode="lazy" swaps in the deferred-carry ops: mul becomes mulF
     (output in the certified fully-reduced class C), mul_lazy/add_raw expose
     the cheaper unreduced forms, add/sub carry once instead of fully, and
     sub against class-D operands must use fe.kd (the wide multiple of p
-    sized for D) instead of the eager ksub.  The mxu16 backend keeps its own
-    fused 16-limb pipeline and degrades to eager (effective_carry_mode)."""
-    if backend not in FE_BACKENDS:
-        raise ValueError(f"fe backend must be one of {FE_BACKENDS}, got {backend!r}")
+    sized for D) instead of the eager ksub."""
     if carry_mode not in CARRY_MODES:
         raise ValueError(f"carry mode must be one of {CARRY_MODES}, got {carry_mode!r}")
-    lazy = effective_carry_mode(backend, carry_mode) == "lazy"
+    lazy = carry_mode == "lazy"
     if curve == "ed25519":
         if not lazy:
             return SimpleNamespace(
-                curve=curve, backend=backend, carry_mode="eager", plan=None,
-                kd=None,
-                mul=partial(ed_fe_mul, backend=backend),
-                sq=partial(ed_fe_sq, backend=backend),
-                inv=partial(ed_fe_inv, backend=backend),
+                curve=curve, carry_mode="eager", plan=None, kd=None,
+                mul=ed_fe_mul, sq=ed_fe_sq, inv=ed_fe_inv,
                 add=ed_fe_add, sub=ed_fe_sub, carry=ed_fe_carry1,
             )
-        plan = derive_carry_plan(curve, backend)
-        mul = partial(ed_fe_mul_lazy, wide=plan.mulf_wide, fix=plan.mulf_fix,
-                      backend=backend)
+        plan = derive_carry_plan(curve)
+        mul = partial(ed_fe_mul_lazy, wide=plan.mulf_wide, fix=plan.mulf_fix)
         return SimpleNamespace(
-            curve=curve, backend=backend, carry_mode="lazy", plan=plan,
+            curve=curve, carry_mode="lazy", plan=plan,
             kd=np.asarray(plan.kd, np.uint32),
             mul=mul,
             mul4=partial(ed_fe_mul4_lazy, wide=plan.mulf_wide,
-                         fix=plan.mulf_fix, backend=backend),
+                         fix=plan.mulf_fix),
             mul_lazy=partial(ed_fe_mul_lazy, wide=plan.mull_wide,
-                             fix=plan.mull_fix, backend=backend),
+                             fix=plan.mull_fix),
             sq=lambda a: mul(a, a),
             inv=partial(ed_fe_inv, mul=mul, sq=lambda a: mul(a, a)),
             add=lambda a, b: ed_fe_norm1(a + b, fix=plan.norm_fix),
@@ -840,23 +562,20 @@ def make_fe(curve: str, backend: str = "vpu",
     if curve == "secp256k1":
         if not lazy:
             return SimpleNamespace(
-                curve=curve, backend=backend, carry_mode="eager", plan=None,
-                kd=None,
-                mul=partial(secp_fe_mul, backend=backend),
-                sq=partial(secp_fe_sq, backend=backend),
-                inv=partial(secp_fe_inv, backend=backend),
+                curve=curve, carry_mode="eager", plan=None, kd=None,
+                mul=secp_fe_mul, sq=secp_fe_sq, inv=secp_fe_inv,
                 add=secp_fe_add, sub=secp_fe_sub, carry=secp_fe_carry,
                 mul_small=secp_fe_mul_small,
             )
-        plan = derive_carry_plan(curve, backend)
+        plan = derive_carry_plan(curve)
         mul = partial(secp_fe_mul_lazy, wide=plan.mulf_wide,
-                      fix=plan.mulf_fix, backend=backend, mid=plan.mid)
+                      fix=plan.mulf_fix, mid=plan.mid)
         return SimpleNamespace(
-            curve=curve, backend=backend, carry_mode="lazy", plan=plan,
+            curve=curve, carry_mode="lazy", plan=plan,
             kd=np.asarray(plan.kd, np.uint32),
             mul=mul,
             mul_lazy=partial(secp_fe_mul_lazy, wide=plan.mull_wide,
-                             fix=plan.mull_fix, backend=backend, mid=plan.mid),
+                             fix=plan.mull_fix, mid=plan.mid),
             sq=lambda a: mul(a, a),
             inv=partial(secp_fe_inv, mul=mul),
             add=lambda a, b: secp_fe_norm1(a + b, wide=plan.norm_wide,
@@ -871,16 +590,6 @@ def make_fe(curve: str, backend: str = "vpu",
     raise ValueError(f"unknown curve {curve!r}")
 
 
-def normalize_backend(value) -> str:
-    """Config/env -> backend name ('' / None / 'auto' mean the VPU path)."""
-    v = (value or "vpu").strip().lower()
-    if v in ("", "auto"):
-        v = "vpu"
-    if v not in FE_BACKENDS:
-        raise ValueError(f"[verify] fe_backend must be one of {FE_BACKENDS}, got {value!r}")
-    return v
-
-
 def normalize_carry_mode(value) -> str:
     """Config/env -> carry mode ('' / None / 'auto' mean lazy, the default)."""
     v = (value or "lazy").strip().lower()
@@ -889,13 +598,6 @@ def normalize_carry_mode(value) -> str:
     if v not in CARRY_MODES:
         raise ValueError(f"carry mode must be one of {CARRY_MODES}, got {value!r}")
     return v
-
-
-def effective_carry_mode(backend: str, carry_mode: str = "lazy") -> str:
-    """mxu16's fused 16-limb pipeline has its own carry schedule and no lazy
-    variant — it degrades gracefully to eager; everything else honors the
-    requested mode."""
-    return "eager" if backend == "mxu16" else carry_mode
 
 
 # ---------------------------------------------------------------------------
@@ -924,7 +626,7 @@ def _b_carry_round(bounds, wrap_terms) -> Tuple[List[int], int]:
 
 
 def bound_mul_columns(ba: Sequence[int], bb: Sequence[int], out_rows: int) -> List[int]:
-    """Column maxima — identical for vpu and mxu (same integers)."""
+    """Column maxima of _columns_vpu_rows."""
     cols = [0] * out_rows
     for i in range(NLIMB):
         for j in range(NLIMB):
@@ -933,13 +635,11 @@ def bound_mul_columns(ba: Sequence[int], bb: Sequence[int], out_rows: int) -> Li
 
 
 def bound_fe_mul(curve: str, ba: Sequence[int], bb: Sequence[int],
-                 backend: str = "vpu", tail_rounds: int = None
-                 ) -> Tuple[List[int], int]:
+                 tail_rounds: int = None) -> Tuple[List[int], int]:
     """Per-row output maxima of fe_mul plus the largest intermediate the
     pipeline can produce (callers assert < 2^32).  tail_rounds overrides the
     module's final-carry count so derive_eager_rounds can search for the
     minimum (None -> the constant the jnp op uses)."""
-    hi_in = max(max(ba), max(bb))
     peak = 0
 
     def see(vals):
@@ -947,15 +647,6 @@ def bound_fe_mul(curve: str, ba: Sequence[int], bb: Sequence[int],
         peak = max(peak, max(vals))
         return vals
 
-    if backend == "mxu":
-        # the plane split must fit its dtype: int8 (split=7) needs limbs
-        # <= 16383, uint8 (split=8) <= 65535
-        limit = 16383 if curve == "ed25519" else 65535
-        if hi_in > limit:
-            raise AssertionError(
-                f"{curve} mxu planes need limbs <= {limit}, got {hi_in}")
-    if backend == "mxu16":
-        return _bound_mul16(curve, ba, bb)
     if curve == "ed25519":
         cols = see(bound_mul_columns(ba, bb, 2 * NLIMB))
         c = [b >> BITS for b in cols]
@@ -999,111 +690,6 @@ def bound_fe_mul(curve: str, ba: Sequence[int], bb: Sequence[int],
     raise ValueError(curve)
 
 
-def _b_carry16(bs, rounds, wrap_terms=()):
-    """Mirror of _carry16 on per-row maxima (same top-row semantics)."""
-    seen = []
-    n = len(bs)
-    for _ in range(rounds):
-        c = [b >> 16 for b in bs]
-        nxt = [min(b, MASK16) + s for b, s in zip(bs, [0] + c[:-1])]
-        if wrap_terms:
-            for row, mult, shift in wrap_terms:
-                nxt[row] += (c[n - 1] * mult) << shift
-        else:
-            nxt[n - 1] += c[n - 1] << 16  # top row keeps its excess
-        bs = nxt
-        seen.append(max(bs))
-    return bs, max(seen)
-
-
-def _bound_mul16(curve, ba, bb) -> Tuple[List[int], int]:
-    fold13 = ED_FOLD256_13 if curve == "ed25519" else SECP_FOLD256_13
-    fold16 = ED_FOLD256_16 if curve == "ed25519" else SECP_FOLD256_16
-    peak = 0
-
-    def see(vals):
-        nonlocal peak
-        peak = max(peak, max(vals))
-        return list(vals)
-
-    def prefold(bs):
-        t = bs[NLIMB - 1] >> 9
-        out = list(bs)
-        out[NLIMB - 1] = min(out[NLIMB - 1], 0x1FF)
-        for row, mult, shift in fold13:
-            out[row] += (t * mult) << shift
-        return see(out)
-
-    def seq_carry(w):
-        for k in range(_R16 - 1):
-            c = w[k] >> 16
-            w[k] = min(w[k], MASK16)
-            w[k + 1] += c
-            see([w[k + 1]])
-        return w
-
-    def repack(bs):
-        w = [0] * _R16
-        for i in range(NLIMB):
-            q, r = divmod(BITS * i, 16)
-            w[q] += bs[i] << r
-        see(w)
-        w = seq_carry(w)
-        c = w[_R16 - 1] >> 16
-        w[_R16 - 1] = min(w[_R16 - 1], MASK16)
-        for row, mult, shift in fold16:
-            w[row] += (c * mult) << shift
-        w = seq_carry(see(w))
-        # rows end < 2^16: after the wrap the value fits 256 bits (an
-        # invariant of the prefold + wrap, not derivable from row maxima)
-        return [min(x, MASK16) for x in w]
-
-    wa = repack(prefold(ba))
-    wb = repack(prefold(bb))
-    # uint8 plane products: ll + ((lh+hl)<<8) at i+j, hh one row up
-    nrows = 2 * _R16 + 1
-    cols = [0] * nrows
-    for i in range(_R16):
-        for j in range(_R16):
-            la, ha = min(wa[i], 0xFF), wa[i] >> 8
-            lb, hb = min(wb[j], 0xFF), wb[j] >> 8
-            cols[i + j] += la * lb + ((la * hb + ha * lb) << 8)
-            cols[i + j + 1] += ha * hb
-    see(cols)
-    cols, m = _b_carry16(cols, rounds=2)
-    peak = max(peak, m)
-    # _fold16 mirror: pass one onto 16+spill rows, carry, pass two
-    spill = max(row for row, _, _ in fold16) + 1
-    lo = cols[:_R16] + [0] * spill
-    hi = cols[_R16:]
-    for row, mult, _ in fold16:
-        for j, h in enumerate(hi):
-            lo[row + j] += h * mult
-    see(lo)
-    lo, m = _b_carry16(lo, rounds=2)
-    peak = max(peak, m)
-    out16 = lo[:_R16]
-    for j in range(spill):
-        h = lo[_R16 + j]
-        for row, mult, _ in fold16:
-            out16[row + j] += h * mult
-    see(out16)
-    out16, m = _b_carry16(out16, rounds=2, wrap_terms=fold16)
-    peak = max(peak, m)
-    limbs = [0] * NLIMB
-    for k in range(_R16):
-        q, r = divmod(16 * k, BITS)
-        limbs[q] += out16[k] << r
-    see(limbs)
-    wrap = ((0, ED_FOLD, 0),) if curve == "ed25519" else (
-        (0, SECP_FOLD_SMALL, 0), (2, 1, SECP_FOLD_SHIFT))
-    rounds = 3 if curve == "ed25519" else 5
-    for _ in range(rounds):
-        limbs, m = _b_carry_round(limbs, wrap)
-        peak = max(peak, m)
-    return limbs, peak
-
-
 def bound_fe_add(curve: str, ba, bb, rounds: int = None) -> Tuple[List[int], int]:
     x = [a + b for a, b in zip(ba, bb)]
     peak = max(x)
@@ -1145,8 +731,7 @@ def bound_fe_mul_small(curve: str, ba, k: int,
     return x, peak
 
 
-def bound_closed_set(curve: str, backend: str = "vpu",
-                     ksub: Sequence[int] = (), iters: int = 64,
+def bound_closed_set(curve: str, ksub: Sequence[int] = (), iters: int = 64,
                      check_ksub: bool = True) -> Tuple[List[int], int]:
     """Fixed point of the op mix: starting from fresh-input bounds (MASK),
     iterate max(mul, add, sub) until the per-row bounds stop growing.
@@ -1155,7 +740,7 @@ def bound_closed_set(curve: str, backend: str = "vpu",
     bounds = [MASK] * NLIMB
     peak = 0
     for _ in range(iters):
-        bm, p1 = bound_fe_mul(curve, bounds, bounds, backend)
+        bm, p1 = bound_fe_mul(curve, bounds, bounds)
         ba, p2 = bound_fe_add(curve, bounds, bounds)
         bs, p3 = (bound_fe_sub(curve, bounds, bounds, ksub,
                                check=check_ksub)
@@ -1165,7 +750,7 @@ def bound_closed_set(curve: str, backend: str = "vpu",
         if nxt == bounds:
             return bounds, peak
         bounds = nxt
-    raise AssertionError(f"{curve}/{backend}: carried bounds did not converge")
+    raise AssertionError(f"{curve}: carried bounds did not converge")
 
 
 # ---------------------------------------------------------------------------
@@ -1349,8 +934,7 @@ def _dominating_ksub(curve: str, prime: int, mult0: int) -> List[int]:
     floor = [2 * MASK] * NLIMB
     for _ in range(8):
         ks, _ = mk_wide_multiple(prime, floor, mult0)
-        cs, _ = bound_closed_set(curve, "vpu", ksub=tuple(ks),
-                                 check_ksub=False)
+        cs, _ = bound_closed_set(curve, ksub=tuple(ks), check_ksub=False)
         if all(k >= b for k, b in zip(ks, cs)):
             return ks
         floor = [max(f, b) for f, b in zip(floor, cs)]
@@ -1360,8 +944,7 @@ def _dominating_ksub(curve: str, prime: int, mult0: int) -> List[int]:
 SECP_KSUB_LIMBS = _dominating_ksub("secp256k1", SECP_P, 64)
 # ed25519's 4*MASK floor already dominates its closed set — assert rather
 # than trust (same soundness condition as the secp derivation above)
-_ED_CS_CHECK, _ = bound_closed_set("ed25519", "vpu",
-                                   ksub=tuple(ED_KSUB_LIMBS))
+_ED_CS_CHECK, _ = bound_closed_set("ed25519", ksub=tuple(ED_KSUB_LIMBS))
 assert all(k >= b for k, b in zip(ED_KSUB_LIMBS, _ED_CS_CHECK))
 del _ED_CS_CHECK
 
@@ -1447,16 +1030,11 @@ B3_SMALL = 21  # 3*b of the secp256k1 curve equation, RCB16's only scalar
 
 
 @lru_cache(maxsize=None)
-def derive_carry_plan(curve: str, backend: str = "vpu") -> SimpleNamespace:
+def derive_carry_plan(curve: str) -> SimpleNamespace:
     """Certified lazy carry plan: iterate the kernel's deferred-carry chain
     set to a fixed point and return the operand classes, KD constant, and
     per-op round/fixup schedule.  The mulF wide count is SEARCHED (smallest
-    that converges), not stated.  Raises for mxu16 — callers degrade it to
-    eager via effective_carry_mode."""
-    if backend == "mxu16":
-        raise ValueError("mxu16 has no lazy carry plan; use effective_carry_mode")
-    if backend not in FE_BACKENDS:
-        raise ValueError(f"fe backend must be one of {FE_BACKENDS}, got {backend!r}")
+    that converges), not stated."""
     closed = _ed_lazy_closed if curve == "ed25519" else _secp_lazy_closed
     if curve not in ("ed25519", "secp256k1"):
         raise ValueError(f"unknown curve {curve!r}")
@@ -1467,21 +1045,15 @@ def derive_carry_plan(curve: str, backend: str = "vpu") -> SimpleNamespace:
     else:
         raise AssertionError(f"{curve}: lazy chain set never converged")
     assert peak < U32, f"{curve} lazy peak {peak:.3e} overflows uint32"
-    if backend == "mxu":
-        # lazy-mode multiply operands (C and raw C+C sums) must fit the
-        # uint8 plane split the lazy ops pin (split=8)
-        worst = 2 * max(C) if curve == "ed25519" else max(
-            max(C) * 2, max(bound_secp_norm1([2 * c for c in C])[0]))
-        assert worst <= 65535, f"{curve} mxu lazy operands reach {worst}"
     ksub = ED_KSUB_LIMBS if curve == "ed25519" else SECP_KSUB_LIMBS
-    eager_cs, _ = bound_closed_set(curve, "vpu", tuple(ksub))
+    eager_cs, _ = bound_closed_set(curve, tuple(ksub))
     # Epilogue certificate: eager ops must accept class-C inputs.  Close the
     # eager op mix seeded at max(C, eager closed set) — this is the domain
     # the eager fe_inv / fe_canonical chains see when fed lazy outputs.
     cs_epi = [max(a, b) for a, b in zip(C, eager_cs)]
     epi_peak = 0
     for _ in range(64):
-        bm, p1 = bound_fe_mul(curve, cs_epi, cs_epi, "vpu")
+        bm, p1 = bound_fe_mul(curve, cs_epi, cs_epi)
         ba, p2 = bound_fe_add(curve, cs_epi, cs_epi)
         bs, p3 = bound_fe_sub(curve, cs_epi, cs_epi, ksub)
         nxt = [max(vals) for vals in zip(bm, ba, bs)]
@@ -1492,11 +1064,6 @@ def derive_carry_plan(curve: str, backend: str = "vpu") -> SimpleNamespace:
     else:
         raise AssertionError(f"{curve}: epilogue closure did not converge")
     assert epi_peak < U32
-    if backend == "mxu":
-        # the XLA eager epilogue keeps the curve's plane split (7 for ed)
-        limit = 16383 if curve == "ed25519" else 65535
-        assert max(cs_epi) <= limit, (
-            f"{curve} mxu eager epilogue operands reach {max(cs_epi)}")
     if curve == "ed25519":
         assert max(cs_epi) <= ED_M, (
             f"ed25519 epilogue limbs {max(cs_epi)} leave _canonical_ref's "
@@ -1516,15 +1083,15 @@ def derive_carry_plan(curve: str, backend: str = "vpu") -> SimpleNamespace:
     assert all(a <= b for a, b in zip(C, D)), f"{curve}: class C exceeds D"
     if curve == "ed25519":
         return SimpleNamespace(
-            curve=curve, backend=backend, c=C, d=D, kd=KD, kd_mult=kd_mult,
+            curve=curve, c=C, d=D, kd=KD, kd_mult=kd_mult,
             ksub=list(ksub), mulf_wide=mulf_wide, mull_wide=1, norm_wide=1,
-            mid=0, mulf_fix=(0,), mull_fix=(0,), norm_fix=(0,), split=8,
+            mid=0, mulf_fix=(0,), mull_fix=(0,), norm_fix=(0,),
             peak=peak, iters=iters)
     return SimpleNamespace(
-        curve=curve, backend=backend, c=C, d=D, kd=KD, kd_mult=kd_mult,
+        curve=curve, c=C, d=D, kd=KD, kd_mult=kd_mult,
         ksub=list(ksub), mulf_wide=mulf_wide, mull_wide=1, norm_wide=1,
         mid=1, mulf_fix=(0, 1, 2, 3), mull_fix=(0,), norm_fix=(0, 1, 2, 3),
-        split=8, peak=peak, iters=iters)
+        peak=peak, iters=iters)
 
 
 @lru_cache(maxsize=None)
@@ -1534,7 +1101,7 @@ def derive_eager_rounds(curve: str) -> dict:
     < 2^32.  The import-time asserts below pin the module constants (and so
     the jnp ops) to exactly these values."""
     ksub = ED_KSUB_LIMBS if curve == "ed25519" else SECP_KSUB_LIMBS
-    cs, _ = bound_closed_set(curve, "vpu", tuple(ksub))
+    cs, _ = bound_closed_set(curve, tuple(ksub))
 
     def minimal(op):
         for r in range(1, 9):
@@ -1545,7 +1112,7 @@ def derive_eager_rounds(curve: str) -> dict:
 
     derived = {
         "mul_tail": minimal(
-            lambda r: bound_fe_mul(curve, cs, cs, "vpu", tail_rounds=r)),
+            lambda r: bound_fe_mul(curve, cs, cs, tail_rounds=r)),
         "add": minimal(lambda r: bound_fe_add(curve, cs, cs, rounds=r)),
         "sub": minimal(lambda r: bound_fe_sub(curve, cs, cs, ksub, rounds=r)),
     }

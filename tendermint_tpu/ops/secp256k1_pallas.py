@@ -20,10 +20,9 @@ Differences from the bit-serial XLA kernel (768 complete adds/signature):
 
 Field arithmetic is the row-layout port of the (carry-safe) XLA ops: radix
 2^13, 20 uint32 limb rows, two-term fold 2^260 ≡ 2^36 + 15632 (mod p),
-shared with the ed25519 kernel through ops/fe_common — which also provides
-the MXU int8-plane multiplier selected by the `[verify] fe_backend` knob
-(threaded through verify_batch below). Overflow bounds are recomputed
-mechanically by fe_common.bound_* and asserted in tests/test_fe_common.py;
+shared with the ed25519 kernel through ops/fe_common. Overflow bounds are
+recomputed mechanically by fe_common.bound_* and asserted in
+tests/test_fe_common.py;
 parity with the host oracle over randomized and adversarial batches is
 enforced by tests/test_ops_secp256k1.
 
@@ -67,24 +66,22 @@ _K_SUB = _xla._K_SUB
 
 # ---------------------------------------------------------------------------
 # Row-layout field ops: (20, B) blocks, batch on lanes — shared with the
-# ed25519 kernel via ops/fe_common (the VPU schoolbook and the MXU int8-plane
-# multipliers live there; overflow bounds are recomputed mechanically by
+# ed25519 kernel via ops/fe_common (the VPU schoolbook multiplier lives
+# there; overflow bounds are recomputed mechanically by
 # fe_common.bound_* and asserted in tests/test_fe_common.py)
 # ---------------------------------------------------------------------------
 
 from tendermint_tpu.ops import fe_common as _fc
 from tendermint_tpu.ops.dispatch import call_jit
 
-_FE = {(b, "eager"): _fc.make_fe("secp256k1", b) for b in _fc.FE_BACKENDS}
-_FE_VPU = _FE[("vpu", "eager")]
+_FE = {"eager": _fc.make_fe("secp256k1")}
+_FE_EAGER = _FE["eager"]
 
 
-def _get_fe(backend: str, carry_mode: str = "eager"):
-    mode = _fc.effective_carry_mode(backend, carry_mode)
-    key = (backend, mode)
-    if key not in _FE:
-        _FE[key] = _fc.make_fe("secp256k1", backend, carry_mode=mode)
-    return _FE[key]
+def _get_fe(carry_mode: str = "eager"):
+    if carry_mode not in _FE:
+        _FE[carry_mode] = _fc.make_fe("secp256k1", carry_mode=carry_mode)
+    return _FE[carry_mode]
 
 # backward-compatible module-level surface (tests/test_ops_secp256k1.py and
 # the XLA kernel's parity checks import these directly)
@@ -102,7 +99,7 @@ fe_mul_small = _fc.secp_fe_mul_small
 # ---------------------------------------------------------------------------
 
 
-def pt_add(p, q, ksub, fe=_FE_VPU, kd=None):
+def pt_add(p, q, ksub, fe=_FE_EAGER, kd=None):
     X1, Y1, Z1 = p
     X2, Y2, Z2 = q
     if fe.carry_mode == "lazy":
@@ -224,26 +221,23 @@ def _canonical_ref(v, s1, s2):
 
 
 def ladder_math(consts, qx, qy, dig1_get, dig2_get, nwin: int = NWIN,
-                loop=lax.fori_loop, fe_backend: str = "vpu",
-                carry_mode: str = "lazy"):
+                loop=lax.fori_loop, carry_mode: str = "lazy"):
     """The windowed-Straus double-scalar multiply u1·G + u2·Q — pure jnp,
     shared by the pallas kernel (on ref values) and the CPU parity tests.
     dig1_get/dig2_get: t -> (1, B) digit row accessors (a ref slice
     in-kernel, an array row in tests). nwin < NWIN drives the identical
     code with small scalars, and tests swap `loop` for a plain Python loop
     to evaluate eagerly (XLA's CPU compile of this graph thrashes for
-    ~10 min in the simplifier). fe_backend picks the limb multiplier
-    (fe_common.FE_BACKENDS); carry_mode "lazy" defers carries between
+    ~10 min in the simplifier). carry_mode "lazy" defers carries between
     point ops per fe_common.derive_carry_plan. Returns projective
     (X, Y, Z) — coordinates land in the certified class C under lazy,
     congruent mod p to the eager result."""
-    mode = _fc.effective_carry_mode(fe_backend, carry_mode)
-    fe = _get_fe(fe_backend, mode)
+    fe = _get_fe(carry_mode)
     B = qx.shape[1]
     zero = jnp.zeros((NLIMB, B), jnp.uint32)
     one = jnp.pad(jnp.ones((1, B), jnp.uint32), ((0, NLIMB - 1), (0, 0)))
     ksub = consts[:, 48:49]
-    kd = consts[:, 49:50] if mode == "lazy" else None
+    kd = consts[:, 49:50] if carry_mode == "lazy" else None
 
     q1 = (qx, qy, one)
     ident = (zero, one, zero)  # (0:1:0)
@@ -285,7 +279,7 @@ def ladder_math(consts, qx, qy, dig1_get, dig2_get, nwin: int = NWIN,
 
 def _ladder_kernel(consts_ref, qx_ref, qy_ref, dig1_ref, dig2_ref,
                    rl_ref, rnl_ref, rnok_ref, out_ref, s1, s2,
-                   fe_backend: str = "vpu", carry_mode: str = "lazy"):
+                   carry_mode: str = "lazy"):
     consts = consts_ref[:]
     ksub = consts[:, 48:49]
     X, _Y, Z = ladder_math(
@@ -293,16 +287,14 @@ def _ladder_kernel(consts_ref, qx_ref, qy_ref, dig1_ref, dig2_ref,
         lambda t: dig1_ref[pl.ds(t, 1), :],
         lambda t: dig2_ref[pl.ds(t, 1), :],
         nwin=dig1_ref.shape[0],
-        fe_backend=fe_backend,
         carry_mode=carry_mode,
     )
 
-    mode = _fc.effective_carry_mode(fe_backend, carry_mode)
-    fe = _get_fe(fe_backend, mode)
+    fe = _get_fe(carry_mode)
     # Under lazy, X/Z sit in the certified class C and fe.sub's norm1
     # output re-enters the eager closed set after _canonical_ref's two
     # opening carry rounds (the re-entry certificate in derive_carry_plan).
-    ks = consts[:, 49:50] if mode == "lazy" else ksub
+    ks = consts[:, 49:50] if carry_mode == "lazy" else ksub
     z_can = _canonical_ref(Z, s1, s2)
     nonzero = jnp.any(z_can != 0, axis=0, keepdims=True)
     # x(R) ≡ r  ⇔  X ≡ r·Z  (Z ≠ 0); same for the r+n representative
@@ -314,7 +306,7 @@ def _ladder_kernel(consts_ref, qx_ref, qy_ref, dig1_ref, dig2_ref,
 
 
 def _ladder_call(qx, qy, dig1, dig2, rl, rnl, rnok, *, interpret=False,
-                 lanes=LANES, fe_backend="vpu", carry_mode="lazy"):
+                 lanes=LANES, carry_mode="lazy"):
     """qx/qy/rl/rnl (20, N); dig1/dig2 (nwin, N) — NWIN=64 in production,
     fewer in the reduced interpret tests; rnok (1, N); N % lanes == 0."""
     n = qx.shape[1]
@@ -324,7 +316,7 @@ def _ladder_call(qx, qy, dig1, dig2, rl, rnl, rnok, *, interpret=False,
     spec64 = pl.BlockSpec((nwin, lanes), lambda i: (0, i), memory_space=pltpu.VMEM)
     spec1 = pl.BlockSpec((1, lanes), lambda i: (0, i), memory_space=pltpu.VMEM)
     return pl.pallas_call(
-        partial(_ladder_kernel, fe_backend=fe_backend, carry_mode=carry_mode),
+        partial(_ladder_kernel, carry_mode=carry_mode),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.uint32),
         grid=(n // lanes,),
         in_specs=[cspec, spec20, spec20, spec64, spec64, spec20, spec20, spec1],
@@ -340,14 +332,13 @@ _CONSTS = _build_g_table()
 # The compiled entry of the real-device path, under a name of its own: the
 # profiler calls the operation after the jitted function, and ed25519_pallas
 # has a _ladder_call too, inside _device_verify_packed.
-@partial(jax.jit, static_argnames=("lanes", "fe_backend", "carry_mode"))
+@partial(jax.jit, static_argnames=("lanes", "carry_mode"))
 def _device_verify_secp256k1(qx, qy, dig1, dig2, rl, rnl, rnok, lanes=LANES,
-                             fe_backend="vpu", carry_mode="lazy"):
+                             carry_mode="lazy"):
     """Lane-major as the host packs them: qx/qy/rl/rnl (b, 20), dig1/dig2
     (b, 64), rnok (b,); turned limb-major for the kernel on the device."""
     ok = _ladder_call(qx.T, qy.T, dig1.T, dig2.T, rl.T, rnl.T, rnok[None, :],
-                      lanes=lanes, fe_backend=fe_backend,
-                      carry_mode=carry_mode)
+                      lanes=lanes, carry_mode=carry_mode)
     return ok[0]
 
 
@@ -373,12 +364,10 @@ def verify_batch(
     digests: Sequence[bytes],
     sigs: Sequence[bytes],
     interpret: bool = False,
-    fe_backend: str = "vpu",
     carry_mode: str = "lazy",
 ) -> np.ndarray:
     """Batched ECDSA verify on the Pallas path — same contract (and the
-    same host prologue) as secp256k1_verify.verify_batch. `fe_backend`
-    selects the limb multiplier (fe_common.FE_BACKENDS); `carry_mode`
+    same host prologue) as secp256k1_verify.verify_batch. `carry_mode`
     "lazy" (default) defers limb carries between point ops, "eager" keeps
     the per-op full carry ripple; verdicts are bit-exact either way.
 
@@ -387,7 +376,6 @@ def verify_batch(
     (``prep_batch``'s two passes round one inversion, which ed25519 has no
     counterpart of),
     ``dispatch.pack``, ``dispatch.launch``, ``dispatch.wait``."""
-    fe_backend = _fc.normalize_backend(fe_backend)
     carry_mode = _fc.normalize_carry_mode(carry_mode)
     n = len(pubkeys)
     if n == 0:
@@ -432,11 +420,11 @@ def verify_batch(
             out = _ladder_call(
                 *(jnp.asarray(a.T) for a in host[:6]),
                 jnp.asarray(rnok[None, :]), interpret=True, lanes=lanes,
-                fe_backend=fe_backend, carry_mode=carry_mode)[0]
+                carry_mode=carry_mode)[0]
         else:
             out = call_jit(_device_verify_secp256k1,
                            *(jnp.asarray(a) for a in host), lanes=lanes,
-                           fe_backend=fe_backend, carry_mode=carry_mode)
+                           carry_mode=carry_mode)
     # the device's run, the copy back and the wake of this thread
     with trace.span("dispatch.wait", lanes=b):
         ok = np.asarray(out)[:n]

@@ -109,15 +109,9 @@ def fe_sub(a, b):
     return fe_carry(a + _K_SUB - b, rounds=3)
 
 
-# Limb-multiplier backend, same trace-time mechanism as ed25519_verify:
-# "mxu" swaps only the column computation for fe_common.mul_columns_batch
-# (4 uint8-plane matmuls, split=8 — secp's carried limb 0 can exceed the
-# int8 plane bound; see fe_common._columns_mxu_rows). Set exclusively by
-# _compiled_kernel's wrapper; the jit cache is keyed on it.
-_FE_BACKEND = "vpu"
-
-# Carry schedule for the ladder's pt_add chain — swapped trace-time via
-# fe_common.trace_with_modes exactly like _FE_BACKEND; the module-level
+# Carry schedule for the ladder's pt_add chain — same trace-time mechanism
+# as ed25519_verify: set exclusively by _compiled_kernel's wrapper
+# (fe_common.trace_with_modes), the jit cache keyed on it; the module-level
 # fe_mul/fe_add/fe_sub/fe_mul_small stay the eager ops regardless.
 _CARRY_MODE = "eager"
 
@@ -142,12 +136,7 @@ def fe_mul(a, b):
     into lo with FULL values (≤ 8200·15632 < 2^27 — nothing masked away).
     """
     shape = jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1])
-    if _FE_BACKEND != "vpu":
-        prod = _fc.mul_columns_batch(a, b, 2 * NLIMB + 1, split=8)
-    else:
-        prod = jnp.zeros(shape + (2 * NLIMB + 1,), dtype=jnp.uint32)
-        for i in range(NLIMB):
-            prod = prod.at[..., i : i + NLIMB].add(a[..., i : i + 1] * b)
+    prod = _mul_cols(a, b)
     for _ in range(3):
         c = prod >> BITS
         prod = (prod & MASK).at[..., 1:].add(c[..., :-1])
@@ -180,9 +169,8 @@ def fe_mul_small(a, k: int):
 # counts come from fe_common.derive_carry_plan (certified at import).
 
 
-def _lazy_mul_cols(a, b):
-    if _FE_BACKEND != "vpu":
-        return _fc.mul_columns_batch(a, b, 2 * NLIMB + 1, split=8)
+def _mul_cols(a, b):
+    """Schoolbook product columns: 20 shifted multiply-accumulates."""
     shape = jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1])
     prod = jnp.zeros(shape + (2 * NLIMB + 1,), dtype=jnp.uint32)
     for i in range(NLIMB):
@@ -191,7 +179,7 @@ def _lazy_mul_cols(a, b):
 
 
 def _lazy_mul(a, b, wide, fix):
-    tmp = _fc.secp_fold_fused_batch(_lazy_mul_cols(a, b))
+    tmp = _fc.secp_fold_fused_batch(_mul_cols(a, b))
     for _ in range(_PLAN.mid):
         tmp = _fc.carry_drop_top_batch(tmp)
     lo = _fc.secp_fold2_batch(tmp)
@@ -374,16 +362,12 @@ def _verify_kernel(qx, qy, u1_words, u2_words, r_limbs, rn_limbs, rn_ok):
 _kernel_cache: dict = {}
 
 
-def _compiled_kernel(batch: int, mesh=None, fe_backend: str = "vpu",
-                     carry_mode: str = "eager"):
-    carry_mode = _fc.effective_carry_mode(fe_backend, carry_mode)
-    if fe_backend not in ("vpu", "mxu"):
-        fe_backend = "mxu" if fe_backend == "mxu16" else "vpu"
-    key = (batch, mesh, fe_backend, carry_mode)
+def _compiled_kernel(batch: int, mesh=None, carry_mode: str = "eager"):
+    key = (batch, mesh, carry_mode)
     fn = _kernel_cache.get(key)
     if fn is None:
         kernel = _fc.trace_with_modes(
-            sys.modules[__name__], _verify_kernel, fe_backend, carry_mode
+            sys.modules[__name__], _verify_kernel, carry_mode
         )
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as PS
@@ -543,15 +527,12 @@ def verify_batch(
     digests: Sequence[bytes],
     sigs: Sequence[bytes],
     mesh=None,
-    fe_backend: str = "vpu",
     carry_mode: str = "lazy",
 ) -> np.ndarray:
     """Batched ECDSA verify; bit-exact with crypto/secp256k1.verify.
     pubkeys: 33-byte compressed; digests: 32 bytes; sigs: DER.
-    fe_backend: "vpu" | "mxu" limb multiplier ("mxu16" degrades to "mxu");
     carry_mode "lazy" (default) defers limb carries between point ops,
     "eager" keeps the full per-op ripple — verdicts are bit-exact both ways."""
-    fe_backend = _fc.normalize_backend(fe_backend)
     carry_mode = _fc.normalize_carry_mode(carry_mode)
     n = len(pubkeys)
     if n == 0:
@@ -585,7 +566,7 @@ def verify_batch(
             rn_ok[i] = True
     record_prologue(reasons, inversions)
 
-    kernel = _compiled_kernel(b, mesh, fe_backend, carry_mode)
+    kernel = _compiled_kernel(b, mesh, carry_mode)
     host = (qx, qy, u1w, u2w, rl, rnl, rn_ok)
     if mesh is not None:
         # device_put the *numpy* arrays straight onto the mesh sharding: an

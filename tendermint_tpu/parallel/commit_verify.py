@@ -135,18 +135,18 @@ _step_cache = {}
 _compiled_shapes = set()
 
 
-def _compiled_step(mesh, fe_backend: str = "vpu", carry_mode: str = "lazy"):
+# the carry schedule the window step traces with, and its records' label
+_CARRY_MODE = "lazy"
+
+
+def _compiled_step(mesh):
     from tendermint_tpu.ops import fe_common as _fc
 
-    # the XLA kernel has no mxu16 lowering — degrade to the plane multiplier
-    fe_backend = "mxu" if fe_backend in ("mxu", "mxu16") else "vpu"
-    carry_mode = _fc.effective_carry_mode(fe_backend, carry_mode)
     # Mesh hashes by devices+axis_names; id() could be gc-reused
-    key = (mesh, fe_backend, carry_mode)
-    fn = _step_cache.get(key)
+    fn = _step_cache.get(mesh)
     if fn is not None:
         return fn
-    step = _fc.trace_with_modes(_k, _step, fe_backend, carry_mode)
+    step = _fc.trace_with_modes(_k, _step, _CARRY_MODE)
     if mesh is None:
         fn = jax.jit(step)
     else:
@@ -161,7 +161,7 @@ def _compiled_step(mesh, fe_backend: str = "vpu", carry_mode: str = "lazy"):
             in_shardings=(hv,) * 8 + (rep,),
             out_shardings=(hv, h_only, h_only),
         )
-    _step_cache[key] = fn
+    _step_cache[mesh] = fn
     return fn
 
 
@@ -317,9 +317,9 @@ def verify_commit_window(
         return out
 
 
-# (fe_backend, carry_mode) combos whose MSM kernel dispatched at least once
-# here — first dispatch carries the jit trace/compile (latency attribution)
-_msm_warm = set()
+# whether the MSM kernel has dispatched here — the first dispatch carries
+# the jit trace/compile (latency attribution)
+_msm_warm = False
 
 
 def _verify_window_device_msm(
@@ -332,17 +332,12 @@ def _verify_window_device_msm(
     chunk RLCs and exact ladder re-runs inside rlc_verify_batch — and the
     verify_commit_window guard/audit wrapping applies unchanged.  The MSM
     folds to one point equation, so the mesh is not consulted."""
-    from tendermint_tpu.crypto.batch import _resolve_fe_backend
-    from tendermint_tpu.ops import fe_common as _fc
-
+    global _msm_warm
     H, V = win.shape
     coords, pubs_l, msgs_l, sigs_l = win.raw
     n = len(pubs_l)
-    fe_backend = _resolve_fe_backend(None)
-    carry_mode = _fc.effective_carry_mode(
-        "mxu" if fe_backend in ("mxu", "mxu16") else "vpu", "lazy")
-    first = (fe_backend, carry_mode) not in _msm_warm
-    _msm_warm.add((fe_backend, carry_mode))
+    first = not _msm_warm
+    _msm_warm = True
     ok = np.zeros((H, V), dtype=bool)
     t0 = time.perf_counter()
     with trace.span("verify.window_dispatch", backend="window_msm",
@@ -352,7 +347,7 @@ def _verify_window_device_msm(
             sigs = np.frombuffer(b"".join(sigs_l), np.uint8).reshape(n, 64)
             res = _k.rlc_verify_batch(
                 pubs, msgs_l, sigs,
-                fe_backend=fe_backend, carry_mode=carry_mode,
+                carry_mode=_CARRY_MODE,
             )
             ok[coords[:, 0], coords[:, 1]] = res
     ok &= win.present
@@ -364,8 +359,7 @@ def _verify_window_device_msm(
         m.record_dispatch(
             "window_msm", "ed25519", n, dt,
             rejects=int(np.count_nonzero(win.present & ~ok)), first=first,
-            fe_backend=fe_backend,
-            carry_mode=carry_mode,
+            carry_mode=_CARRY_MODE,
             ed25519_path="msm",
         )
         get_profiler().record(
@@ -380,8 +374,7 @@ def _verify_window_device_msm(
             # upload ≈ the extended-point pool: 2 points per pair row,
             # 4 coords x 20 uint32 limbs each
             bytes_to_device=n * 2 * 4 * 20 * 4,
-            fe_backend=fe_backend,
-            carry_mode=carry_mode,
+            carry_mode=_CARRY_MODE,
             ed25519_path="msm",
             n_windows=1,
             n_devices=1,
@@ -423,14 +416,7 @@ def _verify_window_device(
     # consensus-safety bug.  Scope the flag to this dispatch instead of
     # flipping global dtype semantics for the whole process at import time.
     backend = "window_mesh" if mesh is not None else "window"
-    from tendermint_tpu.crypto.batch import _resolve_fe_backend
-
-    fe_backend = _resolve_fe_backend(None)
-    from tendermint_tpu.ops import fe_common as _fc
-
-    carry_mode = _fc.effective_carry_mode(
-        "mxu" if fe_backend in ("mxu", "mxu16") else "vpu", "lazy")
-    shape_key = (mesh, (ph, pv), fe_backend, carry_mode)
+    shape_key = (mesh, (ph, pv))
     first = shape_key not in _compiled_shapes
     _compiled_shapes.add(shape_key)
     n = int(np.count_nonzero(win.present))
@@ -442,7 +428,7 @@ def _verify_window_device(
 
                 hv = NamedSharding(mesh, PS(*mesh.axis_names[:2]))
                 arrs = [jax.device_put(a, hv) for a in arrs]
-            ok, tally, committed = _compiled_step(mesh, fe_backend, carry_mode)(
+            ok, tally, committed = _compiled_step(mesh)(
                 *arrs, np.int64(total_power)
             )
             ok = np.asarray(ok)[:H, :V]
@@ -455,8 +441,7 @@ def _verify_window_device(
         m.record_dispatch(
             backend, "ed25519", n, dt,
             rejects=int(np.count_nonzero(win.present & ~ok)), first=first,
-            fe_backend=fe_backend,
-            carry_mode=carry_mode,
+            carry_mode=_CARRY_MODE,
             ed25519_path="ladder",
         )
         if mesh is not None:
@@ -475,8 +460,7 @@ def _verify_window_device(
             run_seconds=dt,
             compiled=first,
             bytes_to_device=sum(a.nbytes for a in arrs),
-            fe_backend=fe_backend,
-            carry_mode=carry_mode,
+            carry_mode=_CARRY_MODE,
             ed25519_path="ladder",
             n_windows=1,
             n_devices=n_devices,

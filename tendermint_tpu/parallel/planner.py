@@ -493,37 +493,28 @@ def _planner_step_lanes(
     return raw & present
 
 
-def _resolve_carry_mode(fe_backend: str) -> str:
-    """The carry schedule the planner step traces with — lazy (the batch
-    verifier's optimized schedule) except where the backend has no lazy
-    plan (fe_common.effective_carry_mode's mxu16 degrade)."""
-    from tendermint_tpu.ops import fe_common as _fc
-
-    return _fc.effective_carry_mode(fe_backend, "lazy")
+# the carry schedule the planner's device steps trace with (the batch
+# verifier's optimized schedule), and their dispatch records' label
+_CARRY_MODE = "lazy"
 
 
-def _compiled_step(mesh, B: int, S: int, fe_backend: str = "vpu",
-                   carry_mode: str = "lazy", reduce: str = "device"):
-    """jit'd step for one (mesh, lane bucket, seg bucket, fe backend, carry
-    mode, reduction side); returns (fn, compiled) where compiled marks a
-    cache miss (a real jit trace — padded shapes are fixed per bucket, so
-    key miss == recompile)."""
+def _compiled_step(mesh, B: int, S: int, reduce: str = "device"):
+    """jit'd step for one (mesh, lane bucket, seg bucket, reduction side);
+    returns (fn, compiled) where compiled marks a cache miss (a real jit
+    trace — padded shapes are fixed per bucket, so key miss == recompile)."""
     global _compiles
     import jax
 
     from tendermint_tpu.ops import ed25519_verify as _k
     from tendermint_tpu.ops import fe_common as _fc
 
-    # the XLA kernel has no mxu16 lowering — degrade to the plane multiplier
-    fe_backend = "mxu" if fe_backend in ("mxu", "mxu16") else "vpu"
-    carry_mode = _fc.effective_carry_mode(fe_backend, carry_mode)
-    key = (mesh, B, S, fe_backend, carry_mode, reduce)
+    key = (mesh, B, S, reduce)
     with _cache_mtx:
         fn = _step_cache.get(key)
         if fn is not None:
             return fn, False
         body = _planner_step_lanes if reduce == "host" else _planner_step
-        step = _fc.trace_with_modes(_k, body, fe_backend, carry_mode)
+        step = _fc.trace_with_modes(_k, body, _CARRY_MODE)
         if mesh is None:
             fn = jax.jit(step)
         else:
@@ -560,9 +551,9 @@ def _host_reduce(plan: WindowPlan, ok_l: np.ndarray):
     return tally, committed, nbad
 
 
-# (fe_backend, carry_mode) combos whose MSM kernel has dispatched at least
-# once in this process — the first dispatch pays the jit trace/compile
-_msm_warm: set = set()
+# whether the MSM kernel has dispatched in this process — the first dispatch
+# pays the jit trace/compile
+_msm_warm = False
 
 
 def _execute_device_msm(plan: WindowPlan, mesh=None) -> WindowVerdict:
@@ -574,16 +565,14 @@ def _execute_device_msm(plan: WindowPlan, mesh=None) -> WindowVerdict:
     chunk RLCs then exact ladder rows — keeping accept/reject
     bit-identical to the per-lane path, and the PR 9 guard/audit wrapping
     (_execute_device_guarded) applies unchanged."""
-    from tendermint_tpu.crypto.batch import _resolve_fe_backend
+    global _msm_warm
     from tendermint_tpu.ops import ed25519_verify as _k
 
-    fe_backend = _resolve_fe_backend(None)
-    carry_mode = _resolve_carry_mode(fe_backend)
     n = plan.n_lanes
     ok_l = np.zeros((n,), dtype=bool)
     wf = np.asarray(plan.wellformed, dtype=bool)
     rows = np.nonzero(wf)[0] if n else np.zeros((0,), dtype=np.int64)
-    first = (fe_backend, carry_mode) not in _msm_warm
+    first = not _msm_warm
     t0 = time.perf_counter()
     with trace.span(
         "planner.dispatch", backend="planner_msm", H=plan.H, lanes=n, n=n,
@@ -600,9 +589,9 @@ def _execute_device_msm(plan: WindowPlan, mesh=None) -> WindowVerdict:
             ).reshape(rows.size, 64)
             ok_l[rows] = _k.rlc_verify_batch(
                 pubs_a, [plan.msgs[j] for j in rows], sigs_a,
-                fe_backend=fe_backend, carry_mode=carry_mode,
+                carry_mode=_CARRY_MODE,
             )
-    _msm_warm.add((fe_backend, carry_mode))
+    _msm_warm = True
     dt = time.perf_counter() - t0
     tally, committed, nbad = _host_reduce(plan, ok_l)
     try:
@@ -611,8 +600,7 @@ def _execute_device_msm(plan: WindowPlan, mesh=None) -> WindowVerdict:
         m.record_dispatch(
             "planner_msm", "ed25519", n, dt,
             rejects=int(np.count_nonzero(wf & ~ok_l)),
-            first=first, fe_backend=fe_backend, carry_mode=carry_mode,
-            ed25519_path="msm",
+            first=first, carry_mode=_CARRY_MODE, ed25519_path="msm",
         )
         get_profiler().record(
             "planner_msm",
@@ -626,8 +614,7 @@ def _execute_device_msm(plan: WindowPlan, mesh=None) -> WindowVerdict:
             # upload ≈ the extended-point pool: 2 points per pair row,
             # 4 coords x 20 uint32 limbs each (schedule indices are noise)
             bytes_to_device=int(rows.size) * 2 * 4 * 20 * 4,
-            fe_backend=fe_backend,
-            carry_mode=carry_mode,
+            carry_mode=_CARRY_MODE,
             ed25519_path="msm",
             n_windows=plan.n_windows,
             n_devices=1,
@@ -651,10 +638,7 @@ def _execute_device(plan: WindowPlan, mesh=None) -> WindowVerdict:
     import jax
 
     from tendermint_tpu.ops.dispatch import call_jit
-    from tendermint_tpu.crypto.batch import (
-        _resolve_ed25519_path,
-        _resolve_fe_backend,
-    )
+    from tendermint_tpu.crypto.batch import _resolve_ed25519_path
 
     if _resolve_ed25519_path(None) == "msm":
         return _execute_device_msm(plan, mesh)
@@ -662,11 +646,8 @@ def _execute_device(plan: WindowPlan, mesh=None) -> WindowVerdict:
     B, S = plan.dev_shape
     n = plan.n_lanes
 
-    fe_backend = _resolve_fe_backend(None)
-    carry_mode = _resolve_carry_mode(fe_backend)
     reduce = _reduce_mode
-    fn, compiled = _compiled_step(
-        mesh, B, S, fe_backend, carry_mode, reduce)
+    fn, compiled = _compiled_step(mesh, B, S, reduce)
     t0 = time.perf_counter()
     backend = "planner_mesh" if mesh is not None else "planner"
     with trace.span(
@@ -705,8 +686,7 @@ def _execute_device(plan: WindowPlan, mesh=None) -> WindowVerdict:
             backend, "ed25519", n, dt,
             rejects=int(np.count_nonzero(plan.dev[6][:n] & ~ok_l)),
             first=compiled,
-            fe_backend=fe_backend,
-            carry_mode=carry_mode,
+            carry_mode=_CARRY_MODE,
             ed25519_path="ladder",
         )
         if mesh is not None:
@@ -724,8 +704,7 @@ def _execute_device(plan: WindowPlan, mesh=None) -> WindowVerdict:
             run_seconds=dt,
             compiled=compiled,
             bytes_to_device=sum(a.nbytes for a in plan.dev),
-            fe_backend=fe_backend,
-            carry_mode=carry_mode,
+            carry_mode=_CARRY_MODE,
             ed25519_path="ladder",
             n_windows=plan.n_windows,
             n_devices=n_devices,

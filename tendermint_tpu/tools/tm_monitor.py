@@ -87,9 +87,9 @@ def _phase_label(key: str, family: str) -> Optional[str]:
 
 def _verify_path(metrics: Dict[str, float]) -> str:
     """Which ed25519 verify strategy served device windows, from the
-    `ed25519_path` label of verify_fe_backend_total: "ladder", "msm",
+    `ed25519_path` label of verify_path_total: "ladder", "msm",
     "mixed" when both appear, "-" when no device dispatch recorded."""
-    fam = "tendermint_verify_fe_backend_total{"
+    fam = "tendermint_verify_path_total{"
     seen = set()
     for k, v in metrics.items():
         if not k.startswith(fam) or v <= 0:

@@ -4,7 +4,7 @@ chip smoke's verdict — the CPU-testable half of running on the chip
 
 Selection is one in-process discovery: `jax.devices()` under JAX_PLATFORMS.
 No child is ever spawned to look for a chip, nothing degrades in silence,
-and a combination the TPU compiler rejects is refused at construction.
+and an option this version removed is refused by name at selection.
 """
 
 import logging
@@ -20,7 +20,6 @@ from tendermint_tpu.crypto import ed25519 as ed
 from tendermint_tpu.crypto.batch import (
     GuardedBatchVerifier,
     HostBatchVerifier,
-    TPUBatchVerifier,
 )
 from tendermint_tpu.libs import breaker as brk
 from tendermint_tpu.libs.metrics import get_verify_metrics
@@ -108,35 +107,78 @@ class TestSelection:
         assert "libtpu: device busy" in str(errors[0].exc_info[1])
 
 
-class TestMxuRefusedOnPallas:
-    @pytest.mark.parametrize("fe", ["mxu", "mxu16"])
-    def test_refused_at_construction_with_the_reason(self, fe, monkeypatch):
-        from tendermint_tpu.ops import dispatch
+def _node(tmp_path, **verify):
+    """A single-validator Node, built and not started; ``verify`` is set on
+    its [verify] section as an operator's file would."""
+    from tendermint_tpu.config.config import default_config, test_config
+    from tendermint_tpu.node.node import Node
+    from tendermint_tpu.privval.file_pv import FilePV
+    from tendermint_tpu.types import GenesisDoc, GenesisValidator
 
-        monkeypatch.setattr(dispatch, "accelerator", lambda: _FakeTPU())
-        with pytest.raises(ValueError) as e:
-            TPUBatchVerifier(backend="pallas", fe_backend=fe)
+    home = str(tmp_path / "n")
+    cfg = default_config().set_root(home)
+    cfg.base.proxy_app = "kvstore"
+    cfg.base.db_backend = "memdb"
+    cfg.rpc.laddr = "tcp://127.0.0.1:0"
+    cfg.p2p.laddr = ""
+    cfg.consensus = test_config().consensus
+    cfg.consensus.wal_path = ""
+    for key, value in verify.items():
+        setattr(cfg.verify, key, value)
+    os.makedirs(os.path.join(home, "config"), exist_ok=True)
+    pv = FilePV.generate(os.path.join(home, "config", "pv.json"))
+    doc = GenesisDoc(
+        chain_id="removed-option-chain",
+        genesis_time_ns=1_700_000_000_000_000_000,
+        validators=[GenesisValidator(pv.get_pub_key(), 10)],
+    )
+    doc.validate_and_complete()
+    return Node(cfg, priv_validator=pv, genesis_doc=doc)
+
+
+class TestRemovedFeBackendOption:
+    """``fe_backend`` no longer exists; it can still arrive from a [verify]
+    section or a launch line written for an earlier build."""
+
+    @pytest.mark.parametrize("stale", ["vpu", " VPU ", "auto", ""])
+    def test_a_stale_value_that_asks_for_the_vpu_is_ignored(
+        self, stale, tmp_path, monkeypatch
+    ):
+        monkeypatch.setenv("TM_FE_BACKEND", stale)
+        node = _node(tmp_path, fe_backend=stale)
+        assert node.verifier_description.startswith("backend=")
+
+    def test_mxu_in_the_config_stops_the_node_with_the_reason(
+        self, tmp_path, monkeypatch
+    ):
+        monkeypatch.delenv("TM_FE_BACKEND", raising=False)
+        with pytest.raises(batch_mod.VerifyConfigError) as e:
+            _node(tmp_path, fe_backend="mxu")
         msg = str(e.value)
-        assert "does not lower for TPU" in msg
-        assert "lhs_contracting_dims" in msg and "_plane_outer" in msg
+        assert "[verify] fe_backend='mxu'" in msg
+        assert "removed in this version" in msg
+        assert "VPU limb multiplier is the only one" in msg
 
-    def test_default_selection_refuses_instead_of_falling_to_host(
+    def test_env_mxu16_is_refused_at_selection_not_served_by_the_host(
         self, fresh_default, monkeypatch
     ):
-        """TM_FE_BACKEND=mxu on a machine with a chip must stop the program,
-        not start a node that raises inside the guard on every dispatch."""
+        """On a machine with a chip the program stops; it neither builds a
+        device verifier nor latches the host one."""
         from tendermint_tpu.ops import dispatch
 
+        def no_verifier(*a, **k):
+            raise AssertionError("a verifier was built for a refused option")
+
         monkeypatch.setattr(dispatch, "accelerator", lambda: _FakeTPU())
-        monkeypatch.setenv("TM_FE_BACKEND", "mxu")
-        with pytest.raises(ValueError, match="does not lower for TPU"):
+        monkeypatch.setattr(batch_mod, "TPUBatchVerifier", no_verifier)
+        monkeypatch.setattr(batch_mod, "HostBatchVerifier", no_verifier)
+        monkeypatch.setenv("TM_FE_BACKEND", "mxu16")
+        before = dict(get_verify_metrics().host_fallback.snapshot())
+        with pytest.raises(batch_mod.VerifyConfigError,
+                           match="TM_FE_BACKEND='mxu16'.*removed"):
             batch_mod.get_batch_verifier()
         assert batch_mod.verifier_info()["installed"] is False
-
-    def test_vpu_on_pallas_and_mxu_on_xla_are_accepted(self):
-        batch_mod.check_fe_backend_lowers("pallas", "vpu")
-        batch_mod.check_fe_backend_lowers("xla", "mxu")
-        assert TPUBatchVerifier(backend="xla", fe_backend="mxu").backend == "xla"
+        assert dict(get_verify_metrics().host_fallback.snapshot()) == before
 
 
 class _CompilingDevice:

@@ -1,23 +1,19 @@
 """Property tests for the shared field arithmetic in ops/fe_common.py.
 
-Every fe op (mul / sq / add / sub / carry / inv) on every backend
-(vpu / mxu / mxu16) for both curves is checked against a Python-bignum
-reference, over random limb vectors plus the adversarial patterns the
+Every fe op (mul / sq / add / sub / carry / inv) for both curves is
+checked against a Python-bignum reference, over random limb vectors plus the adversarial patterns the
 ISSUE calls out: all-ones 13-bit limbs, p-1, p, p+1, and inputs held at
 the closed-set carried maxima (the largest limbs any op chain can
 produce).  Runs entirely eagerly under JAX_PLATFORMS=cpu.
 
 Two tiers: the default run keeps a fast core (edge-case lanes plus one
-random lane per pattern, inv on the vpu reference backend) under ~30s;
-the exhaustive sweeps — full random lane counts, inv on every backend
-including the eager mxu16 repack — carry `@pytest.mark.slow` and run
-with `-m slow`.
+random lane per pattern) under ~30s; the exhaustive sweeps — full random
+lane counts — carry `@pytest.mark.slow` and run with `-m slow`.
 
 The bounds section replaces the hand-stated overflow analysis that used
 to live in the ed25519_pallas header comment: fe_common.bound_*
-re-derives, mechanically, that the op mix is closed (carried limbs stay
-under each backend's plane limit) and that no intermediate reaches
-2^32.  If a future edit to the carry/fold chains breaks either claim,
+re-derives, mechanically, that the op mix is closed and that no
+intermediate reaches 2^32.  If a future edit to the carry/fold chains breaks either claim,
 these tests fail instead of a comment going stale.
 """
 
@@ -77,7 +73,7 @@ def _inputs(curve, rng, n_random=SLOW_RANDOM):
     cols = [to_limbs(v) for v in vals]
     cols.append(np.full(NLIMB, MASK, dtype=np.uint32))
     ksub = CURVES[curve]["ksub"]
-    bounds, _ = fc.bound_closed_set(curve, "vpu", ksub=list(ksub))
+    bounds, _ = fc.bound_closed_set(curve, ksub=list(ksub))
     cols.append(np.asarray(bounds, dtype=np.uint32))
     # random carried-form inputs up to the closed-set bound per row
     for _ in range(n_random):
@@ -88,11 +84,10 @@ def _inputs(curve, rng, n_random=SLOW_RANDOM):
 
 
 @pytest.mark.parametrize("curve", list(CURVES))
-@pytest.mark.parametrize("backend", fc.FE_BACKENDS)
 class TestFeOpsVsBignum:
-    def test_mul_sq(self, curve, backend):
+    def test_mul_sq(self, curve):
         p = CURVES[curve]["p"]
-        fe = fc.make_fe(curve, backend)
+        fe = fc.make_fe(curve)
         rng = np.random.default_rng(7)
         cols = _inputs(curve, rng, n_random=FAST_RANDOM)
         a = _lanes(cols)
@@ -101,17 +96,12 @@ class TestFeOpsVsBignum:
         sq = np.asarray(fe.sq(a))
         for k in range(a.shape[1]):
             va, vb = from_limbs(cols[k]), from_limbs(cols[::-1][k])
-            assert from_limbs(got[:, k]) % p == (va * vb) % p, (
-                curve, backend, "mul", k)
-            assert from_limbs(sq[:, k]) % p == (va * va) % p, (
-                curve, backend, "sq", k)
+            assert from_limbs(got[:, k]) % p == (va * vb) % p, (curve, "mul", k)
+            assert from_limbs(sq[:, k]) % p == (va * va) % p, (curve, "sq", k)
 
-    def test_add_sub_carry(self, curve, backend):
-        # add/sub/carry are backend-independent VPU chains, but run them
-        # under every backend namespace anyway: make_fe must wire the
-        # same functions regardless of the mul backend chosen
+    def test_add_sub_carry(self, curve):
         p = CURVES[curve]["p"]
-        fe = fc.make_fe(curve, backend)
+        fe = fc.make_fe(curve)
         rng = np.random.default_rng(11)
         cols = _inputs(curve, rng, n_random=FAST_RANDOM)
         a = _lanes(cols)
@@ -122,33 +112,24 @@ class TestFeOpsVsBignum:
         got_carry = np.asarray(fe.carry(a))
         for k in range(a.shape[1]):
             va, vb = from_limbs(cols[k]), from_limbs(cols[::-1][k])
-            assert from_limbs(got_add[:, k]) % p == (va + vb) % p, (
-                curve, backend, "add", k)
-            assert from_limbs(got_sub[:, k]) % p == (va - vb) % p, (
-                curve, backend, "sub", k)
-            assert from_limbs(got_carry[:, k]) % p == va % p, (
-                curve, backend, "carry", k)
+            assert from_limbs(got_add[:, k]) % p == (va + vb) % p, (curve, "add", k)
+            assert from_limbs(got_sub[:, k]) % p == (va - vb) % p, (curve, "sub", k)
+            assert from_limbs(got_carry[:, k]) % p == va % p, (curve, "carry", k)
 
-    def test_inv(self, curve, backend):
-        if backend != "vpu":
-            # ~250 eager muls per backend is the bulk of this file's
-            # runtime; mul/sq/add/sub/carry cover mxu/mxu16 in the fast
-            # tier, the exhaustive class sweeps inv on every backend
-            pytest.skip("non-vpu inv runs in the slow sweep (-m slow)")
+    def test_inv(self, curve):
         p = CURVES[curve]["p"]
-        fe = fc.make_fe(curve, backend)
+        fe = fc.make_fe(curve)
         vals = [1, 2, p - 1]
         cols = [to_limbs(v) for v in vals]
         got = np.asarray(fe.inv(_lanes(cols)))
         for k, v in enumerate(vals):
-            assert from_limbs(got[:, k]) % p == pow(v, p - 2, p), (
-                curve, backend, "inv", k)
+            assert from_limbs(got[:, k]) % p == pow(v, p - 2, p), (curve, "inv", k)
 
-    def test_mul_small(self, curve, backend):
+    def test_mul_small(self, curve):
         if curve != "secp256k1":
             pytest.skip("mul_small is a secp-only op (B3 = 21)")
         p = CURVES[curve]["p"]
-        fe = fc.make_fe(curve, backend)
+        fe = fc.make_fe(curve)
         rng = np.random.default_rng(17)
         cols = _inputs(curve, rng)
         got = np.asarray(fe.mul_small(_lanes(cols), 21))
@@ -158,15 +139,13 @@ class TestFeOpsVsBignum:
 
 @pytest.mark.slow
 @pytest.mark.parametrize("curve", list(CURVES))
-@pytest.mark.parametrize("backend", fc.FE_BACKENDS)
 class TestFeOpsVsBignumExhaustive:
     """The full-width sweeps the fast tier trims: every adversarial
-    pattern with the full random lane count, and inv on every backend
-    (including the eager mxu16 repack — minutes on CPU)."""
+    pattern with the full random lane count, and inv on one more value."""
 
-    def test_mul_sq_exhaustive(self, curve, backend):
+    def test_mul_sq_exhaustive(self, curve):
         p = CURVES[curve]["p"]
-        fe = fc.make_fe(curve, backend)
+        fe = fc.make_fe(curve)
         rng = np.random.default_rng(7)
         cols = _inputs(curve, rng, n_random=SLOW_RANDOM)
         a = _lanes(cols)
@@ -175,14 +154,12 @@ class TestFeOpsVsBignumExhaustive:
         sq = np.asarray(fe.sq(a))
         for k in range(a.shape[1]):
             va, vb = from_limbs(cols[k]), from_limbs(cols[::-1][k])
-            assert from_limbs(got[:, k]) % p == (va * vb) % p, (
-                curve, backend, "mul", k)
-            assert from_limbs(sq[:, k]) % p == (va * va) % p, (
-                curve, backend, "sq", k)
+            assert from_limbs(got[:, k]) % p == (va * vb) % p, (curve, "mul", k)
+            assert from_limbs(sq[:, k]) % p == (va * va) % p, (curve, "sq", k)
 
-    def test_add_sub_carry_exhaustive(self, curve, backend):
+    def test_add_sub_carry_exhaustive(self, curve):
         p = CURVES[curve]["p"]
-        fe = fc.make_fe(curve, backend)
+        fe = fc.make_fe(curve)
         rng = np.random.default_rng(11)
         cols = _inputs(curve, rng, n_random=SLOW_RANDOM)
         a = _lanes(cols)
@@ -193,44 +170,41 @@ class TestFeOpsVsBignumExhaustive:
         got_carry = np.asarray(fe.carry(a))
         for k in range(a.shape[1]):
             va, vb = from_limbs(cols[k]), from_limbs(cols[::-1][k])
-            assert from_limbs(got_add[:, k]) % p == (va + vb) % p, (
-                curve, backend, "add", k)
-            assert from_limbs(got_sub[:, k]) % p == (va - vb) % p, (
-                curve, backend, "sub", k)
-            assert from_limbs(got_carry[:, k]) % p == va % p, (
-                curve, backend, "carry", k)
+            assert from_limbs(got_add[:, k]) % p == (va + vb) % p, (curve, "add", k)
+            assert from_limbs(got_sub[:, k]) % p == (va - vb) % p, (curve, "sub", k)
+            assert from_limbs(got_carry[:, k]) % p == va % p, (curve, "carry", k)
 
-    def test_inv_all_backends(self, curve, backend):
+    def test_inv_exhaustive(self, curve):
         p = CURVES[curve]["p"]
-        fe = fc.make_fe(curve, backend)
+        fe = fc.make_fe(curve)
         rng = np.random.default_rng(13)
         vals = [1, 2, p - 1, int(rng.integers(2, 1 << 61)) ** 4 % p]
         cols = [to_limbs(v) for v in vals]
         got = np.asarray(fe.inv(_lanes(cols)))
         for k, v in enumerate(vals):
-            assert from_limbs(got[:, k]) % p == pow(v, p - 2, p), (
-                curve, backend, "inv", k)
+            assert from_limbs(got[:, k]) % p == pow(v, p - 2, p), (curve, "inv", k)
 
 
 class TestBatchLayout:
     """The XLA kernels use the batch-leading (..., NLIMB) layout through
-    mul_columns_batch; its columns must be the exact schoolbook integers
+    their _mul_cols; its columns must be the exact schoolbook integers
     (the carry tails downstream assume identical column values)."""
 
-    @pytest.mark.parametrize("curve,split", [("ed25519", 7), ("secp256k1", 8)])
-    def test_columns_match_schoolbook(self, curve, split):
+    @pytest.mark.parametrize("curve", list(CURVES))
+    def test_columns_match_schoolbook(self, curve):
         rng = np.random.default_rng(19)
         ksub = CURVES[curve]["ksub"]
-        bounds, _ = fc.bound_closed_set(curve, "vpu", ksub=list(ksub))
+        bounds, _ = fc.bound_closed_set(curve, ksub=list(ksub))
         hi = np.asarray(bounds, dtype=np.uint64)
         for shape in ((4, NLIMB), (2, 3, NLIMB)):
             a = rng.integers(0, hi + 1, shape).astype(np.uint32)
             b = rng.integers(0, hi + 1, shape).astype(np.uint32)
             out = 2 * NLIMB + 1
-            got = np.asarray(
-                fc.mul_columns_batch(jnp.asarray(a), jnp.asarray(b), out,
-                                     split=split)
-            ).astype(np.uint64)
+            if curve == "ed25519":
+                cols = ed_xla._mul_cols(jnp.asarray(a), jnp.asarray(b), out)
+            else:
+                cols = sp_xla._mul_cols(jnp.asarray(a), jnp.asarray(b))
+            got = np.asarray(cols).astype(np.uint64)
             want = np.zeros(shape[:-1] + (out,), dtype=np.uint64)
             for i in range(NLIMB):
                 want[..., i:i + NLIMB] += (
@@ -241,44 +215,19 @@ class TestBatchLayout:
             np.testing.assert_array_equal(got & 0xFFFFFFFF,
                                           want & 0xFFFFFFFF)
 
-    @pytest.mark.parametrize(
-        "backend",
-        ["vpu", "mxu",
-         pytest.param("mxu16", marks=pytest.mark.slow)])
-    def test_constant_operand_broadcasts(self, backend, curve="ed25519"):
-        # pt_add multiplies by (NLIMB, 1) constants (d2, ksub); the MXU
-        # path must broadcast them against (NLIMB, B) like the VPU does.
-        # The eager mxu16 repack is the slow one — slow tier only.
+    def test_constant_operand_broadcasts(self, curve="ed25519"):
+        # pt_add multiplies by (NLIMB, 1) constants (d2, ksub), which
+        # broadcast against (NLIMB, B) operands
         p = CURVES[curve]["p"]
         rng = np.random.default_rng(23)
         a = rng.integers(0, MASK + 1, (NLIMB, 5)).astype(np.uint32)
         c = rng.integers(0, MASK + 1, (NLIMB, 1)).astype(np.uint32)
-        fe = fc.make_fe(curve, backend)
+        fe = fc.make_fe(curve)
         got = np.asarray(fe.mul(jnp.asarray(a), jnp.asarray(c)))
         vc = from_limbs(c[:, 0])
         for k in range(a.shape[1]):
             assert from_limbs(got[:, k]) % p == (
-                from_limbs(a[:, k]) * vc) % p, (backend, k)
-
-
-class TestXlaKernelFeMul:
-    """The trace-time _FE_BACKEND switch in the XLA kernel modules: the
-    mxu branch of fe_mul must be bit-identical (not just congruent) to
-    the vpu branch, since the audit path compares encodings."""
-
-    @pytest.mark.parametrize("mod,curve", [(ed_xla, "ed25519"),
-                                           (sp_xla, "secp256k1")])
-    def test_bit_identical(self, mod, curve):
-        rng = np.random.default_rng(29)
-        ksub = CURVES[curve]["ksub"]
-        bounds, _ = fc.bound_closed_set(curve, "vpu", ksub=list(ksub))
-        hi = np.asarray(bounds, dtype=np.uint64)
-        a = jnp.asarray(rng.integers(0, hi + 1, (6, NLIMB)).astype(np.uint32))
-        b = jnp.asarray(rng.integers(0, hi + 1, (6, NLIMB)).astype(np.uint32))
-        base = np.asarray(mod.fe_mul(a, b))
-        wrapped = fc.trace_with_backend(mod, mod.fe_mul, "mxu")
-        np.testing.assert_array_equal(np.asarray(wrapped(a, b)), base)
-        assert mod._FE_BACKEND == "vpu"  # wrapper must restore
+                from_limbs(a[:, k]) * vc) % p, k
 
 
 class TestBounds:
@@ -286,32 +235,16 @@ class TestBounds:
     hand-written block that used to sit atop ops/ed25519_pallas.py)."""
 
     @pytest.mark.parametrize("curve", list(CURVES))
-    @pytest.mark.parametrize("backend", fc.FE_BACKENDS)
-    def test_closed_set_converges_below_2_32(self, curve, backend):
+    def test_closed_set_converges_below_2_32(self, curve):
         ksub = list(CURVES[curve]["ksub"])
-        bounds, peak = fc.bound_closed_set(curve, backend, ksub=ksub)
-        assert peak < 1 << 32, (curve, backend, peak)
+        bounds, peak = fc.bound_closed_set(curve, ksub=ksub)
+        assert peak < 1 << 32, (curve, peak)
         # closure: one more round of every op stays within the fixed point
-        bm, _ = fc.bound_fe_mul(curve, bounds, bounds, backend)
+        bm, _ = fc.bound_fe_mul(curve, bounds, bounds)
         ba, _ = fc.bound_fe_add(curve, bounds, bounds)
         bs, _ = fc.bound_fe_sub(curve, bounds, bounds, ksub)
         for nxt in (bm, ba, bs):
-            assert all(x <= y for x, y in zip(nxt, bounds)), (curve, backend)
-
-    def test_plane_limits_hold_on_closed_set(self):
-        # the int8 (ed, split=7) and uint8 (secp, split=8) plane splits
-        # require carried limbs <= 16383 / 65535; the closed set must
-        # stay under those or the MXU planes silently truncate
-        for curve, limit in (("ed25519", 16383), ("secp256k1", 65535)):
-            ksub = list(CURVES[curve]["ksub"])
-            bounds, _ = fc.bound_closed_set(curve, "vpu", ksub=ksub)
-            assert max(bounds) <= limit, (curve, max(bounds))
-
-    def test_plane_limit_violation_raises(self):
-        # ed25519 limbs past the int8 plane bound must be rejected, not
-        # silently mis-multiplied
-        with pytest.raises(AssertionError):
-            fc.bound_fe_mul("ed25519", [16384] * NLIMB, [1] * NLIMB, "mxu")
+            assert all(x <= y for x, y in zip(nxt, bounds)), curve
 
     def test_ed25519_41st_product_row_required(self):
         # regression pin for the top-carry drop: no direct product reaches
@@ -326,12 +259,3 @@ class TestBounds:
             c = [b >> BITS for b in bs]
             bs = [min(b, MASK) + s for b, s in zip(bs, [0] + c[:-1])]
         assert bs[2 * NLIMB] > 0
-
-    def test_normalize_backend(self):
-        assert fc.normalize_backend(None) == "vpu"
-        assert fc.normalize_backend("") == "vpu"
-        assert fc.normalize_backend("auto") == "vpu"
-        assert fc.normalize_backend("MXU") == "mxu"
-        assert fc.normalize_backend(" mxu16 ") == "mxu16"
-        with pytest.raises(ValueError):
-            fc.normalize_backend("gpu")

@@ -6,8 +6,8 @@ Three layers, mirroring how the feature is built:
     point, the KD/KSUB wide zeros, and the derived-vs-pinned eager round
     counts (the import-time asserts, re-run here so a failure points at
     the claim, not at an ImportError);
-  * op exactness — every lazy op on both curves and both lazy-capable
-    backends against Python bignum, driven at the certified class bounds
+  * op exactness — every lazy op on both curves against Python bignum,
+    driven at the certified class bounds
     (p±1, all-MASK, the class-C/D maxima rows) where overflow would hide;
   * kernel parity — the XLA verify kernels must return bit-identical
     verdicts under eager and lazy schedules, and the Pallas ladder's lazy
@@ -32,7 +32,6 @@ NLIMB, BITS, MASK = fc.NLIMB, fc.BITS, fc.MASK
 U32 = 1 << 32
 
 CURVE_P = {"ed25519": fc.ED_P, "secp256k1": fc.SECP_P}
-LAZY_BACKENDS = ("vpu", "mxu")
 
 
 def to_limbs(x: int) -> np.ndarray:
@@ -53,10 +52,9 @@ def _limb_col(limbs):
 
 
 @pytest.mark.parametrize("curve", list(CURVE_P))
-@pytest.mark.parametrize("backend", LAZY_BACKENDS)
 class TestCarryPlan:
-    def test_plan_certified(self, curve, backend):
-        plan = fc.derive_carry_plan(curve, backend)
+    def test_plan_certified(self, curve):
+        plan = fc.derive_carry_plan(curve)
         p = CURVE_P[curve]
         assert plan.peak < U32
         # operand classes are a fixed point ordered C <= D, and both wide
@@ -69,9 +67,9 @@ class TestCarryPlan:
         assert plan.mull_wide == 1 and plan.norm_wide == 1
         assert 1 <= plan.mulf_wide <= 4
 
-    def test_closure_one_more_step(self, curve, backend):
+    def test_closure_one_more_step(self, curve):
         # one more application of every chain op stays inside the classes
-        plan = fc.derive_carry_plan(curve, backend)
+        plan = fc.derive_carry_plan(curve)
         C, D, KD = plan.c, plan.d, list(plan.kd)
         if curve == "ed25519":
             bm, _ = fc.bound_ed_mul_lazy(C, C, wide=plan.mulf_wide)
@@ -87,14 +85,6 @@ class TestCarryPlan:
         assert all(x <= y for x, y in zip(bn, C))
         assert all(x <= y for x, y in zip(bs, C))
         assert all(x <= y for x, y in zip(bd, D))
-
-    def test_mxu_plane_limit(self, curve, backend):
-        if backend != "mxu":
-            pytest.skip("plane limits are an MXU constraint")
-        # lazy mxu uses uint8 planes (split=8): operands must stay < 2^16
-        plan = fc.derive_carry_plan(curve, backend)
-        assert plan.split == 8
-        assert 2 * max(plan.c) <= 65535
 
 
 class TestDerivedConstants:
@@ -117,11 +107,7 @@ class TestDerivedConstants:
             np.asarray(fc.SECP_KSUB_LIMBS, np.uint32),
             np.asarray(sp_xla._K_SUB))
 
-    def test_mxu16_has_no_plan(self):
-        with pytest.raises(ValueError):
-            fc.derive_carry_plan("ed25519", "mxu16")
-        assert fc.effective_carry_mode("mxu16", "lazy") == "eager"
-        assert fc.effective_carry_mode("mxu", "lazy") == "lazy"
+    def test_normalize_carry_mode(self):
         assert fc.normalize_carry_mode(None) == "lazy"
         assert fc.normalize_carry_mode("auto") == "lazy"
         assert fc.normalize_carry_mode(" EAGER ") == "eager"
@@ -130,7 +116,6 @@ class TestDerivedConstants:
 
 
 @pytest.mark.parametrize("curve", list(CURVE_P))
-@pytest.mark.parametrize("backend", LAZY_BACKENDS)
 class TestLazyOpsVsBignum:
     """Row-layout lazy ops vs Python bignum at the certified bounds."""
 
@@ -143,10 +128,10 @@ class TestLazyOpsVsBignum:
         cols.append(np.asarray(plan.c, np.uint32))  # class-C maxima
         return cols
 
-    def test_mul_f_and_l(self, curve, backend):
+    def test_mul_f_and_l(self, curve):
         p = CURVE_P[curve]
-        plan = fc.derive_carry_plan(curve, backend)
-        fe = fc.make_fe(curve, backend, carry_mode="lazy")
+        plan = fc.derive_carry_plan(curve)
+        fe = fc.make_fe(curve, carry_mode="lazy")
         assert fe.carry_mode == "lazy"
         rng = np.random.default_rng(31)
         cols = self._operands(curve, plan, rng)
@@ -163,10 +148,10 @@ class TestLazyOpsVsBignum:
             assert all(int(v) <= c for v, c in zip(mf[:, k], plan.c))
             assert all(int(v) <= d for v, d in zip(ml[:, k], plan.d))
 
-    def test_add_sub_norm_chain(self, curve, backend):
+    def test_add_sub_norm_chain(self, curve):
         p = CURVE_P[curve]
-        plan = fc.derive_carry_plan(curve, backend)
-        fe = fc.make_fe(curve, backend, carry_mode="lazy")
+        plan = fc.derive_carry_plan(curve)
+        fe = fc.make_fe(curve, carry_mode="lazy")
         rng = np.random.default_rng(37)
         cols = self._operands(curve, plan, rng)
         a, b = _lanes(cols), _lanes(cols[::-1])
@@ -187,10 +172,10 @@ class TestLazyOpsVsBignum:
             assert from_limbs(got_raw[:, k]) % p == (2 * dv[k] + va) % p
             assert all(int(v) <= c for v, c in zip(got_add[:, k], plan.c))
 
-    def test_mul_small_and_inv(self, curve, backend):
+    def test_mul_small_and_inv(self, curve):
         p = CURVE_P[curve]
-        plan = fc.derive_carry_plan(curve, backend)
-        fe = fc.make_fe(curve, backend, carry_mode="lazy")
+        plan = fc.derive_carry_plan(curve)
+        fe = fc.make_fe(curve, carry_mode="lazy")
         rng = np.random.default_rng(41)
         vals = [1, 2, p - 1, int(rng.integers(2, 1 << 61)) ** 4 % p]
         cols = [to_limbs(v) for v in vals]

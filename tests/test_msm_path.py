@@ -7,7 +7,7 @@ rpc/core/env.py: forged signatures, mutant R, the Go malleability zone
 non-canonical R hidden inside otherwise-clean windows must localize to
 the exact rows with verdicts bit-identical to the serial verifier — on
 the RLC fast path AND through the chunk-RLC/ladder fallback, under the
-PR-9 device guard, on vpu and mxu, eager and lazy, interpret-Pallas and
+PR-9 device guard, eager and lazy, interpret-Pallas and
 XLA-CPU (the interpret and eager combos ride the slow lane).
 """
 
@@ -122,25 +122,21 @@ class TestXlaMsm:
 
         pubs, msgs, sigs = _corpus(16, tag=3)
         p, s = _np_batch(pubs, sigs)
-        ok = xk.rlc_verify_batch(p, msgs, s, fe_backend="vpu",
-                                 carry_mode="lazy", seed=SEED)
+        ok = xk.rlc_verify_batch(p, msgs, s, carry_mode="lazy", seed=SEED)
         assert ok.all()
 
-    @pytest.mark.parametrize("fe_backend", ["vpu", "mxu"])
-    def test_adversarial_localization(self, fe_backend):
+    def test_adversarial_localization(self):
         from tendermint_tpu.ops import ed25519_verify as xk
 
         pubs, msgs, sigs, expected = _adversarial_window(tag=4)
         p, s = _np_batch(pubs, sigs)
-        got = xk.rlc_verify_batch(p, msgs, s, fe_backend=fe_backend,
-                                  carry_mode="lazy", seed=SEED)
+        got = xk.rlc_verify_batch(p, msgs, s, carry_mode="lazy", seed=SEED)
         assert np.array_equal(got, expected), (
-            f"msm/{fe_backend} verdicts diverge from serial: "
+            f"msm verdicts diverge from serial: "
             f"{np.nonzero(got != expected)[0].tolist()}"
         )
-        # and bit-identical to the per-row ladder at the same combo
-        ladder = xk.verify_batch(p, msgs, s, fe_backend=fe_backend,
-                                 carry_mode="lazy")
+        # and bit-identical to the per-row ladder under the same schedule
+        ladder = xk.verify_batch(p, msgs, s, carry_mode="lazy")
         assert np.array_equal(got, ladder)
 
     @pytest.mark.slow
@@ -156,14 +152,12 @@ class TestXlaMsm:
         assert np.array_equal(a, b) and np.array_equal(a, c)
 
     @pytest.mark.slow
-    @pytest.mark.parametrize("fe_backend", ["vpu", "mxu"])
-    def test_adversarial_localization_eager(self, fe_backend):
+    def test_adversarial_localization_eager(self):
         from tendermint_tpu.ops import ed25519_verify as xk
 
         pubs, msgs, sigs, expected = _adversarial_window(tag=6)
         p, s = _np_batch(pubs, sigs)
-        got = xk.rlc_verify_batch(p, msgs, s, fe_backend=fe_backend,
-                                  carry_mode="eager", seed=SEED)
+        got = xk.rlc_verify_batch(p, msgs, s, carry_mode="eager", seed=SEED)
         assert np.array_equal(got, expected)
 
 
@@ -202,7 +196,7 @@ class TestPallasInterpretMsm:
 
 class TestPathKnob:
     """[verify] ed25519_path resolution: explicit > TM_ED25519_PATH >
-    config default > ladder — the fe_backend chain, mirrored."""
+    config default > ladder."""
 
     def test_resolution_precedence(self, monkeypatch):
         r = batch_mod._resolve_ed25519_path
@@ -223,7 +217,7 @@ class TestPathKnob:
         monkeypatch.delenv("TM_ED25519_PATH", raising=False)
         with pytest.raises(ValueError):
             batch_mod._resolve_ed25519_path("pippenger")
-        # the setter stores unvalidated (mirrors set_default_fe_backend);
+        # the setter stores unvalidated;
         # resolution is where a typo'd config value surfaces
         batch_mod.set_default_ed25519_path("msmm")
         try:
@@ -363,10 +357,8 @@ class TestObservability:
 
         vm = VerifyMetrics(Registry())
         vm.record_dispatch("planner_msm", "ed25519", 16, 0.01,
-                           fe_backend="vpu", carry_mode="lazy",
-                           ed25519_path="msm")
-        vm.record_dispatch("xla", "ed25519", 16, 0.01,
-                           fe_backend="vpu", carry_mode="lazy")
+                           carry_mode="lazy", ed25519_path="msm")
+        vm.record_dispatch("xla", "ed25519", 16, 0.01, carry_mode="lazy")
         text = vm.registry.expose_text()
         assert 'ed25519_path="msm"' in text
         # unlabeled dispatches default to the ladder path
@@ -377,10 +369,10 @@ class TestObservability:
 
         prof = Profiler()
         with prof.window(100, 2):
-            prof.record("planner_msm", fe_backend="vpu", carry_mode="lazy",
+            prof.record("planner_msm", carry_mode="lazy",
                         ed25519_path="msm", lanes_present=16,
                         lanes_dispatched=16, run_seconds=0.01)
-            prof.record("planner_msm", fe_backend="vpu", carry_mode="lazy",
+            prof.record("planner_msm", carry_mode="lazy",
                         ed25519_path="msm", lanes_present=16,
                         lanes_dispatched=16, run_seconds=0.01)
         rows = prof.ledger()
@@ -389,8 +381,8 @@ class TestObservability:
     def test_monitor_verify_path_column(self):
         from tendermint_tpu.tools.tm_monitor import _fmt_verify, _verify_path
 
-        key = ('tendermint_verify_fe_backend_total{backend="planner_msm",'
-               'carry_mode="lazy",ed25519_path="msm",fe_backend="vpu"}')
+        key = ('tendermint_verify_path_total{backend="planner_msm",'
+               'carry_mode="lazy",ed25519_path="msm"}')
         assert _verify_path({key: 3.0}) == "msm"
         assert _verify_path({}) == "-"
         other = key.replace('"msm"', '"ladder"')

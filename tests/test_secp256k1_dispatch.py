@@ -44,8 +44,7 @@ def pallas_on_cpu(monkeypatch):
     monkeypatch.setattr(sp, "_ladder_call", ladder)
     monkeypatch.setattr(
         sp, "verify_batch",
-        lambda p, d, g, fe_backend="vpu": real(p, d, g, interpret=True,
-                                               fe_backend=fe_backend))
+        lambda p, d, g: real(p, d, g, interpret=True))
     v = TPUBatchVerifier(backend="xla")
     v.backend = "pallas"
     xla._decompress_cache.clear()
@@ -346,7 +345,7 @@ def test_pallas_pipeline_and_xla_path_decide_the_same_batch_alike(
             d = lanes(dig).astype(np.uint64)[:, ::-1].reshape(32, 8, 8)
             return (d << (4 * np.arange(8, dtype=np.uint64))).sum(axis=2).astype(np.uint32)
 
-        kernel = xla._compiled_kernel(32, None, "vpu", "lazy")
+        kernel = xla._compiled_kernel(32, None, "lazy")
         ok = kernel(lanes(qx), lanes(qy), words(dig1), words(dig2), lanes(rl),
                     lanes(rnl), lanes(rnok)[:, 0].astype(bool))
         return jnp.asarray(np.asarray(ok)[:b].astype(np.uint32))[None, :]
