@@ -312,6 +312,8 @@ def _self_check():
     vm.device_retries.add(1.0)
     vm.device_audit.add(8.0, ("ok",))
     vm.device_audit.add(1.0, ("mismatch",))
+    # how a Pallas ed25519 call packed its lanes (ops/ed25519_pallas)
+    vm.ed25519_pack.add(1.0, ("uniform",))
     # the secp256k1 prologue's pair (ops/secp256k1_verify.record_prologue)
     vm.secp256k1_host_decided.add(2.0, ("malformed",))
     vm.secp256k1_inversions.add(1.0)
@@ -471,6 +473,7 @@ def _self_check():
         "tendermint_verify_device_audit_seconds",
         "tendermint_verify_valset_cache_total",
         "tendermint_verify_sync_ticks_total",
+        "tendermint_verify_ed25519_pack_total",
         # the secp256k1 host prologue: lanes it decided itself, and the
         # modular inversions it performed (one a dispatch)
         "tendermint_verify_secp256k1_host_decided_total",

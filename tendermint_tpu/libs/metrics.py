@@ -414,6 +414,19 @@ class VerifyMetrics:
             "and result (hit|miss)",
             label_names=("cache", "result"),
         )
+        # how ops/ed25519_pallas.verify_batch handed a call's columns down:
+        # as they came (every message one length: a commit, a sync window)
+        # or regrouped by length, one launch a length
+        self.ed25519_pack = r.counter(
+            "verify_ed25519_pack_total",
+            "Calls of the Pallas ed25519 verify by how their lanes were "
+            "packed: uniform (one message length: the caller's own columns, "
+            "one launch) | grouped (several lengths: columns copied and "
+            "launched once a length)",
+            label_names=("path",),
+        )
+        for path in ("uniform", "grouped"):  # both series from 0
+            self.ed25519_pack.add(0.0, (path,))
         # secp256k1 lanes the host prologue (secp256k1_verify.prep_batch)
         # decided: they never reach the device, so the guard's audit, which
         # samples the dispatch's answer, sees the host's verdict for them

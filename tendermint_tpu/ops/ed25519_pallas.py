@@ -756,10 +756,19 @@ _valset_cache: dict = {}
 _VALSET_CACHE_MAX = 64
 
 
-def _decompress_valset(pubs: np.ndarray) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+def _valset_key(pubs: np.ndarray) -> bytes:
+    """What both valset caches know a (N, 32) pubkey array by."""
+    return hashlib.sha256(np.ascontiguousarray(pubs)).digest()
+
+
+def _decompress_valset(
+    pubs: np.ndarray, key: Optional[bytes] = None,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """(N, 32) pubkeys -> (neg_ax, ay, valid) with whole-set caching: commit
-    verification hits the same validator-set array every height."""
-    key = hashlib.sha256(pubs.tobytes()).digest()
+    verification hits the same validator-set array every height.  ``key`` is
+    ``_valset_key(pubs)`` where the caller already holds it."""
+    if key is None:
+        key = _valset_key(pubs)
     hit = _valset_cache.get(key)
     get_verify_metrics().valset_cache.add(
         1.0, ("host", "miss" if hit is None else "hit"))
@@ -794,12 +803,12 @@ _dev_valset_cache: dict = {}
 _DEV_VALSET_CACHE_MAX = 32
 
 
-def _upload_valset(pubs, neg_ax, ay, b):
+def _upload_valset(pubs, neg_ax, ay, b, key: Optional[bytes] = None):
     """Device-resident (negax, ay, pub_words) padded to bucket b, cached per
     (valset, bucket). Commit verification reuses the same validator set
     every height, so after the first call the pubkey material is never
-    uploaded again."""
-    key = (hashlib.sha256(pubs.tobytes()).digest(), b)
+    uploaded again.  ``key`` as in ``_decompress_valset``."""
+    key = (_valset_key(pubs) if key is None else key, b)
     hit = _dev_valset_cache.get(key)
     get_verify_metrics().valset_cache.add(
         1.0, ("device", "miss" if hit is None else "hit"))
@@ -843,19 +852,31 @@ def verify_batch(pubs: np.ndarray, msgs: Sequence[bytes], sigs: np.ndarray,
     if n == 0:
         return np.zeros((0,), dtype=bool)
 
-    # valset limbs and the lanes grouped by message length (one group for a
-    # commit), before any launch: the host work that is not packing
-    with trace.span("dispatch.prepare", n=n):
-        neg_ax, ay, valid = _decompress_valset(pubs)
+    # valset limbs and the length scan, before any launch: the host work that
+    # is not packing.  One length (a commit, a sync window) goes down as the
+    # caller's own columns; several are regrouped, one launch a length
+    with trace.span("dispatch.prepare", n=n) as sp:
+        key = _valset_key(pubs)
+        neg_ax, ay, valid = _decompress_valset(pubs, key)
         valid = valid & ((sigs[:, 63] & 224) == 0)  # Go's only s range check
-        lens = np.array([len(m) for m in msgs]) if msgs else np.zeros((0,), int)
+        lengths = set(map(len, msgs))
+        uniform = len(lengths) == 1 and len(msgs) == n
         groups = []
-        for ln in np.unique(lens):
-            idx = np.nonzero(lens == ln)[0]
-            groups.append((idx, (
-                pubs[idx], [msgs[i] for i in idx], sigs[idx],
-                neg_ax[idx], ay[idx], valid[idx], int(ln),
-            )))
+        if not uniform:
+            lens = np.fromiter(map(len, msgs), dtype=np.int64, count=len(msgs))
+            for ln in np.unique(lens):
+                idx = np.nonzero(lens == ln)[0]
+                groups.append((idx, (
+                    pubs[idx], [msgs[i] for i in idx], sigs[idx],
+                    neg_ax[idx], ay[idx], valid[idx], int(ln),
+                )))
+        sp.set(groups=len(lengths))
+    get_verify_metrics().ed25519_pack.add(
+        1.0, ("uniform" if uniform else "grouped",))
+    if uniform:
+        (ln,) = lengths
+        return _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln,
+                               interpret, carry_mode, valset_key=key)
     out = np.zeros((n,), dtype=bool)
     for idx, cols in groups:
         out[idx] = _verify_uniform(*cols, interpret, carry_mode)
@@ -948,66 +969,78 @@ def rlc_verify_batch(pubs: np.ndarray, msgs: Sequence[bytes],
     return np.asarray(out, dtype=bool)
 
 
+def _varying_columns(m: np.ndarray) -> np.ndarray:
+    """Indices of the byte columns of (n, ln) ``m`` in which some row differs
+    from row 0.  numpy's ``any`` down the rows pays a loop start for every
+    row, which at 110 bytes a row is most of its time: 16 rows are folded
+    into one first."""
+    n, ln = m.shape
+    ne = m != m[0]
+    head = n - n % 16
+    folded = ne[:head].reshape(head // 16, 16 * ln).any(axis=0).reshape(16, ln)
+    return np.nonzero(folded.any(axis=0) | ne[head:].any(axis=0))[0]
+
+
 def pack_variable_words(pubs, msgs, sigs, ln: int, b: int):
     """Host-side packing for the transfer-minimizing dispatch: returns
     (tmpl, vrows, vwords) — the padded-SHA-input template of batch row 0,
     the word rows (>= 16) that vary across the batch, and each signature's
     values at those rows. Pure numpy (shared by _verify_uniform and the
-    bench's device-resident re-dispatch timing)."""
+    bench's device-resident re-dispatch timing).
+
+    Only the bytes of the varying rows are gathered, from the joined
+    messages; no lane's padded input is built but row 0's."""
     n = pubs.shape[0]
     total = 64 + ln
     nblocks = (total + 1 + 16 + 127) // 128
-    rows = nblocks * 32
     m = (
         np.frombuffer(b"".join(msgs), dtype=np.uint8).reshape(n, ln)
         if ln else np.zeros((n, 0), np.uint8)
     )
+    # the padded input past the message, the same in every lane: 0x80,
+    # zeros, the 16-byte bit length
+    tail = np.zeros((nblocks * 128 - total,), dtype=np.uint8)
+    tail[0] = 0x80
+    tail[-16:] = np.frombuffer((total * 8).to_bytes(16, "big"), np.uint8)
     # template = row 0's padded SHA input, as BE words
-    pad0 = np.zeros((nblocks * 128,), dtype=np.uint8)
-    pad0[:32] = sigs[0, :32]
-    pad0[32:64] = pubs[0]
-    pad0[64:total] = m[0]
-    pad0[total] = 0x80
-    pad0[-16:] = np.frombuffer((total * 8).to_bytes(16, "big"), np.uint8)
     tmpl = (
-        np.ascontiguousarray(pad0.reshape(-1, 4)[:, ::-1].reshape(-1))
-        .view("<u4").astype(np.uint32)
+        np.concatenate([sigs[0, :32], pubs[0], m[0], tail])
+        .view(">u4").astype(np.uint32)
     )
     # message byte columns that differ across the batch -> padded word rows
-    diff_cols = np.nonzero((m != m[0]).any(axis=0))[0]
-    vrows = np.unique((64 + diff_cols) // 4).astype(np.int32)
+    vrows = np.unique((64 + _varying_columns(m)) // 4).astype(np.int32)
     if vrows.size == 0:
         vrows = np.array([16], np.int32)  # row 16 always exists (rows>=32)
     k = int(vrows.size)
     k_pad = 1 << (k - 1).bit_length()
-    # per-signature BE words at the varying rows
-    mpad = np.zeros((b, (rows - 16) * 4), dtype=np.uint8)
-    mpad[:n, : total - 64] = m
-    mpad[:, total - 64] = 0x80
-    mpad[:, -16:] = np.frombuffer((total * 8).to_bytes(16, "big"), np.uint8)
-    mwords = (
-        np.ascontiguousarray(mpad.reshape(b, -1, 4)[:, :, ::-1].reshape(b, -1))
-        .view("<u4").astype(np.uint32)
-    )
-    vwords = mwords[:, vrows - 16]
     if k_pad > k:  # duplicate scatter rows carry identical values
         vrows = np.concatenate([vrows, np.full((k_pad - k,), vrows[0], np.int32)])
-        vwords = np.concatenate(
-            [vwords, np.tile(vwords[:, :1], (1, k_pad - k))], axis=1
-        )
-    return tmpl, vrows, vwords
+    # per-signature BE words at the varying rows: each row's four bytes, by
+    # their column in the message; a column past the message reads the tail,
+    # lanes n..b the all-zero message
+    cols = ((vrows[:, None] - 16) * 4 + np.arange(4)).reshape(-1)
+    past = cols >= ln
+    vbytes = np.empty((b, 4 * k_pad), dtype=np.uint8)
+    if ln:
+        np.take(m, cols, axis=1, out=vbytes[:n], mode="clip")
+    vbytes[n:] = 0
+    vbytes[:, past] = tail[cols[past] - ln]
+    return tmpl, vrows, vbytes.view(">u4").astype(np.uint32)
 
 
-def _sig_words(sigs, valid) -> np.ndarray:
-    """(n, 64) signature bytes as (n, 16) LE words, a fresh array; invalid
-    rows' scalars zeroed to keep device work defined."""
-    sig_words = np.ascontiguousarray(sigs).view("<u4").astype(np.uint32)
-    sig_words[~valid] = 0
+def _sig_words(sigs, valid, b: Optional[int] = None) -> np.ndarray:
+    """(n, 64) signature bytes as (b, 16) LE words (b = n unless given), a
+    fresh array; invalid rows' scalars zeroed to keep device work defined,
+    rows n..b zero."""
+    n = sigs.shape[0]
+    sig_words = np.zeros((n if b is None else b, 16), dtype=np.uint32)
+    sig_words[:n] = np.ascontiguousarray(sigs).view("<u4")
+    sig_words[:n][~valid] = 0
     return sig_words
 
 
 def _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln, interpret,
-                    carry_mode="lazy"):
+                    carry_mode="lazy", valset_key: Optional[bytes] = None):
     n = pubs.shape[0]
     # interpret mode (CPU tests) has no tile-alignment constraint: shrink the
     # lane count so the eager interpreter does 16x less padded work.
@@ -1021,11 +1054,13 @@ def _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln, interpret,
         # packed path: ship only signatures + the message words that actually
         # vary across the batch; everything else is device-cached or template.
         # One device launch; its three spans split the host's share of it
-        with trace.span("dispatch.pack", n=n, lanes=b):
-            sig_words = _pad_rows(_sig_words(sigs, valid), b)
+        with trace.span("dispatch.pack", n=n, lanes=b) as sp:
+            sig_words = _sig_words(sigs, valid, b)
             tmpl, vrows, vwords = pack_variable_words(pubs, msgs, sigs, ln, b)
+            sp.set(vwords=int(vrows.size))
         with trace.span("dispatch.launch", lanes=b):  # copies in + enqueue
-            negax_d, ay_d, pubw_d = _upload_valset(pubs, neg_ax, ay, b)
+            negax_d, ay_d, pubw_d = _upload_valset(pubs, neg_ax, ay, b,
+                                                   valset_key)
             out = call_jit(
                 _device_verify_packed,
                 negax_d, ay_d, pubw_d,
