@@ -104,7 +104,9 @@ def main(argv=None) -> int:
             dev.device_kind, lambda m: print(m, flush=True), t_process,
             device=make_device(dev.platform, args.control))
         rows.append({"seed": seed, "control": args.control,
-                     "correct": result["correct"], "failed": result["failed"]})
+                     "correct": result["correct"], "failed": result["failed"],
+                     "failed_checks": [c["name"] for c in result["checks"]
+                                       if not c["ok"]]})
         print("CONTROL " + json.dumps(rows[-1]), flush=True)
     want = args.control == "none"
     print("CONTROL_SUMMARY " + json.dumps({

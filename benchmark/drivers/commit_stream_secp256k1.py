@@ -5,10 +5,14 @@
 not know a key type).  ``setup`` and ``check`` are this file's: the ring is
 signed by ``benchmark/chaingen_secp256k1.py`` and judged by
 ``benchmark/oracle_secp256k1.py``, and the window also answers for what only
-this path has: lanes its host prologue decided, and where the audit's
-(Python, 4.5 ms a lane) oracle ran.  ``setup`` also refuses, before anything
-is timed, a program whose host oracle does not hold the accept set
-(``_require_the_guards_oracle``).
+this path has: lanes its host prologue decided (since PR 27 two passes over
+the lanes round ONE modular inversion a dispatch), and where the audit's
+oracle ran (plain Python, about 4.1 ms a lane on the v5e's host: the 4-lane
+worker answers after the 13.8 ms dispatch has, so the audit paces the call,
+and 3-12 % of a window's calls end 4 or 8 ms late behind one late worker,
+PERF.md 7f).  ``setup`` also
+refuses, before anything is timed, a program whose host oracle does not hold
+the accept set (``_require_the_guards_oracle``).
 
 Traffic parameters: ``ring``, ``first_height``, ``warmup_calls``,
 ``tampers``, as ``commit_stream``.

@@ -27,7 +27,7 @@ import threading
 import time
 from array import array
 from contextlib import contextmanager
-from dataclasses import dataclass, field
+from dataclasses import asdict, dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence
 
 DATA_DIR = "benchmark"
@@ -501,18 +501,16 @@ def run_cell(bench: Bench, workload: str, seed: int, seconds: float,
     log(watch.line(t_open, t_close, unfrozen))
 
     checks: List[Check] = list(driver.check(ctx, state, window, data))
-    for c in checks:
-        log(c.line())
 
-    metrics: Dict[str, dict] = {}
-    wanted = cell.per_layer if trace else cell.end_to_end
-    for m in wanted:
-        if m["name"] == "setup_s":
-            value: Optional[float] = setup_s
-        else:
-            value = cell.reduce(m["name"], data)
-        if value is not None:
-            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    def reduce_all(entries) -> Dict[str, dict]:
+        out: Dict[str, dict] = {}
+        for m in entries:
+            value = setup_s if m["name"] == "setup_s" else cell.reduce(m["name"], data)
+            if value is not None:
+                out[m["name"]] = {"value": value, "unit": m["unit"]}
+        return out
+
+    metrics = reduce_all(cell.per_layer if trace else cell.end_to_end)
     if window.halves:
         halves = {}
         for m in cell.end_to_end:
@@ -539,4 +537,13 @@ def run_cell(bench: Bench, workload: str, seed: int, seconds: float,
     if trace_data is not None:
         result["_trace"] = trace_data
         result["_spans"] = data.spans
+    else:
+        # per-layer readings that need no trace stand in an untraced line
+        # too, under a key of their own (``metrics`` is the end-to-end set)
+        clock = reduce_all(m for m in cell.per_layer if m["source"] == "host_clock")
+        if clock:
+            result["per_layer_host_clock"] = clock
+    # each number compared beside its limit; ``run.py`` puts the key last
+    # and prints the lines
+    result["checks"] = [asdict(c) for c in checks]
     return result

@@ -94,6 +94,12 @@ def main(argv=None) -> int:
         log(f"trace: {len(trace_data['ops'])} device ops, clock drift "
             f"{trace_data['drift_ns']} ns, planes {trace_data['planes']}")
     result["device"] = device
+    # what was compared, beside its limit: the result line's last key and
+    # the last lines of standard error
+    checks = result["checks"] = result.pop("checks")
+    for c in checks:
+        print(harness.Check(**c).line(), file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

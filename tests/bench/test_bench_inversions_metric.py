@@ -41,11 +41,13 @@ def _window(dispatches, inversions=None, ed25519=0.0):
 def test_the_entry_is_the_issues_but_for_its_name():
     bench = harness.Bench(ROOT)
     (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == METRIC]
-    assert entry == {
+    # a later cell may list itself beside the issue's
+    assert "secp256-stream" in entry["workloads"]
+    assert dict(entry, workloads=None) == {
         "name": METRIC, "unit": "inversions", "better": "lower",
         "source": "program_counter",
         "layer": "device boundary (crypto/batch -> ops/dispatch)",
-        "moves": "verify_p50_ms", "workloads": ["secp256-stream"]}
+        "moves": "verify_p50_ms", "workloads": None}
     assert entry in bench.cell("secp256-stream").per_layer
     spec = bench.read_json("metrics", METRIC + ".json")
     assert spec["reducer"] == "counter_ratio"

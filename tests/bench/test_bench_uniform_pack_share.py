@@ -49,14 +49,17 @@ def test_the_entry_and_its_file_are_the_issues(metric):
     cell, moves = METRICS[metric]
     bench = harness.Bench(ROOT)
     (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == metric]
-    assert entry == {
+    # a later cell may list itself beside the issue's
+    assert cell in entry["workloads"]
+    assert dict(entry, workloads=None) == {
         "name": metric, "unit": "ratio", "better": "higher",
         "source": "program_counter",
         "layer": "device boundary (crypto/batch -> ops/dispatch)",
-        "moves": moves, "workloads": [cell]}
+        "moves": moves, "workloads": None}
     assert entry in bench.cell(cell).per_layer
-    assert bench.spec["per_layer"][-2:] == [
-        m for m in bench.spec["per_layer"] if m["name"] in METRICS]
+    # appended behind what was there, wherever later PRs append theirs
+    names = [m["name"] for m in bench.spec["per_layer"]]
+    assert names.index(metric) > names.index("inversions_per_dispatch.secp256k1")
     assert bench.read_json("metrics", metric + ".json") == {
         "name": metric, "reducer": "counter_ratio",
         "args": {"numerator": PACK, "numerator_labels": {"path": "uniform"},
