@@ -314,6 +314,11 @@ def _self_check():
     vm.device_audit.add(1.0, ("mismatch",))
     # how a Pallas ed25519 call packed its lanes (ops/ed25519_pallas)
     vm.ed25519_pack.add(1.0, ("uniform",))
+    # a chain whose validator set changes: cut windows, applied changes,
+    # whole-cache clears (blockchain/reactor, ops/ed25519_pallas)
+    vm.window_cut.add(1.0, ("valset_change",))
+    vm.valset_changes.add(1.0)
+    vm.valset_cache_clears.add(1.0, ("device",))
     # the secp256k1 prologue's pair (ops/secp256k1_verify.record_prologue)
     vm.secp256k1_host_decided.add(2.0, ("malformed",))
     vm.secp256k1_inversions.add(1.0)
@@ -474,6 +479,10 @@ def _self_check():
         "tendermint_verify_valset_cache_total",
         "tendermint_verify_sync_ticks_total",
         "tendermint_verify_ed25519_pack_total",
+        # fast sync over a changing validator set
+        "tendermint_verify_window_cut_total",
+        "tendermint_verify_valset_changes_total",
+        "tendermint_verify_valset_cache_clears_total",
         # the secp256k1 host prologue: lanes it decided itself, and the
         # modular inversions it performed (one a dispatch)
         "tendermint_verify_secp256k1_host_decided_total",

@@ -19,6 +19,7 @@ from __future__ import annotations
 import threading
 import time
 from concurrent.futures import CancelledError, Future
+from concurrent.futures import TimeoutError as FutureTimeout
 from typing import List, Optional, Tuple
 
 from tendermint_tpu.blockchain.messages import (
@@ -43,6 +44,7 @@ MAX_MSG_SIZE = 104857600  # 100 MB protocol block ceiling (types/params.go:11)
 TRY_SYNC_INTERVAL = 0.01  # reference trySyncTicker 10ms
 STATUS_UPDATE_INTERVAL = 2.0  # reference 10s; shrunk for test nets
 SWITCH_TO_CONSENSUS_INTERVAL = 0.5  # reference 1s
+DRAIN_TIMEOUT = 30.0  # the longest a thrown-away speculation is waited for
 # Heights verified per device dispatch. Two regimes
 # (scripts/bench_fastsync.py --sweep): the HOST pipeline alone is
 # window-size-insensitive up to ~128 and degrades slightly beyond (cache
@@ -121,11 +123,13 @@ def verify_block_window(
     votes_rows: List[list] = []
     power_rows: List[list] = []
     local_parts: List = []
+    cut = "none"  # what ended the run of heights: window_cut_total's label
     # one span for the loop: per window, never per block
     with trace.span("fastsync.precheck", n=n) as sp:
         for i in range(n):
             block, next_block = blocks[i], blocks[i + 1]
             if block.header.validators_hash != valset.hash():
+                cut = "valset_change"
                 if i == 0:
                     # offset 0 is always OUR current valset; a mismatch there
                     # is a bad block, not a future valset — punishable, else
@@ -143,6 +147,7 @@ def verify_block_window(
                 )
             except CommitError as e:
                 structural = WindowVerifyError(i, str(e))
+                cut = "structural"
                 break
             vrow, prow = planner.rows_from_commit(
                 commit.precommits, pubkeys, msgs, sigs, powers
@@ -151,7 +156,8 @@ def verify_block_window(
             power_rows.append(prow)
             local_parts.append(parts)
             usable += 1
-        sp.set(n=usable)
+        sp.set(n=usable, cut=cut)
+    get_verify_metrics().window_cut.add(1.0, (cut,))
 
     if usable == 0:
         return 0, structural
@@ -380,21 +386,40 @@ class BlockchainReactor(Reactor):
         return auto_verify_window(self.state.validators.size)
 
     # -- speculative (double-buffered) verify --------------------------------------
-    def _discard_speculation(self, slots) -> None:
+    def _drain(self, fut: Future, where: str) -> bool:
+        """Wait, BOUNDED, for a speculative verify that could not be
+        cancelled: a wedged device must hold neither the sync loop nor the
+        switch to consensus hostage (the daemon worker dies with the process
+        either way).  False: it did not finish in DRAIN_TIMEOUT."""
+        try:
+            fut.result(timeout=DRAIN_TIMEOUT)
+        except FutureTimeout:
+            self.logger.warning(
+                "speculative verify did not drain %s (wedged device "
+                "dispatch?)", where)
+            return False
+        except Exception:
+            pass  # cancelled, or the verify's own failure: it has finished
+        return True
+
+    def _discard_speculation(self, slots, reason: str) -> None:
         """Cancel-or-drain invalidated slots.  A running verify must drain —
         letting it race a fresh synchronous verify would double-dispatch
-        its window through the device."""
+        its window through the device.  ``reason``: what voided them,
+        ``valset_change`` (the apply changed the set they assumed) or
+        ``height`` (the pool is no longer where they start)."""
         with trace.span(
             "fastsync.discard", slots=len(slots),
             heights=sum(len(blocks) - 1 for *_, blocks in slots),
-        ):
+            reason=reason,
+        ) as sp:
+            drained = True
             for _, _, fut, _, _ in slots:
                 get_verify_metrics().speculative.add(1.0, ("miss",))
                 if not fut.cancel():
-                    try:
-                        fut.result()
-                    except BaseException:
-                        pass
+                    drained = self._drain(fut, "before its window was "
+                                          "verified again") and drained
+            sp.set(drained=drained)
 
     def _take_speculative(self) -> Optional[tuple]:
         """Harvest the in-flight window N+1 verification, if it still
@@ -410,7 +435,9 @@ class BlockchainReactor(Reactor):
         first_h, vhash, fut, parts_list, blocks = head
         if first_h != self.pool.height or self.state.validators.hash() != vhash:
             rest, self._spec = self._spec, []
-            self._discard_speculation([head] + rest)
+            self._discard_speculation(
+                [head] + rest,
+                "height" if first_h != self.pool.height else "valset_change")
             return None
         # how long the apply loop stood waiting for the bc-verify worker
         with trace.span("fastsync.harvest", h0=first_h, hit=False) as sp:
@@ -517,6 +544,20 @@ class BlockchainReactor(Reactor):
         with trace.span("fastsync.apply", h0=blocks[0].height, n=n_ok):
             self._apply_verified(blocks, parts_list, n_ok)
 
+    def _note_valset_change(self, height: int, old, new) -> None:
+        """One span and one count a block whose apply changed the set that
+        binds at ``height + 1``: who joined, left or changed power."""
+        with trace.span("fastsync.valset_change", h=height) as sp:
+            before = {v.address: v.voting_power for v in old.validators}
+            after = {v.address: v.voting_power for v in new.validators}
+            sp.set(
+                added=len(after.keys() - before.keys()),
+                removed=len(before.keys() - after.keys()),
+                repowered=sum(1 for a, p in after.items()
+                              if before.get(a, p) != p),
+            )
+        get_verify_metrics().valset_changes.add(1.0)
+
     def _apply_verified(self, blocks, parts_list, n_ok: int) -> None:
         for i in range(n_ok):
             block = blocks[i]
@@ -527,7 +568,7 @@ class BlockchainReactor(Reactor):
                 # the first synced block's own LastCommit predates our
                 # batches — its membership check below is False, forcing
                 # the full verify
-                self.state = self.block_exec.apply_block(
+                new_state = self.block_exec.apply_block(
                     self.state, block_id, block,
                     trusted_last_commit=block.height - 1
                     in self._trusted_commit_heights,
@@ -543,6 +584,10 @@ class BlockchainReactor(Reactor):
                 raise FatalSyncError(
                     f"verified block {block.height} failed to apply: {e}"
                 ) from e
+            if new_state.validators.hash() != self.state.validators.hash():
+                self._note_valset_change(
+                    block.height, self.state.validators, new_state.validators)
+            self.state = new_state
             self.pool.pop_first()
             self.blocks_synced += 1
             self._trusted_commit_heights.discard(block.height - 2)
@@ -576,16 +621,8 @@ class BlockchainReactor(Reactor):
         for spec in specs:
             if not spec[2].cancel():
                 # drain: the device should be idle before consensus starts
-                # its own commit verifies — but BOUNDED: a wedged device
-                # must not hold the switch to consensus hostage (the daemon
-                # worker dies with the process either way)
-                try:
-                    spec[2].result(timeout=30.0)
-                except BaseException:
-                    self.logger.warning(
-                        "speculative verify did not drain before consensus "
-                        "switchover (wedged device dispatch?)"
-                    )
+                # its own commit verifies
+                self._drain(spec[2], "before consensus switchover")
         if self.consensus_reactor is not None:
             self.consensus_reactor.switch_to_consensus(
                 self.state.copy(), self.blocks_synced

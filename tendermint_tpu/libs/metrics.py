@@ -456,6 +456,37 @@ class VerifyMetrics:
             "line), harvest (took a speculation), empty (under two blocks)",
             label_names=("result",),
         )
+        # a chain whose validator set changes (blockchain/reactor): where
+        # each verify_block_window call stopped collecting heights, how
+        # often an applied block changed the set, and how often the Pallas
+        # path's whole-valset caches were emptied (ops/ed25519_pallas)
+        self.window_cut = r.counter(
+            "verify_window_cut_total",
+            "Fast-sync verify_block_window calls by what ended the run of "
+            "heights they collected: valset_change (a block whose "
+            "validators_hash is not the state's set, at offset 0 too: a "
+            "speculation begun behind a cut) | structural (a commit refused "
+            "by the per-precommit rules) | none (ran to the end of what was "
+            "peeked)",
+            label_names=("reason",),
+        )
+        for reason in ("valset_change", "structural", "none"):  # from 0
+            self.window_cut.add(0.0, (reason,))
+        self.valset_changes = r.counter(
+            "verify_valset_changes_total",
+            "Blocks applied by fast sync whose apply changed the state's "
+            "validator set (the set binds at the next height)",
+        )
+        self.valset_changes.add(0.0)  # exposed from 0
+        self.valset_cache_clears = r.counter(
+            "verify_valset_cache_clears_total",
+            "Whole-cache clears of the Pallas ed25519 path's valset caches "
+            "(host: 64 entries, device: 32), which are emptied whole when "
+            "full",
+            label_names=("cache",),
+        )
+        for cache in ("host", "device"):  # both series from 0
+            self.valset_cache_clears.add(0.0, (cache,))
         # path attribution: which carry schedule each device window traced
         # with (eager | lazy; ops/fe_common) and which verify strategy
         # decided it (ladder | msm; ops/ed25519_msm); host dispatches carry

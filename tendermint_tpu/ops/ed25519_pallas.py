@@ -775,18 +775,21 @@ def _decompress_valset(
     if hit is not None:
         return hit
     n = pubs.shape[0]
-    neg_ax = np.zeros((n, NLIMB), dtype=np.uint32)
-    ay = np.zeros((n, NLIMB), dtype=np.uint32)
-    valid = np.ones((n,), dtype=bool)
-    for i in range(n):
-        dec = _xla._decompress_neg_cached(pubs[i].tobytes())
-        if dec is None:
-            valid[i] = False
-        else:
-            neg_ax[i] = dec[0]
-            ay[i] = dec[1]
+    # one span a missed set, never a lane: what a new key array costs
+    with trace.span("valset.miss", cache="host", lanes=n, bytes=32 * n):
+        neg_ax = np.zeros((n, NLIMB), dtype=np.uint32)
+        ay = np.zeros((n, NLIMB), dtype=np.uint32)
+        valid = np.ones((n,), dtype=bool)
+        for i in range(n):
+            dec = _xla._decompress_neg_cached(pubs[i].tobytes())
+            if dec is None:
+                valid[i] = False
+            else:
+                neg_ax[i] = dec[0]
+                ay[i] = dec[1]
     if len(_valset_cache) >= _VALSET_CACHE_MAX:
         _valset_cache.clear()
+        get_verify_metrics().valset_cache_clears.add(1.0, ("host",))
     _valset_cache[key] = (neg_ax, ay, valid)
     return neg_ax, ay, valid
 
@@ -814,14 +817,19 @@ def _upload_valset(pubs, neg_ax, ay, b, key: Optional[bytes] = None):
         1.0, ("device", "miss" if hit is None else "hit"))
     if hit is not None:
         return hit
-    pub_words = np.ascontiguousarray(pubs).view("<u4").astype(np.uint32)
-    entry = (
-        jnp.asarray(_pad_rows(neg_ax, b)),
-        jnp.asarray(_pad_rows(ay, b)),
-        jnp.asarray(_pad_rows(pub_words, b)),
-    )
+    # the three uploads of a missed set: negax, ay and the key words of
+    # every lane of the bucket
+    with trace.span("valset.miss", cache="device", lanes=b,
+                    bytes=4 * b * (2 * NLIMB + 8)):
+        pub_words = np.ascontiguousarray(pubs).view("<u4").astype(np.uint32)
+        entry = (
+            jnp.asarray(_pad_rows(neg_ax, b)),
+            jnp.asarray(_pad_rows(ay, b)),
+            jnp.asarray(_pad_rows(pub_words, b)),
+        )
     if len(_dev_valset_cache) >= _DEV_VALSET_CACHE_MAX:
         _dev_valset_cache.clear()
+        get_verify_metrics().valset_cache_clears.add(1.0, ("device",))
     _dev_valset_cache[key] = entry
     return entry
 

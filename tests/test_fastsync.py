@@ -156,6 +156,56 @@ class TestVerifyBlockWindow:
         assert rounds >= 2  # pipeline crossed the valset boundary
 
 
+    # -- what ended a window's run of heights: one count a call, and the
+    # precheck span says the same
+
+    @pytest.mark.parametrize("case,want_n,want_cut", [
+        ("whole", 11, "none"),
+        ("valset_change", 6, "valset_change"),
+        ("behind_a_cut", 0, "valset_change"),
+        ("structural", 2, "structural"),
+    ])
+    def test_window_cut_is_counted_by_reason(
+            self, fx, tracing, verify_counters, case, want_n, want_cut):
+        import base64
+
+        from tendermint_tpu.abci.examples.kvstore import PersistentKVStoreApp
+        from tendermint_tpu.crypto.keys import PrivKeyEd25519
+        from tendermint_tpu.types import MockPV
+
+        def cuts():
+            return {r: verify_counters("tendermint_verify_window_cut_total",
+                                       {"reason": r})
+                    for r in ("valset_change", "structural", "none")}
+
+        if case in ("valset_change", "behind_a_cut"):
+            joiner = MockPV(PrivKeyEd25519.generate(bytes([93]) * 32))
+            churn = build_chain(
+                n_vals=4, n_heights=12, chain_id="cut-churn",
+                app_factory=PersistentKVStoreApp, extra_pvs=[joiner],
+                on_height=lambda h, st: [b"val:" + base64.b64encode(
+                    joiner.get_pub_key().bytes()) + b"!50"] if h == 5 else [])
+            st = state_from_genesis(churn.genesis)
+            blocks = [churn.block_store.load_block(h) for h in range(1, 13)]
+            if case == "behind_a_cut":
+                blocks = blocks[6:]  # height 7 on: the next set's, not the state's
+        else:
+            st = state_from_genesis(fx.genesis)
+            blocks = self._blocks(fx)
+            if case == "structural":
+                blocks[3].last_commit.block_id = dataclasses.replace(
+                    blocks[3].last_commit.block_id, hash=b"\xde" * 32)
+        tracing.reset()
+        before = cuts()
+        n_ok, err = verify_block_window(st, blocks)
+        assert n_ok == want_n
+        assert (err is None) == (case in ("whole", "valset_change"))
+        grown = {k: v - before[k] for k, v in cuts().items()}
+        assert grown == {r: float(r == want_cut) for r in grown}
+        (pre,) = [e for e in tracing.export() if e.get("name") == "fastsync.precheck"]
+        assert pre["args"]["cut"] == want_cut and pre["args"]["n"] == want_n
+
+
 # ---------------------------------------------------------------------------
 # BlockPool
 # ---------------------------------------------------------------------------
@@ -281,6 +331,21 @@ def _make_syncing_node(genesis):
     return bc_reactor, cons_reactor, store
 
 
+def _patient_mconfig():
+    """The test switches' connection settings with a keepalive that a loaded
+    machine can meet.  ``MConnConfig.test_config()`` drops a connection whose
+    pong is 0.35 s late; both nodes of these tests share one interpreter with
+    two consensus states and a sync loop, under six test workers, and nothing
+    redials: a joiner that has lost its only peer never reads "caught up" and
+    stays in fast sync for good (the driver's run of PR 32's tree lost
+    ``test_live_producer_late_joiner_follows`` that way, at its first 60 s
+    wait; a pong timeout of 0.5 ms reproduces the same line).  What the tests
+    assert is fast sync and the hand-over to consensus, not the keepalive."""
+    from tendermint_tpu.p2p.conn.connection import MConnConfig
+
+    return dataclasses.replace(MConnConfig.test_config(), pong_timeout=30.0)
+
+
 class TestFastSyncIntegration:
     def test_late_joiner_syncs_chain_and_switches_to_consensus(self):
         from tendermint_tpu.p2p.test_util import make_connected_switches
@@ -294,7 +359,8 @@ class TestFastSyncIntegration:
             lambda sw: (sw.add_reactor("blockchain", bc), sw.add_reactor("consensus", cons)),
         ]
         switches = make_connected_switches(
-            2, lambda i, sw: (reactors[i](sw), sw)[1], network="sync-chain"
+            2, lambda i, sw: (reactors[i](sw), sw)[1], network="sync-chain",
+            mconfig=_patient_mconfig(),
         )
         try:
             # syncs 29 of 30 blocks (the tip's commit lives in the future),
@@ -347,7 +413,8 @@ class TestFastSyncIntegration:
                         sw.add_reactor("blockchain", bc)),
         ]
         switches = make_connected_switches(
-            2, lambda i, sw: (builders[i](sw), sw)[1], network=doc.chain_id
+            2, lambda i, sw: (builders[i](sw), sw)[1], network=doc.chain_id,
+            mconfig=_patient_mconfig(),
         )
         try:
             # producer commits on its own
@@ -559,6 +626,113 @@ class TestPipelinedVerify:
         assert discards, "the valset change voided no speculation"
         for e in discards:
             assert e["args"]["slots"] >= 1 and e["args"]["heights"] >= 1
+
+    def test_valset_change_is_spanned_and_counted(self, tracing, verify_counters):
+        """A block whose apply changed the state's set draws one
+        ``fastsync.valset_change`` saying who joined, left and changed power,
+        and the speculation it voids says why it went and that it drained."""
+        import base64
+
+        from tendermint_tpu.abci.examples.kvstore import PersistentKVStoreApp
+        from tendermint_tpu.crypto.keys import PrivKeyEd25519
+        from tendermint_tpu.types import MockPV
+
+        joiner = MockPV(PrivKeyEd25519.generate(bytes([94]) * 32))
+
+        def val(pub, power):
+            return b"val:" + base64.b64encode(pub.bytes()) + b"!%d" % power
+
+        def on_height(h, st):
+            sitting = [v.pub_key for v in st.next_validators.validators]
+            if h == 4:  # in force from height 6
+                return [val(joiner.get_pub_key(), 50)]
+            if h == 7:  # in force from height 9: one leaves, one re-powered
+                leaver = next(p for p in sitting if p != joiner.get_pub_key())
+                return [val(leaver, 0), val(joiner.get_pub_key(), 20)]
+            return []
+
+        fx = build_chain(
+            n_vals=4, n_heights=12, chain_id="change-span",
+            app_factory=PersistentKVStoreApp, on_height=on_height,
+            extra_pvs=[joiner],
+        )
+        bc, store = self._direct_reactor(
+            fx, window=4, verifier=self._AcceptAll(),
+            app_factory=PersistentKVStoreApp,
+        )
+        tracing.reset()
+        before = (verify_counters("tendermint_verify_valset_changes_total"),
+                  verify_counters("tendermint_verify_speculative_total",
+                                  {"outcome": "miss"}))
+        for _ in range(10):
+            bc._try_sync_window()
+        assert store.height() == fx.height - 1
+        bc.on_stop()
+        assert verify_counters("tendermint_verify_valset_changes_total") - before[0] == 2
+        spans = [e for e in tracing.export() if e.get("ph") == "X"]
+        changes = [e["args"] for e in spans if e["name"] == "fastsync.valset_change"]
+        assert [(a["h"], a["added"], a["removed"], a["repowered"]) for a in changes] == [
+            (5, 1, 0, 0), (8, 0, 1, 1)]
+        discards = [e["args"] for e in spans if e["name"] == "fastsync.discard"]
+        assert discards and all(
+            a["reason"] == "valset_change" and a["drained"] is True for a in discards)
+        missed = verify_counters("tendermint_verify_speculative_total",
+                                 {"outcome": "miss"}) - before[1]
+        assert missed == sum(a["slots"] for a in discards)
+
+    def test_discard_does_not_wait_for_ever_for_a_speculation(
+            self, tracing, monkeypatch, caplog):
+        """ROADMAP D13: a thrown-away speculation whose verify never returns
+        (a wedged device) holds the sync loop for DRAIN_TIMEOUT and no
+        longer; the loop says so and verifies the window itself."""
+        from tendermint_tpu.blockchain import reactor as reactor_mod
+
+        monkeypatch.setattr(reactor_mod, "DRAIN_TIMEOUT", 0.3, raising=False)
+        fx = build_chain(n_vals=4, n_heights=12, chain_id="wedged-chain")
+
+        class Wedged(self._AcceptAll):
+            """Call 2, the speculation, never answers."""
+
+            def __init__(self):
+                self.calls = 0
+                self.started2 = threading.Event()
+                self.release = threading.Event()
+
+            def verify_ed25519(self, items):
+                self.calls += 1
+                if self.calls == 2:
+                    self.started2.set()
+                    self.release.wait(60)
+                return super().verify_ed25519(items)
+
+            verify_secp256k1 = verify_ed25519
+
+        wv = Wedged()
+        bc, store = self._direct_reactor(fx, window=4, verifier=wv)
+        try:
+            bc._try_sync_window()  # verifies and applies 1..4, speculates on 5..8
+            assert wv.started2.wait(10) and store.height() == 4
+            # the pool is no longer where the speculation starts
+            first_h, *rest = bc._spec[0]
+            bc._spec[0] = (first_h + 1, *rest)
+            done = threading.Event()
+
+            def look():
+                bc._try_sync_window()
+                done.set()
+
+            t0 = time.monotonic()
+            threading.Thread(target=look, daemon=True).start()
+            assert done.wait(10), "the sync loop is still waiting for the speculation"
+            assert 0.3 <= time.monotonic() - t0 < 5.0
+            assert store.height() == 8  # verified in line and applied
+            (discard,) = [e["args"] for e in tracing.export()
+                          if e.get("name") == "fastsync.discard"]
+            assert discard["reason"] == "height" and discard["drained"] is False
+            assert any("did not drain" in r.getMessage() for r in caplog.records)
+        finally:
+            wv.release.set()
+            bc.on_stop()
 
 
 class TestVerifyBlockWindowSharded:

@@ -620,12 +620,45 @@ class TestProgramSpans:
             ("host", "miss"): 1, ("host", "hit"): 1,
             ("device", "miss"): 1, ("device", "hit"): 1}
         by = _by_name(trace_mod.export())
+        # the first call's two misses are spanned, one a cache; the second
+        # call hits both and draws none
         assert {n: len(v) for n, v in by.items()} == {
             "dispatch.prepare": 2, "dispatch.pack": 2, "dispatch.launch": 2,
-            "dispatch.wait": 2}
+            "dispatch.wait": 2, "valset.miss": 2}
         assert by["dispatch.pack"][0]["args"]["n"] == 5
         assert {e["args"]["lanes"] for n, v in by.items() for e in v
-                if n != "dispatch.prepare"} == {128}
+                if n not in ("dispatch.prepare", "valset.miss")} == {128}
+        assert {(e["args"]["cache"], e["args"]["lanes"], e["args"]["bytes"])
+                for e in by["valset.miss"]} == {
+            ("host", 5, 5 * 32), ("device", 128, 128 * 192)}
+        prepare, launch = by["dispatch.prepare"][0], by["dispatch.launch"][0]
+        parents = {e["args"]["cache"]: e["args"]["parent_id"] for e in by["valset.miss"]}
+        assert parents == {"host": prepare["args"]["span_id"],
+                           "device": launch["args"]["span_id"]}
+
+    def test_a_full_valset_cache_is_emptied_whole_and_counted(
+            self, monkeypatch, verify_counters):
+        import numpy as np
+
+        from tendermint_tpu.ops import ed25519_pallas as ep
+
+        def clears():
+            return {c: verify_counters("tendermint_verify_valset_cache_clears_total",
+                                       {"cache": c}) for c in ("host", "device")}
+
+        monkeypatch.setattr(ep, "_valset_cache", {})
+        monkeypatch.setattr(ep, "_dev_valset_cache", {})
+        monkeypatch.setattr(ep, "_VALSET_CACHE_MAX", 3)
+        monkeypatch.setattr(ep, "_DEV_VALSET_CACHE_MAX", 2)
+        before = clears()
+        rng = np.random.default_rng(33)
+        for _ in range(5):  # five new key arrays, as five windows of a churn chain
+            pubs = rng.integers(0, 256, size=(4, 32), dtype=np.uint8)
+            neg_ax, ay, _valid = ep._decompress_valset(pubs)
+            ep._upload_valset(pubs, neg_ax, ay, 8)
+        grown = {c: v - before[c] for c, v in clears().items()}
+        assert grown == {"host": 1.0, "device": 2.0}
+        assert len(ep._valset_cache) == 2 and len(ep._dev_valset_cache) == 1
 
     def test_first_call_of_a_program_is_named(self, tracing):
         import jax
