@@ -20,6 +20,13 @@ against the host oracle:
                  same height with the same error.
   secp256k1      config 4: a 256-validator secp256k1 commit through
                  verify_commit / verify_generic -> ops/secp256k1_pallas.
+  multisig       config 5, cut from 1,000 validators to 100: a commit of
+                 validators keyed 3-of-5 (PubKeyMultisigThreshold over
+                 ed25519 sub-keys, a seeded 3, 4 or 5 of the five signing)
+                 through verify_commit; every sub-signature rides ONE
+                 ed25519 dispatch and no validator is decided on the host;
+                 then seeded bad sub-signatures through verify_generic,
+                 each validator's verdict equal to its key's verify_bytes.
   ed25519_msm    one 512-signature window with ed25519_path="msm" (the RLC
                  seed is a hash of the seeded content, so it is pinned).
   node           a live node through the CLI, no TM_BATCH_VERIFIER in its
@@ -33,7 +40,7 @@ device_fallback_total and host_fallback_total, zero audit mismatches and a
 closed breaker.  Seconds are printed as set-up facts, never recorded as
 performance numbers.
 
-One process per chip: this parent never imports jax.  The four kernel
+One process per chip: this parent never imports jax.  The five kernel
 stages share one child; the CLI node is a second child started after the
 first has exited.  Without a TPU the first child says so and exits before
 any stage; the command takes no flag.
@@ -60,13 +67,15 @@ N_VALIDATORS = 10_000
 CORRUPT_SHARE = 0.01
 FASTSYNC_BLOCKS, FASTSYNC_VALS, FASTSYNC_WINDOW = 2048, 64, 512
 SECP_VALIDATORS = 256
+MULTISIG_VALIDATORS, MULTISIG_K, MULTISIG_N = 100, 3, 5
 MSM_WINDOW = 512
 NODE_HEIGHT = 5
 BACKEND = "pallas"  # what every stage must have run on
 
 KERNELS_DEADLINE_S = 840.0
 NODE_DEADLINE_S = 240.0
-KERNEL_STAGES = ("commit_verify", "fast_sync", "secp256k1", "ed25519_msm")
+KERNEL_STAGES = (
+    "commit_verify", "fast_sync", "secp256k1", "multisig", "ed25519_msm")
 STAGE_PREFIX = "STAGE "
 
 NO_TPU_EXIT = 3
@@ -336,6 +345,95 @@ def stage_secp256k1(checks: dict) -> None:
     assert not want[lanes].any(), "host oracle accepted a corrupted lane"
 
 
+def stage_multisig(checks: dict) -> None:
+    import random
+
+    import numpy as np
+
+    from tendermint_tpu.crypto import ed25519 as ed
+    from tendermint_tpu.crypto.batch import verify_generic
+    from tendermint_tpu.crypto.keys import PubKeyEd25519
+    from tendermint_tpu.crypto.multisig import (
+        Multisignature,
+        PubKeyMultisigThreshold,
+    )
+    from tendermint_tpu.libs.metrics import get_verify_metrics
+    from tendermint_tpu.types import BlockID, PartSetHeader, SignedMsgType, Vote
+    from tendermint_tpu.types.block import Commit
+    from tendermint_tpu.types.validator_set import Validator, ValidatorSet
+
+    chain_id, height = "smoke-multisig", 11
+    rng = random.Random(SEED + 2)
+    privs = {}  # a validator's address -> its five sub-keys' private keys
+    vals = []
+    for _ in range(MULTISIG_VALIDATORS):
+        subs = [ed.gen_privkey(rng.randbytes(32)) for _ in range(MULTISIG_N)]
+        key = PubKeyMultisigThreshold(
+            MULTISIG_K, tuple(PubKeyEd25519(p[32:]) for p in subs))
+        privs[key.address()] = subs
+        vals.append(Validator(key, 10))
+    valset = ValidatorSet(vals)
+    block_id = BlockID(b"\x55" * 32, PartSetHeader(1, b"\x66" * 32))
+    votes, signed = [], 0
+    for idx, val in enumerate(valset.validators):
+        vote = Vote(
+            vote_type=SignedMsgType.PRECOMMIT, height=height, round=0,
+            timestamp_ns=1_700_000_000_000_000_000 + idx, block_id=block_id,
+            validator_address=val.address, validator_index=idx,
+        )
+        msg = vote.sign_bytes(chain_id)
+        # which of the five devices answer differs from validator to validator
+        signers = sorted(rng.sample(
+            range(MULTISIG_N), rng.choice((3, 3, 3, 4, 4, 5))))
+        ms = Multisignature.new(MULTISIG_N)
+        for j in signers:
+            ms.add_signature_from_pubkey(
+                ed.sign(privs[val.address][j], msg), val.pub_key.pubkeys[j],
+                val.pub_key.pubkeys)
+        signed += len(signers)
+        votes.append(vote.with_signature(ms.marshal()))
+    commit = Commit(block_id=block_id, precommits=votes)
+    # what the cell msig1k-stream asserts of every call in its window
+    # (benchmark/drivers/commit_stream_multisig.py), asserted here of the
+    # valid commit: every validator flattened, a lane a sub-signature, all
+    # of them in ONE ed25519 dispatch, none decided on the host
+    m = get_verify_metrics()
+    watched = (m.multisig_groups, m.multisig_lanes, m.calls)
+    before = [c.snapshot() for c in watched]
+    valset.verify_commit(chain_id, block_id, height, commit)
+    checks["verify_commit_accepted"] = True
+    groups, lanes, calls = (
+        _delta(c.snapshot(), b) for c, b in zip(watched, before))
+    checks["valid_commit"] = {
+        "flattened_validators": sum(groups.values()),
+        "lanes": sum(lanes.values()),
+        "dispatches": {"/".join(k): v for k, v in calls.items()},
+    }
+    assert sum(groups.values()) == MULTISIG_VALIDATORS, groups
+    assert sum(lanes.values()) == signed, (lanes, signed)
+    assert calls == {(BACKEND, "ed25519"): 1}, f"dispatches of one commit: {calls}"
+
+    pubkeys, msgs, sigs, _ = valset.collect_commit_sigs(
+        chain_id, block_id, height, commit)
+    pubkeys, msgs, sigs = list(pubkeys), list(msgs), list(sigs)
+    bad = rng.sample(range(len(sigs)), 5)
+    for v in bad:  # one bit of one sub-signature: still flattened
+        ms = Multisignature.unmarshal(sigs[v])
+        j = rng.randrange(len(ms.sigs))
+        ms.sigs[j] = _flip(ms.sigs[j], rng)
+        sigs[v] = ms.marshal()
+    got = verify_generic(pubkeys, msgs, sigs)
+    want = np.array([pk.verify_bytes(m_, s) for pk, m_, s in
+                     zip(pubkeys, msgs, sigs)], dtype=bool)
+    diff = np.flatnonzero(got != want)
+    checks["validators"] = len(want)
+    checks["corrupted"] = len(bad)
+    checks["rejected_host"] = int(np.count_nonzero(~want))
+    checks["rejected_device"] = int(np.count_nonzero(~got))
+    assert diff.size == 0, f"device != verify_bytes in validators {diff[:8].tolist()}"
+    assert not want[bad].any() and checks["rejected_host"] == len(bad)
+
+
 def stage_ed25519_msm(checks: dict) -> None:
     import random
 
@@ -464,6 +562,7 @@ def kernels_main() -> int:
         "commit_verify": stage_commit_verify,
         "fast_sync": stage_fast_sync,
         "secp256k1": stage_secp256k1,
+        "multisig": stage_multisig,
         "ed25519_msm": stage_ed25519_msm,
     }
     rc = 0

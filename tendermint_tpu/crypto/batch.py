@@ -27,7 +27,7 @@ import os
 import random
 import threading
 import time
-from typing import List, NamedTuple, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -834,12 +834,13 @@ def verify_generic(
     device dispatch — ref threshold_pubkey.go:41-55 loops serially); only
     structurally odd items fall back to host verify_bytes."""
     # the span's own time is the key-type scan and the column lists; the
-    # verifier's spans (guard.call or verify.dispatch) are its children
-    with trace.span("verify.generic", n=len(pubkeys)):
-        return _verify_generic(pubkeys, msgs, sigs, verifier)
+    # verifier's spans (guard.call or verify.dispatch) and, for multisig
+    # members, multisig.flatten and multisig.reduce are its children
+    with trace.span("verify.generic", n=len(pubkeys)) as sp:
+        return _verify_generic(pubkeys, msgs, sigs, verifier, sp)
 
 
-def _verify_generic(pubkeys, msgs, sigs, verifier) -> np.ndarray:
+def _verify_generic(pubkeys, msgs, sigs, verifier, sp) -> np.ndarray:
     from tendermint_tpu.crypto.keys import PubKeySecp256k1
     from tendermint_tpu.crypto.multisig import PubKeyMultisigThreshold
 
@@ -853,6 +854,7 @@ def _verify_generic(pubkeys, msgs, sigs, verifier) -> np.ndarray:
     if all(type(pk) is PubKeyEd25519 for pk in pubkeys) and all(
         len(s) == 64 for s in sigs
     ):
+        sp.set(keys="ed25519")
         raw = getattr(verifier, "verify_ed25519_raw", None)
         if raw is not None:
             return np.asarray(
@@ -863,37 +865,22 @@ def _verify_generic(pubkeys, msgs, sigs, verifier) -> np.ndarray:
             SigItem(pk.bytes(), m, s) for pk, m, s in zip(pubkeys, msgs, sigs)
         ]
         return np.asarray(verifier.verify_ed25519(items), dtype=bool)
+    sp.set(keys="mixed")
     out = np.zeros((n,), dtype=bool)
-    ed_idx: List[Tuple[int, int]] = []  # (result index, position in ed_items)
+    ed_idx: List[int] = []  # result index of ed_items[j]; multisig lanes follow
     ed_items: List[SigItem] = []
     sk_idx: List[int] = []
     sk_items: List[SigItem] = []
-    # multisig groups: (result index, start offset in ed_items, count)
-    ms_groups: List[tuple] = []
+    ms_idx: List[int] = []
     for i, pk in enumerate(pubkeys):
         if isinstance(pk, PubKeyEd25519) and len(sigs[i]) == 64:
-            # (result index, position in ed_items) — multisig sub-items
-            # interleave in ed_items, so positions must be explicit
-            ed_idx.append((i, len(ed_items)))
+            ed_idx.append(i)
             ed_items.append(SigItem(pk.bytes(), msgs[i], sigs[i]))
         elif isinstance(pk, PubKeySecp256k1):
             sk_idx.append(i)
             sk_items.append(SigItem(pk.bytes(), msgs[i], sigs[i]))
         elif isinstance(pk, PubKeyMultisigThreshold):
-            flat = pk.flatten(msgs[i], sigs[i])
-            if flat is None or len(flat) < pk.k:
-                # structurally invalid / non-ed25519 sub-keys / too few
-                # flagged signers — host path decides (usually False)
-                try:
-                    get_verify_metrics().host_fallback.add(
-                        1.0, ("multisig_structural",)
-                    )
-                except Exception:
-                    pass
-                out[i] = pk.verify_bytes(msgs[i], sigs[i])
-                continue
-            ms_groups.append((i, len(ed_items), len(flat)))
-            ed_items.extend(SigItem(p, m, s) for p, m, s in flat)
+            ms_idx.append(i)
         else:
             try:
                 get_verify_metrics().host_fallback.add(
@@ -902,14 +889,54 @@ def _verify_generic(pubkeys, msgs, sigs, verifier) -> np.ndarray:
             except Exception:
                 pass
             out[i] = pk.verify_bytes(msgs[i], sigs[i])
+    # multisig groups: (result index, start offset in ed_items, count)
+    ms_groups: List[tuple] = []
+    if ms_idx:
+        ms_groups = _flatten_multisig(pubkeys, msgs, sigs, ms_idx, ed_items, out)
     if ed_items:
         res = verifier.verify_ed25519(ed_items)
-        for i, pos in ed_idx:
+        for pos, i in enumerate(ed_idx):
             out[i] = res[pos]
-        for i, start, cnt in ms_groups:
-            out[i] = bool(np.all(res[start : start + cnt]))
+        if ms_groups:
+            # a validator's verdict from its run of lanes: all of them
+            with trace.span("multisig.reduce", groups=len(ms_groups)):
+                for i, start, cnt in ms_groups:
+                    out[i] = bool(np.all(res[start : start + cnt]))
     if sk_items:
         res = verifier.verify_secp256k1(sk_items)
         for j, i in enumerate(sk_idx):
             out[i] = res[j]
     return out
+
+
+def _flatten_multisig(pubkeys, msgs, sigs, ms_idx, ed_items, out) -> List[tuple]:
+    """Every multisig member of one call: unmarshal, the size rules, the walk
+    over flagged bits and one ``SigItem`` a flagged sub-signature, appended
+    to ``ed_items``.  Returns the groups (result index, start in ed_items,
+    lanes).  A member whose signature cannot be flattened (structurally
+    invalid, a sub-key that is not ed25519, fewer flagged signers than k)
+    is decided here by the host's ``verify_bytes`` (usually False) and
+    written to ``out``."""
+    groups: List[tuple] = []
+    host_decided = 0
+    first = len(ed_items)
+    with trace.span("multisig.flatten", validators=len(ms_idx)) as sp:
+        for i in ms_idx:
+            pk = pubkeys[i]
+            flat = pk.flatten(msgs[i], sigs[i])
+            if flat is None or len(flat) < pk.k:
+                host_decided += 1
+                out[i] = pk.verify_bytes(msgs[i], sigs[i])
+                continue
+            groups.append((i, len(ed_items), len(flat)))
+            ed_items.extend(SigItem(p, m, s) for p, m, s in flat)
+        sp.set(lanes=len(ed_items) - first, host_decided=host_decided)
+    try:
+        m = get_verify_metrics()
+        if host_decided:
+            m.host_fallback.add(float(host_decided), ("multisig_structural",))
+        m.multisig_groups.add(float(len(groups)))
+        m.multisig_lanes.add(float(len(ed_items) - first))
+    except Exception:
+        pass
+    return groups

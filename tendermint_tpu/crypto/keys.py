@@ -11,7 +11,7 @@ import hmac as _hmac
 import json
 from abc import ABC, abstractmethod
 from dataclasses import dataclass
-from typing import Dict, Type
+from typing import Callable, Dict, Type
 
 from tendermint_tpu.crypto import ed25519 as _ed
 from tendermint_tpu.crypto import secp256k1 as _secp
@@ -200,9 +200,18 @@ class PrivKeySecp256k1(PrivKey):
 # Registry (amino-route replacement)
 # ---------------------------------------------------------------------------
 
-_PUBKEY_TYPES: Dict[str, Type[PubKey]] = {
+def _multisig_from_bytes(data: bytes) -> PubKey:
+    # crypto/multisig imports this module, so it is looked up at the decode
+    from tendermint_tpu.crypto.multisig import PubKeyMultisigThreshold
+
+    return PubKeyMultisigThreshold.from_bytes(data)
+
+
+# type name -> the key from its bytes()
+_PUBKEY_TYPES: Dict[str, Callable[[bytes], PubKey]] = {
     PubKeyEd25519.type_name: PubKeyEd25519,
     PubKeySecp256k1.type_name: PubKeySecp256k1,
+    "tendermint/PubKeyMultisigThreshold": _multisig_from_bytes,
 }
 _PRIVKEY_TYPES: Dict[str, Type[PrivKey]] = {
     PrivKeyEd25519.type_name: PrivKeyEd25519,

@@ -59,9 +59,13 @@ class CompactBitArray:
 
     @staticmethod
     def from_bytes(data: bytes) -> "CompactBitArray":
+        if len(data) < 4:
+            raise ValueError("bit array: truncated")
         bits = int.from_bytes(data[:4], "big")
         ba = CompactBitArray(bits)
         ba.elems = bytearray(data[4 : 4 + (bits + 7) // 8])
+        if len(ba.elems) != (bits + 7) // 8:
+            raise ValueError("bit array: truncated")
         return ba
 
 
@@ -98,16 +102,25 @@ class Multisignature:
 
     @staticmethod
     def unmarshal(data: bytes) -> "Multisignature":
+        """Raises ValueError on bytes ``marshal`` cannot have written: short
+        of what their length fields announce, or with bytes left over (amino's
+        UnmarshalBinaryBare refuses both, so VerifyBytes says false)."""
         ba = CompactBitArray.from_bytes(data)
         off = 4 + (ba.bits + 7) // 8
+        if off + 2 > len(data):
+            raise ValueError("multisignature: truncated")
         nsigs = int.from_bytes(data[off : off + 2], "big")
         off += 2
         sigs = []
         for _ in range(nsigs):
             ln = int.from_bytes(data[off : off + 2], "big")
             off += 2
+            if off + ln > len(data):
+                raise ValueError("multisignature: truncated")
             sigs.append(data[off : off + ln])
             off += ln
+        if off != len(data):
+            raise ValueError("multisignature: bytes left over")
         return Multisignature(ba, sigs)
 
 
@@ -137,6 +150,39 @@ class PubKeyMultisigThreshold(PubKey):
             out += len(kb).to_bytes(2, "big") + kb
         return out
 
+    @classmethod
+    def from_bytes(cls, data: bytes) -> "PubKeyMultisigThreshold":
+        """The key ``bytes()`` wrote; sub-keys of any registered type.
+        Raises ValueError on anything else."""
+        from tendermint_tpu.crypto.keys import _PUBKEY_TYPES
+
+        if len(data) < 8:
+            raise ValueError("multisig pubkey: truncated")
+        k = int.from_bytes(data[:4], "big")
+        n = int.from_bytes(data[4:8], "big")
+        off = 8
+        pubkeys = []
+        for _ in range(n):
+            if off + 1 > len(data):
+                raise ValueError("multisig pubkey: truncated")
+            tl = data[off]
+            name = data[off + 1 : off + 1 + tl]
+            off += 1 + tl
+            if off + 2 > len(data):
+                raise ValueError("multisig pubkey: truncated")
+            kl = int.from_bytes(data[off : off + 2], "big")
+            off += 2
+            if off + kl > len(data):
+                raise ValueError("multisig pubkey: truncated")
+            decode = _PUBKEY_TYPES.get(name.decode("utf-8", "replace"))
+            if decode is None:
+                raise ValueError(f"multisig pubkey: unknown sub-key type {name!r}")
+            pubkeys.append(decode(data[off : off + kl]))
+            off += kl
+        if off != len(data):
+            raise ValueError("multisig pubkey: bytes left over")
+        return cls(k, tuple(pubkeys))
+
     def verify_bytes(self, msg: bytes, sig: bytes) -> bool:
         try:
             multisig = Multisignature.unmarshal(sig)
@@ -145,12 +191,14 @@ class PubKeyMultisigThreshold(PubKey):
         size = multisig.bitarray.bits
         if len(self.pubkeys) != size:
             return False
-        if len(multisig.sigs) < self.k:
+        # threshold_pubkey.go:46: the signature list is k..n long
+        if len(multisig.sigs) < self.k or len(multisig.sigs) > size:
             return False
         # adversarial bytes can flag more signers than signatures supplied —
         # reject instead of indexing out of range (the reference would panic).
-        # count < len(sigs) (unused trailing sigs) stays ACCEPTED: the
-        # reference only indexes flagged entries and never looks at the rest
+        # count < len(sigs) (unused trailing sigs, at most n in all) stays
+        # ACCEPTED: the reference only indexes flagged entries and never
+        # looks at the rest
         if multisig.bitarray.count() > len(multisig.sigs):
             return False
         # each flagged signer must verify (threshold_pubkey.go:41-55)
@@ -173,8 +221,8 @@ class PubKeyMultisigThreshold(PubKey):
             return None
         if multisig.bitarray.bits != len(self.pubkeys):
             return None
-        if len(multisig.sigs) < self.k:
-            return None
+        if not self.k <= len(multisig.sigs) <= len(self.pubkeys):
+            return None  # verify_bytes' bound (threshold_pubkey.go:46)
         if multisig.bitarray.count() > len(multisig.sigs):
             return None  # mirrors verify_bytes' out-of-range rejection
         out = []

@@ -341,6 +341,22 @@ class VerifyMetrics:
             "Items diverted from the device batch to the host path",
             label_names=("reason",),
         )
+        # a multisig validator is decided on the host only where its
+        # signature cannot be flattened: exposed from 0
+        self.host_fallback.add(0.0, ("multisig_structural",))
+        # k-of-n multisig validators on the batch path (crypto/batch
+        # _verify_generic): a validator becomes one ed25519 lane a flagged
+        # sub-signature, so lanes are no longer validators
+        self.multisig_groups = r.counter(
+            "verify_multisig_groups_total",
+            "Multisig validators whose precommit signature was flattened "
+            "into the ed25519 batch (one group of lanes each)",
+        )
+        self.multisig_lanes = r.counter(
+            "verify_multisig_lanes_total",
+            "Sub-signatures of flattened multisig validators sent to the "
+            "ed25519 batch (k..n lanes a validator)",
+        )
         self.speculative = r.counter(
             "verify_speculative_total",
             "Speculative (double-buffered) fast-sync window verifies by outcome",
