@@ -14,7 +14,10 @@ Backends:
   * TPUBatchVerifier   — device path. On a real TPU it dispatches the fused
     Pallas pipeline (ops/ed25519_pallas); on CPU or when a mesh is given it
     uses the portable XLA kernel (ops/ed25519_verify, shard_map-able).
-    Non-ed25519 items (secp256k1, multisig) fall back to host.
+    secp256k1 items have a device kernel of their own (verify_secp256k1).
+    A k-of-n multisig member of ``verify_generic`` rides the ed25519
+    dispatch, one lane a flagged sub-signature; only a member whose
+    signature cannot be flattened is decided on the host (verify_bytes).
 
 Accept/reject is bit-exact across backends (tests/test_ops_ed25519.py).
 """
@@ -832,7 +835,15 @@ def verify_generic(
     their backends; k-of-n threshold multisig aggregates FLATTEN into the
     ed25519 batch (every flagged signer's sub-signature rides the same
     device dispatch — ref threshold_pubkey.go:41-55 loops serially); only
-    structurally odd items fall back to host verify_bytes."""
+    structurally odd items fall back to host verify_bytes.
+
+    The ed25519 lanes of a call go down as three columns (32-byte key,
+    message, 64-byte signature) in ONE ``verify_ed25519_raw``: the plain
+    ed25519 members first, then each multisig member's run of lanes, which
+    ``multisig.flatten_columns`` reads in place from the marshalled
+    signature (no item object a lane).  A multisig member's verdict is the
+    AND of its run, taken for all members at once (``logical_and.reduceat``
+    at the runs' starts)."""
     # the span's own time is the key-type scan and the column lists; the
     # verifier's spans (guard.call or verify.dispatch) and, for multisig
     # members, multisig.flatten and multisig.reduce are its children
@@ -855,27 +866,27 @@ def _verify_generic(pubkeys, msgs, sigs, verifier, sp) -> np.ndarray:
         len(s) == 64 for s in sigs
     ):
         sp.set(keys="ed25519")
-        raw = getattr(verifier, "verify_ed25519_raw", None)
-        if raw is not None:
-            return np.asarray(
-                raw([pk.bytes() for pk in pubkeys], msgs, sigs), dtype=bool
-            )
-        # verifiers without the column form (fakes in tests) get SigItems
-        items = [
-            SigItem(pk.bytes(), m, s) for pk, m, s in zip(pubkeys, msgs, sigs)
-        ]
-        return np.asarray(verifier.verify_ed25519(items), dtype=bool)
+        return _verify_ed25519_columns(
+            verifier, [pk.bytes() for pk in pubkeys], msgs, sigs
+        )
     sp.set(keys="mixed")
     out = np.zeros((n,), dtype=bool)
-    ed_idx: List[int] = []  # result index of ed_items[j]; multisig lanes follow
-    ed_items: List[SigItem] = []
+    # the call's ed25519 lanes as three columns: the plain members first
+    # (ed_idx[j] is the result index of lane j), every multisig member's run
+    # of lanes behind them
+    ed_idx: List[int] = []
+    ed_pubs: List[bytes] = []
+    ed_msgs: List[bytes] = []
+    ed_sigs: List[bytes] = []
     sk_idx: List[int] = []
     sk_items: List[SigItem] = []
     ms_idx: List[int] = []
     for i, pk in enumerate(pubkeys):
         if isinstance(pk, PubKeyEd25519) and len(sigs[i]) == 64:
             ed_idx.append(i)
-            ed_items.append(SigItem(pk.bytes(), msgs[i], sigs[i]))
+            ed_pubs.append(pk.bytes())
+            ed_msgs.append(msgs[i])
+            ed_sigs.append(sigs[i])
         elif isinstance(pk, PubKeySecp256k1):
             sk_idx.append(i)
             sk_items.append(SigItem(pk.bytes(), msgs[i], sigs[i]))
@@ -889,19 +900,24 @@ def _verify_generic(pubkeys, msgs, sigs, verifier, sp) -> np.ndarray:
             except Exception:
                 pass
             out[i] = pk.verify_bytes(msgs[i], sigs[i])
-    # multisig groups: (result index, start offset in ed_items, count)
-    ms_groups: List[tuple] = []
+    groups = None
     if ms_idx:
-        ms_groups = _flatten_multisig(pubkeys, msgs, sigs, ms_idx, ed_items, out)
-    if ed_items:
-        res = verifier.verify_ed25519(ed_items)
-        for pos, i in enumerate(ed_idx):
-            out[i] = res[pos]
-        if ms_groups:
-            # a validator's verdict from its run of lanes: all of them
-            with trace.span("multisig.reduce", groups=len(ms_groups)):
-                for i, start, cnt in ms_groups:
-                    out[i] = bool(np.all(res[start : start + cnt]))
+        groups = _flatten_multisig(
+            pubkeys, msgs, sigs, ms_idx, ed_pubs, ed_msgs, ed_sigs, out
+        )
+    if ed_pubs:
+        res = _verify_ed25519_columns(verifier, ed_pubs, ed_msgs, ed_sigs)
+        out[ed_idx] = res[: len(ed_idx)]
+        if groups is not None and len(groups.member):
+            # a validator's verdict from its run of lanes: all of them.  The
+            # runs lie end to end up to the columns' end and none is empty
+            # (a flattened member has k >= 1 lanes), which is what reduceat
+            # needs to read start[i]:start[i + 1] as member i's lanes
+            with trace.span("multisig.reduce", groups=len(groups.member)):
+                assert groups.lanes.min() >= 1 and len(res) == (
+                    groups.start[-1] + groups.lanes[-1]
+                )
+                out[groups.member] = np.logical_and.reduceat(res, groups.start)
     if sk_items:
         res = verifier.verify_secp256k1(sk_items)
         for j, i in enumerate(sk_idx):
@@ -909,34 +925,41 @@ def _verify_generic(pubkeys, msgs, sigs, verifier, sp) -> np.ndarray:
     return out
 
 
-def _flatten_multisig(pubkeys, msgs, sigs, ms_idx, ed_items, out) -> List[tuple]:
-    """Every multisig member of one call: unmarshal, the size rules, the walk
-    over flagged bits and one ``SigItem`` a flagged sub-signature, appended
-    to ``ed_items``.  Returns the groups (result index, start in ed_items,
-    lanes).  A member whose signature cannot be flattened (structurally
-    invalid, a sub-key that is not ed25519, fewer flagged signers than k)
-    is decided here by the host's ``verify_bytes`` (usually False) and
-    written to ``out``."""
-    groups: List[tuple] = []
-    host_decided = 0
-    first = len(ed_items)
+def _verify_ed25519_columns(verifier, pubs, msgs, sigs) -> np.ndarray:
+    """One ed25519 dispatch from three columns.  A verifier without the
+    column form (fakes in tests) gets ``SigItem``s."""
+    raw = getattr(verifier, "verify_ed25519_raw", None)
+    if raw is not None:
+        return np.asarray(raw(pubs, msgs, sigs), dtype=bool)
+    items = [SigItem(p, m, s) for p, m, s in zip(pubs, msgs, sigs)]
+    return np.asarray(verifier.verify_ed25519(items), dtype=bool)
+
+
+def _flatten_multisig(pubkeys, msgs, sigs, ms_idx, ed_pubs, ed_msgs, ed_sigs, out):
+    """Every multisig member of one call through ``multisig.flatten_columns``:
+    one lane a flagged sub-signature appended to the three ed25519 columns,
+    read from the marshalled bytes in place, and the members' (result index,
+    first lane, lanes) as the ``FlatGroups`` returned.  A member whose
+    signature cannot be flattened (structurally invalid, a flagged sub-key
+    that is not ed25519, fewer flagged signers than k) is decided here by
+    the host's ``verify_bytes`` (usually False) and written to ``out``."""
+    from tendermint_tpu.crypto.multisig import flatten_columns
+
+    first = len(ed_pubs)
     with trace.span("multisig.flatten", validators=len(ms_idx)) as sp:
-        for i in ms_idx:
-            pk = pubkeys[i]
-            flat = pk.flatten(msgs[i], sigs[i])
-            if flat is None or len(flat) < pk.k:
-                host_decided += 1
-                out[i] = pk.verify_bytes(msgs[i], sigs[i])
-                continue
-            groups.append((i, len(ed_items), len(flat)))
-            ed_items.extend(SigItem(p, m, s) for p, m, s in flat)
-        sp.set(lanes=len(ed_items) - first, host_decided=host_decided)
+        groups = flatten_columns(
+            pubkeys, msgs, sigs, ms_idx, ed_pubs, ed_msgs, ed_sigs
+        )
+        for i in groups.host:
+            out[i] = pubkeys[i].verify_bytes(msgs[i], sigs[i])
+        lanes = len(ed_pubs) - first
+        sp.set(lanes=lanes, host_decided=len(groups.host))
     try:
         m = get_verify_metrics()
-        if host_decided:
-            m.host_fallback.add(float(host_decided), ("multisig_structural",))
-        m.multisig_groups.add(float(len(groups)))
-        m.multisig_lanes.add(float(len(ed_items) - first))
+        if groups.host:
+            m.host_fallback.add(float(len(groups.host)), ("multisig_structural",))
+        m.multisig_groups.add(float(len(groups.member)))
+        m.multisig_lanes.add(float(lanes))
     except Exception:
         pass
     return groups

@@ -4,18 +4,30 @@ Mirrors reference crypto/multisig/threshold_pubkey.go:34 (VerifyBytes walks the
 sub-signatures in pubkey order, guided by a compact bit array) and
 crypto/multisig/bitarray/compact_bit_array.go.
 
-TPU note: a multisig verify over a batch of validators decomposes into the same
-flat (pubkey, msg, sig) tensor the ed25519 batch kernel consumes; Multisignature
-provides `flatten()` for that path (BASELINE.json configs[4]).
+Two readers of one marshalled signature live here, on purpose apart:
+
+* ``PubKeyMultisigThreshold.verify_bytes`` is the host oracle: it unmarshals
+  into a ``Multisignature`` and walks it as the Go does.
+* ``flatten_columns`` is the batch path (BASELINE.json configs[4]): a multisig
+  verify over a batch of validators decomposes into the same three columns
+  (32-byte key, message, 64-byte signature) the ed25519 batch kernel consumes.
+  It reads the marshalled bytes in place and appends every flagged
+  sub-signature of every member as one lane to the caller's columns, with the
+  (result index, start, count) of each member's run of lanes; it builds no
+  ``Multisignature``, ``CompactBitArray`` or tuple a lane.  A member it cannot
+  flatten goes to the host list, which ``verify_bytes`` decides.
+  ``PubKeyMultisigThreshold.flatten`` is its one-member case.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
+
+import numpy as np
 
 from tendermint_tpu.crypto.hashing import tmhash_truncated
-from tendermint_tpu.crypto.keys import PubKey
+from tendermint_tpu.crypto.keys import PubKey, PubKeyEd25519
 
 
 class CompactBitArray:
@@ -62,10 +74,10 @@ class CompactBitArray:
         if len(data) < 4:
             raise ValueError("bit array: truncated")
         bits = int.from_bytes(data[:4], "big")
+        if len(data) - 4 < (bits + 7) // 8:  # before bits/8 bytes are made
+            raise ValueError("bit array: truncated")
         ba = CompactBitArray(bits)
         ba.elems = bytearray(data[4 : 4 + (bits + 7) // 8])
-        if len(ba.elems) != (bits + 7) // 8:
-            raise ValueError("bit array: truncated")
         return ba
 
 
@@ -137,6 +149,19 @@ class PubKeyMultisigThreshold(PubKey):
             raise ValueError("threshold k must be positive")
         if len(self.pubkeys) < self.k:
             raise ValueError("threshold k cannot exceed number of keys")
+        # what flatten_columns reads of a key, made once: the signature's
+        # first four bytes (bits == n), the bit array's length and the mask
+        # of its last byte's real bits, and each sub-key's 32 bytes (None:
+        # not ed25519, so a signature that flags it is the host's)
+        n = len(self.pubkeys)
+        subkeys = tuple(
+            pk.bytes() if pk.type_name == PubKeyEd25519.type_name else None
+            for pk in self.pubkeys
+        )
+        object.__setattr__(self, "_flat", (
+            self.k, n.to_bytes(4, "big"), (n + 7) // 8,
+            0xFF & (0xFF << (-n % 8)), subkeys,
+        ))
 
     def address(self) -> bytes:
         return tmhash_truncated(self.bytes())
@@ -213,36 +238,133 @@ class PubKeyMultisigThreshold(PubKey):
     def flatten(
         self, msg: bytes, sig: bytes
     ) -> Optional[List[Tuple[bytes, bytes, bytes]]]:
-        """Decompose into (pubkey32, msg, sig64) tuples for the TPU batch path.
-        Returns None if structurally invalid or any sub-key is not ed25519."""
-        try:
-            multisig = Multisignature.unmarshal(sig)
-        except Exception:
+        """Decompose into (pubkey32, msg, sig64) tuples for the TPU batch path:
+        ``flatten_columns`` over this one member.  Returns None where that
+        leaves the member to the host (structurally invalid, a flagged
+        sub-key that is not ed25519, fewer flagged signers than k)."""
+        pubs: List[bytes] = []
+        lane_msgs: List[bytes] = []
+        lane_sigs: List[bytes] = []
+        groups = flatten_columns(
+            (self,), (msg,), (sig,), (0,), pubs, lane_msgs, lane_sigs
+        )
+        if groups.host:
             return None
-        if multisig.bitarray.bits != len(self.pubkeys):
-            return None
-        if not self.k <= len(multisig.sigs) <= len(self.pubkeys):
-            return None  # verify_bytes' bound (threshold_pubkey.go:46)
-        if multisig.bitarray.count() > len(multisig.sigs):
-            return None  # mirrors verify_bytes' out-of-range rejection
-        out = []
-        sig_index = 0
-        for i in range(len(self.pubkeys)):
-            if multisig.bitarray.get_index(i):
-                pk = self.pubkeys[i]
-                if pk.type_name != "tendermint/PubKeyEd25519":
-                    return None
-                if sig_index >= len(multisig.sigs):
-                    return None
-                sub = multisig.sigs[sig_index]
-                if len(sub) != 64:
-                    # unmarshal accepts any sub-sig length; a short one would
-                    # crash the whole batched dispatch downstream (frombuffer
-                    # reshape) — bail to the host path, which returns False
-                    return None
-                out.append((pk.bytes(), msg, sub))
-                sig_index += 1
-        return out
+        return list(zip(pubs, lane_msgs, lane_sigs))
 
     def __hash__(self):
         return hash((self.k, self.pubkeys))
+
+
+# -- the batch path: lanes in column form -------------------------------------
+
+# the set bits of one bit-array byte, most significant first
+# (compact_bit_array.go GetIndex: bit i is elems[i >> 3] & (1 << (7 - i % 8)))
+_SET_BITS = tuple(
+    tuple(i for i in range(8) if byte & (0x80 >> i)) for byte in range(256)
+)
+
+
+class FlatGroups(NamedTuple):
+    """The members ``flatten_columns`` flattened, one entry each in three
+    integer arrays, and those it left to the host."""
+
+    member: np.ndarray  # the member's place in the call (its result index)
+    start: np.ndarray  # its first lane's place in the columns
+    lanes: np.ndarray  # its lanes, k..n, contiguous from ``start``
+    host: List[int]  # members for ``verify_bytes``, by place in the call
+
+
+def flatten_columns(
+    pubkeys: Sequence["PubKeyMultisigThreshold"],
+    msgs: Sequence[bytes],
+    sigs: Sequence[bytes],
+    members: Sequence[int],
+    pubs: List[bytes],
+    lane_msgs: List[bytes],
+    lane_sigs: List[bytes],
+) -> FlatGroups:
+    """Every multisig member of one call, ``pubkeys[i]`` / ``msgs[i]`` /
+    ``sigs[i]`` for ``i`` in ``members``, read in place from the marshalled
+    signature
+
+        bits:u32be  ceil(bits/8) bytes  count:u16be  count x (len:u16be sig)
+
+    A member that can ride the ed25519 batch gets one lane a flagged
+    sub-signature, in key order, APPENDED to the caller's three columns
+    ``pubs`` (32-byte sub-key), ``lane_msgs`` and ``lane_sigs`` (64 bytes),
+    and its (place, first lane, lanes) in the arrays returned.  Any other goes
+    on ``host``: its verdict is ``verify_bytes``', which stays the
+    independent reading of the same rules (threshold_pubkey.go:34-60).  A
+    member flattens only if
+
+    * the bytes are what ``Multisignature.marshal`` writes: nothing short of
+      what a length field announces, nothing left over;
+    * ``bits`` is the key's n, and k <= ``count`` <= n (:41, :46);
+    * the flagged bits (pad bits of the last byte are not bits) number at
+      least k (:50) and at most ``count`` (the Go would index out of range);
+    * every flagged sub-key is ed25519 and its sub-signature 64 bytes long.
+
+    Signatures no bit points to are parsed and otherwise not looked at, as
+    the Go never looks at them.
+    """
+    member: List[int] = []
+    start: List[int] = []
+    lanes: List[int] = []
+    host: List[int] = []
+    set_bits = _SET_BITS
+    for i in members:
+        k, head, nbytes, pad, subkeys = pubkeys[i]._flat
+        sig = sigs[i]
+        at = 4 + nbytes  # of the 2-byte count
+        if len(sig) < at + 2 or sig[:4] != head:
+            host.append(i)
+            continue
+        flagged = [
+            8 * j + b for j in range(nbytes - 1) for b in set_bits[sig[4 + j]]
+        ]
+        flagged += [8 * (nbytes - 1) + b for b in set_bits[sig[at - 1] & pad]]
+        nsigs = (sig[at] << 8) | sig[at + 1]
+        if not (k <= len(flagged) <= nsigs <= len(subkeys)):
+            host.append(i)
+            continue
+        subsigs = _walk_subsignatures(sig, at + 2, nsigs, len(flagged))
+        keys = [subkeys[b] for b in flagged]
+        if subsigs is None or None in keys:  # None: a sub-key not ed25519
+            host.append(i)
+            continue
+        member.append(i)
+        start.append(len(pubs))
+        lanes.append(len(keys))
+        pubs += keys
+        lane_msgs += [msgs[i]] * len(keys)
+        lane_sigs += subsigs
+    return FlatGroups(
+        np.array(member, dtype=np.intp),
+        np.array(start, dtype=np.intp),
+        np.array(lanes, dtype=np.intp),
+        host,
+    )
+
+
+def _walk_subsignatures(
+    sig: bytes, at: int, nsigs: int, lanes: int
+) -> Optional[List[bytes]]:
+    """The first ``lanes`` of the ``nsigs`` length-prefixed sub-signatures
+    from ``sig[at:]``, or None: one of them is not 64 bytes long, a length
+    field announces more than is there, or bytes are left over."""
+    size = len(sig)
+    out = []
+    for j in range(nsigs):
+        if at + 2 > size:
+            return None
+        ln = (sig[at] << 8) | sig[at + 1]
+        at += 2
+        if at + ln > size:
+            return None
+        if j < lanes:
+            if ln != 64:
+                return None
+            out.append(sig[at : at + 64])
+        at += ln
+    return out if at == size else None
