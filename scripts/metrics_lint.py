@@ -316,6 +316,8 @@ def _self_check():
     vm.ed25519_pack.add(1.0, ("uniform",))
     # a chain whose validator set changes: cut windows, applied changes,
     # whole-cache clears (blockchain/reactor, ops/ed25519_pallas)
+    # a block's decode and hand-over to the pool (blockchain/reactor.receive)
+    vm.block_intake_seconds.observe(0.0003)
     vm.window_cut.add(1.0, ("valset_change",))
     vm.valset_changes.add(1.0)
     vm.valset_cache_clears.add(1.0, ("device",))
@@ -479,6 +481,8 @@ def _self_check():
         "tendermint_verify_valset_cache_total",
         "tendermint_verify_sync_ticks_total",
         "tendermint_verify_ed25519_pack_total",
+        # the third consumer of the interpreter in a fast sync: block intake
+        "tendermint_verify_block_intake_seconds",
         # fast sync over a changing validator set
         "tendermint_verify_window_cut_total",
         "tendermint_verify_valset_changes_total",

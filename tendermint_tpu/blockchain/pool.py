@@ -24,6 +24,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
+from tendermint_tpu.libs import trace
 from tendermint_tpu.libs.service import BaseService
 
 REQUEST_WINDOW = 128  # in-flight heights (ref: maxTotalRequesters 600)
@@ -188,6 +189,16 @@ class BlockPool(BaseService):
     # -- scheduler ---------------------------------------------------------------
     def _scheduler(self) -> None:
         while not self._quit.is_set():
+            self._schedule_pass()
+            self._quit.wait(0.01)
+
+    def _schedule_pass(self) -> None:
+        """One pass: spawn the window's requesters, assign and retry, then
+        send.  One ``pool.schedule`` span a pass that sent or failed
+        something (an idle pass, 100 a second, takes its span back); where a
+        peer answers inside ``request_cb``, as an in-process one does, the
+        span holds the blocks' decode and intake too."""
+        with trace.span("pool.schedule") as sp:
             sends: List[tuple] = []
             errors: List[tuple] = []
             now = time.monotonic()
@@ -229,7 +240,10 @@ class BlockPool(BaseService):
                     self._error_cb(peer_id, reason)
                 except Exception:
                     self.logger.exception("error_cb failed")
-            self._quit.wait(0.01)
+            if sends or errors:
+                sp.set(sends=len(sends), errors=len(errors))
+            else:
+                sp.drop()
 
     def _pick_peer(self, height: int) -> Optional[_PoolPeer]:
         cands = [

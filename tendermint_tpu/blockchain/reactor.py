@@ -227,6 +227,9 @@ class BlockchainReactor(Reactor):
         self.blocks_synced = 0
         self._trusted_commit_heights: set = set()
         self._switched = threading.Event()
+        # when the pool routine last asked for statuses and last looked
+        # whether it has caught up (monotonic seconds)
+        self._last_status = self._last_switch_check = 0.0
         # pipelined speculative verify (SURVEY §2.4): while the apply loop
         # walks window N, windows N+1..N+k verify on daemon worker threads
         # — the device wait releases the GIL, so verify and apply genuinely
@@ -306,6 +309,7 @@ class BlockchainReactor(Reactor):
         self.pool.remove_peer(peer.id)
 
     def receive(self, chan_id: int, peer, msg_bytes: bytes) -> None:
+        t0 = time.perf_counter()
         msg = unmarshal_msg(msg_bytes)
         if isinstance(msg, BlockRequestMessage):
             block = self.store.load_block(msg.height)
@@ -317,6 +321,10 @@ class BlockchainReactor(Reactor):
                 )
         elif isinstance(msg, BlockResponseMessage):
             self.pool.add_block(peer.id, msg.block)
+            # a block's decode and its way into the pool, on whichever
+            # thread received it: per block, so a histogram and no span
+            get_verify_metrics().block_intake_seconds.observe(
+                time.perf_counter() - t0)
         elif isinstance(msg, NoBlockResponseMessage):
             self.pool.no_block(peer.id, msg.height)
         elif isinstance(msg, StatusRequestMessage):
@@ -346,24 +354,37 @@ class BlockchainReactor(Reactor):
     # -- the sync loop ---------------------------------------------------------------
     def _pool_routine(self) -> None:
         """reactor.go:216 poolRoutine — with windowed verify→apply."""
-        last_status = 0.0
-        last_switch_check = 0.0
-        while not self._quit.is_set():
+        self._last_status = self._last_switch_check = 0.0
+        while not self._quit.is_set() and self._sync_cycle():
+            pass
+
+    def _sync_cycle(self) -> bool:
+        """One turn of the pool routine: the status and switch checks, one
+        look (``_try_sync_window``) and the wait after it.  False: the loop
+        is over (switched to consensus, or halted).
+
+        One ``fastsync.cycle`` tree a turn whose look did something.  An
+        empty look takes its span back: a node idling at the tip looks 100
+        times a second, and ``sync_ticks_total{result="empty"}`` counts
+        those."""
+        with trace.span("fastsync.cycle") as cycle:
             now = time.monotonic()
-            if now - last_status > STATUS_UPDATE_INTERVAL:
-                last_status = now
+            if now - self._last_status > STATUS_UPDATE_INTERVAL:
+                self._last_status = now
                 if self.switch is not None:
                     self.switch.broadcast(
                         BLOCKCHAIN_CHANNEL,
                         encode_msg(StatusRequestMessage(self.store.height())),
                     )
-            if now - last_switch_check > SWITCH_TO_CONSENSUS_INTERVAL:
-                last_switch_check = now
+            if now - self._last_switch_check > SWITCH_TO_CONSENSUS_INTERVAL:
+                self._last_switch_check = now
                 if self.pool.is_caught_up() and self.pool.num_peers() > 0:
+                    cycle.drop()
                     self._switch_to_consensus()
-                    return
+                    return False
+            result, n = "error", 0
             try:
-                self._try_sync_window()
+                result, n = self._try_sync_window()
             except FatalSyncError:
                 self.logger.error(
                     "FATAL: fast sync halted — verified block failed to "
@@ -374,10 +395,18 @@ class BlockchainReactor(Reactor):
                     self.pool.stop()
                 except Exception:
                     pass
-                return
+                cycle.set(result="error")
+                return False
             except Exception:
                 self.logger.exception("fast sync window failed")
-            self._quit.wait(TRY_SYNC_INTERVAL)
+            if result == "empty":
+                cycle.drop()
+                self._quit.wait(TRY_SYNC_INTERVAL)
+            else:
+                cycle.set(result=result, n=n)
+                with trace.span("fastsync.tick", after=result):
+                    self._quit.wait(TRY_SYNC_INTERVAL)
+        return True
 
     @property
     def verify_window(self) -> int:
@@ -431,25 +460,30 @@ class BlockchainReactor(Reactor):
         valset the head promised."""
         if not self._spec:
             return None
-        head = self._spec.pop(0)
-        first_h, vhash, fut, parts_list, blocks = head
-        if first_h != self.pool.height or self.state.validators.hash() != vhash:
-            rest, self._spec = self._spec, []
-            self._discard_speculation(
-                [head] + rest,
-                "height" if first_h != self.pool.height else "valset_change")
-            return None
-        # how long the apply loop stood waiting for the bc-verify worker
-        with trace.span("fastsync.harvest", h0=first_h, hit=False) as sp:
-            try:
-                n_ok, err = fut.result()
-            except CancelledError:
-                # on_stop cancelled the slot from another thread mid-harvest
-                get_verify_metrics().speculative.add(1.0, ("miss",))
+        # its own time: the pop and the two comparisons before the harvest
+        with trace.span("fastsync.take", slots=len(self._spec)):
+            head = self._spec.pop(0)
+            first_h, vhash, fut, parts_list, blocks = head
+            if (first_h != self.pool.height
+                    or self.state.validators.hash() != vhash):
+                rest, self._spec = self._spec, []
+                self._discard_speculation(
+                    [head] + rest,
+                    "height" if first_h != self.pool.height
+                    else "valset_change")
                 return None
-            sp.set(hit=True)
-        get_verify_metrics().speculative.add(1.0, ("hit",))
-        return blocks, parts_list, n_ok, err
+            # how long the apply loop stood waiting for the bc-verify worker
+            with trace.span("fastsync.harvest", h0=first_h, hit=False) as sp:
+                try:
+                    n_ok, err = fut.result()
+                except CancelledError:
+                    # on_stop cancelled the slot from another thread
+                    # mid-harvest
+                    get_verify_metrics().speculative.add(1.0, ("miss",))
+                    return None
+                sp.set(hit=True)
+            get_verify_metrics().speculative.add(1.0, ("hit",))
+            return blocks, parts_list, n_ok, err
 
     def _start_speculative(self, offset: int) -> None:
         """Top the speculation chain up to depth while window N applies.
@@ -499,18 +533,30 @@ class BlockchainReactor(Reactor):
             self._spec.append(
                 (nxt[0].height, st.validators.hash(), fut, parts_list, nxt))
 
-    def _try_sync_window(self) -> None:
+    def _try_sync_window(self) -> Tuple[str, int]:
+        """One look.  Returns what it did, for ``fastsync.cycle``: ``harvest``
+        or ``window`` and the heights applied; ``discard`` for a look that
+        threw a speculation away and then found under two blocks; ``empty``
+        for one that did nothing at all (and so recorded no span)."""
         ticks = get_verify_metrics().sync_ticks
+        had_spec = bool(self._spec)
         spec = self._take_speculative()
         if spec is not None:
             ticks.add(1.0, ("harvest",))
+            result = "harvest"
             blocks, parts_list, n_ok, err = spec
         else:
-            blocks = self.pool.peek_window(self.verify_window + 1)
-            if len(blocks) < 2:
-                ticks.add(1.0, ("empty",))
-                return
+            # the walk under the pool's mutex, which the scheduler and
+            # add_block also take
+            with trace.span("fastsync.peek") as sp:
+                blocks = self.pool.peek_window(self.verify_window + 1)
+                sp.set(n=len(blocks) - 1)
+                if len(blocks) < 2:
+                    sp.drop()
+                    ticks.add(1.0, ("empty",))
+                    return ("discard" if had_spec else "empty"), 0
             ticks.add(1.0, ("window",))
+            result = "window"
             parts_list = []
             with trace.span(
                 "fastsync.window", h0=blocks[0].height, n=len(blocks) - 1,
@@ -536,13 +582,23 @@ class BlockchainReactor(Reactor):
                     self._stop_peer_by_id(peer_id, f"sent bad block {h}")
         elif n_ok > 0:
             # pipeline: verify window N+1 on the worker while the loop
-            # below applies window N (its device wait releases the GIL)
-            self._start_speculative(offset=n_ok)
+            # below applies window N (its device wait releases the GIL).
+            # The span: what the loop pays before it can begin applying (the
+            # peek under the pool's mutex, the snapshot, a thread start a slot)
+            with trace.span("fastsync.speculate") as sp:
+                before = len(self._spec)
+                self._start_speculative(offset=n_ok)
+                sp.set(started=len(self._spec) - before)
         # apply the verified prefix
-        if n_ok == 0:
-            return
-        with trace.span("fastsync.apply", h0=blocks[0].height, n=n_ok):
-            self._apply_verified(blocks, parts_list, n_ok)
+        if n_ok > 0:
+            with trace.span("fastsync.apply", h0=blocks[0].height, n=n_ok):
+                self._apply_verified(blocks, parts_list, n_ok)
+        # the window's blocks and part sets die here (the stores kept their
+        # bytes): freeing a hundred blocks' objects is a millisecond or two,
+        # which would otherwise be the cycle's own time at this return
+        with trace.span("fastsync.release", n=len(blocks) - 1):
+            del blocks, parts_list, spec
+        return result, n_ok
 
     def _note_valset_change(self, height: int, old, new) -> None:
         """One span and one count a block whose apply changed the set that

@@ -472,6 +472,18 @@ class VerifyMetrics:
             "line), harvest (took a speculation), empty (under two blocks)",
             label_names=("result",),
         )
+        # a block's way in (blockchain/reactor.receive): the decode of one
+        # BlockResponseMessage and pool.add_block, on whichever thread
+        # received it.  Beside the verify and apply loops it is the third
+        # consumer of the interpreter during a fast sync
+        self.block_intake_seconds = r.histogram(
+            "verify_block_intake_seconds",
+            "Fast-sync block intake wall seconds a block: unmarshal of one "
+            "BlockResponseMessage and its hand-over to the pool, on the "
+            "thread that received it",
+            buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                     0.025, 0.1, 0.5, 2.5),
+        )
         # a chain whose validator set changes (blockchain/reactor): where
         # each verify_block_window call stopped collecting heights, how
         # often an applied block changed the set, and how often the Pallas

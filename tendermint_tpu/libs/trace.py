@@ -33,7 +33,18 @@ in the tree through a handle:
     trace.adopt(handle)                 # spans opened here are its children
 
 Args known only at exit go through the span: ``with trace.span(..) as sp:
-... sp.set(hit=True)``.
+... sp.set(hit=True)``.  A span that turns out to have covered nothing worth
+a record (a look of a polling loop that found no work) is taken back with
+``sp.drop()`` before it exits: it is then never written to the ring.
+
+CPU time.  A recorded span also carries ``cpu_ms``: the CPU time of ITS
+thread between entry and exit (``time.thread_time_ns``, taken inside the
+wall-clock pair, so ``cpu_ms <= dur`` up to the clocks' grain).  ``dur -
+cpu_ms`` is the time the thread was off the CPU inside the span: waiting for
+the interpreter lock or the scheduler in a span of plain Python, and the wait
+itself in a span drawn round one (a sleep, a join, a blocking device read).
+Another thread's work under the span never counts.  The clock is read only
+on the enabled path.
 
 Rule for call sites: a span is per call, per dispatch or per window — never
 per block, per lane or per signature — and its kwargs are O(1) to compute
@@ -50,6 +61,7 @@ import time
 from typing import List, Optional
 
 _now_ns = time.perf_counter_ns
+_thread_ns = time.thread_time_ns  # this thread's CPU time; enabled path only
 _ids = itertools.count(1)  # next() is atomic under the GIL
 
 DEFAULT_CAPACITY = 8192
@@ -69,6 +81,9 @@ class _NoopSpan:
     def set(self, **args) -> None:
         pass
 
+    def drop(self) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
@@ -76,13 +91,14 @@ _NOOP = _NoopSpan()
 class _Span:
     """One open span; also the handle ``current()`` hands to other threads."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0",
+    __slots__ = ("_tracer", "name", "args", "_t0", "_c0", "_dropped",
                  "span_id", "parent_id", "root_id")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._dropped = False
 
     def __enter__(self) -> "_Span":
         stack = self._tracer._stack()
@@ -96,21 +112,32 @@ class _Span:
             self.root_id = self.span_id
         stack.append(self)
         self._t0 = _now_ns()
+        self._c0 = _thread_ns()  # inside the wall pair: cpu_ms <= dur
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        c1 = _thread_ns()
         t1 = _now_ns()
         stack = self._tracer._stack()
         if stack and stack[-1] is self:  # not so after an adopt() under it
             stack.pop()
+        if self._dropped:
+            return False
         self.args.update(span_id=self.span_id, parent_id=self.parent_id,
-                         root_id=self.root_id)
+                         root_id=self.root_id,
+                         cpu_ms=round((c1 - self._c0) / 1e6, 3))
         self._tracer.record(self.name, self._t0, t1, self.args)
         return False
 
     def set(self, **args) -> None:
         """Args known only at exit (a verdict, a count)."""
         self.args.update(args)
+
+    def drop(self) -> None:
+        """Never record this span (it covered nothing: an empty look).  Its
+        children, if any were recorded, would be left without a parent, so
+        drop only what opened none."""
+        self._dropped = True
 
 
 class Tracer:

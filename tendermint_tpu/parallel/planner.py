@@ -947,7 +947,10 @@ def execute_plan(
         use_device = mesh is not None
     if use_device and plan.all_ed25519():
         return _execute_device_guarded(plan, mesh=mesh, verifier=verifier)
-    return _execute_host(plan, verifier=verifier)
+    # its own time: the lane loop before verify.generic and the segment
+    # tallies after it (the device paths draw planner.dispatch instead)
+    with trace.span("planner.execute", lanes=plan.n_lanes, H=plan.H):
+        return _execute_host(plan, verifier=verifier)
 
 
 def verify_window(

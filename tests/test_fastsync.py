@@ -246,6 +246,26 @@ class TestBlockPool:
         finally:
             pool.stop()
 
+    def test_a_pass_is_spanned_only_if_it_sent_or_failed_something(self, tracing):
+        """An idle pass (100 a second on a node at the tip) records nothing;
+        a sending one draws one ``pool.schedule`` with its ``sends``."""
+        requests = []
+        pool = BlockPool(
+            start_height=1, request_cb=lambda h, p: requests.append((h, p)),
+            error_cb=lambda p, r: None)  # not started: passes made by hand
+        for _ in range(50):
+            pool._schedule_pass()  # no peer, nothing to ask for
+        assert requests == [] and len(tracing.get_tracer()) == 0
+        pool.set_peer_height("peerA", 7)
+        pool._schedule_pass()
+        assert sorted(h for h, _ in requests) == list(range(1, 8))
+        for _ in range(50):
+            pool._schedule_pass()  # all seven are in flight: idle again
+        (ev,) = [e for e in tracing.export() if e.get("ph") == "X"]
+        assert ev["name"] == "pool.schedule"
+        assert (ev["args"]["sends"], ev["args"]["errors"]) == (7, 0)
+        assert ev["args"]["parent_id"] is None and "cpu_ms" in ev["args"]
+
     def test_unsolicited_block_rejected(self):
         pool, requests, _ = self._pool()
         try:
@@ -592,6 +612,122 @@ class TestPipelinedVerify:
         # per window, never per block: 11 blocks applied, a handful of spans
         assert len([e for e in spans if e["name"] == "fastsync.precheck"]) == 3
         assert len(spans) <= 3 * 16
+
+    def test_one_cycle_tree_a_look_that_did_something(
+            self, tracing, verify_counters, monkeypatch):
+        """``_sync_cycle`` (a turn of the pool routine) draws one
+        ``fastsync.cycle`` a non-empty look, with its ``fastsync.tick``, and
+        the loop's own spans of that look are its descendants."""
+        from tendermint_tpu.blockchain import reactor as reactor_mod
+
+        monkeypatch.setattr(reactor_mod, "TRY_SYNC_INTERVAL", 0.0)
+        fx = build_chain(n_vals=4, n_heights=12, chain_id="cycle-chain")
+        bc, store = self._direct_reactor(fx, window=4, verifier=self._AcceptAll())
+        tracing.reset()
+        for _ in range(5):  # 1..4 in line, 5..8 and 9..11 harvested, 2 empty
+            assert bc._sync_cycle() is True
+        assert store.height() == fx.height - 1
+        bc.on_stop()
+        spans = [e for e in tracing.export() if e.get("ph") == "X"]
+        by_id = {e["args"]["span_id"]: e for e in spans}
+        cycles = [e for e in spans if e["name"] == "fastsync.cycle"]
+        assert [(c["args"]["result"], c["args"]["n"]) for c in cycles] == [
+            ("window", 4), ("harvest", 4), ("harvest", 3)]
+        assert all(c["args"]["parent_id"] is None for c in cycles)
+
+        def children(parent, name=None):
+            return [e for e in spans
+                    if e["args"]["parent_id"] == parent["args"]["span_id"]
+                    and name in (None, e["name"])]
+
+        for c in cycles:
+            (tick,) = children(c, "fastsync.tick")
+            assert tick["args"]["after"] == c["args"]["result"]
+            (apply_,) = children(c, "fastsync.apply")
+            assert apply_["args"]["n"] == c["args"]["n"]
+            (release,) = children(c, "fastsync.release")
+            assert apply_["ts"] + apply_["dur"] <= release["ts"] <= tick["ts"]
+            # whatever this thread drew inside the look belongs to its cycle
+            assert all(e["args"]["root_id"] == c["args"]["span_id"]
+                       for e in spans if e["tid"] == c["tid"]
+                       and c["ts"] <= e["ts"] < c["ts"] + c["dur"])
+        first, second, third = cycles
+        (peek,) = children(first, "fastsync.peek")
+        assert peek["args"]["n"] == 4
+        (window,) = children(first, "fastsync.window")
+        assert window["args"]["mode"] == "sync" and not children(first, "fastsync.take")
+        for c, h0 in ((second, 5), (third, 9)):
+            (take,) = children(c, "fastsync.take")
+            (harvest,) = children(take, "fastsync.harvest")
+            assert harvest["args"]["h0"] == h0 and not children(c, "fastsync.peek")
+        # the first two looks start the next speculation, the last finds none
+        assert [children(c, "fastsync.speculate")[0]["args"]["started"]
+                for c in cycles] == [1, 1, 0]
+        # the speculative windows stay roots on their own thread
+        spec = [e for e in spans if e["name"] == "fastsync.window"
+                and e["args"]["mode"] == "speculative"]
+        assert len(spec) == 2 and all(e["args"]["parent_id"] is None for e in spec)
+        assert {e["tid"] for e in spec}.isdisjoint({c["tid"] for c in cycles})
+        # nothing else is a root on the loop's thread: every span has a
+        # recorded parent or is a cycle or a speculative window
+        assert all(e["args"]["parent_id"] in by_id or e in cycles or e in spec
+                   for e in spans)
+
+    def test_empty_looks_record_nothing_and_are_counted(
+            self, tracing, verify_counters, monkeypatch):
+        from tendermint_tpu.blockchain import reactor as reactor_mod
+
+        monkeypatch.setattr(reactor_mod, "TRY_SYNC_INTERVAL", 0.0)
+        fx = build_chain(n_vals=4, n_heights=3, chain_id="idle-chain")
+        bc, _store = self._direct_reactor(fx, window=4, verifier=self._AcceptAll())
+        bc.pool._requests.clear()  # a node at the tip: nothing ready
+        tracing.reset()
+        family = ("tendermint_verify_sync_ticks_total", {"result": "empty"})
+        before = verify_counters(*family)
+        for _ in range(200):
+            assert bc._sync_cycle() is True
+        assert verify_counters(*family) - before == 200
+        assert len(tracing.get_tracer()) == 0 and tracing.dropped() == 0
+
+    def test_the_loop_with_tracing_off_builds_no_span_and_reads_no_cpu_clock(
+            self, no_tracing, monkeypatch):
+        from tendermint_tpu.blockchain import reactor as reactor_mod
+
+        monkeypatch.setattr(reactor_mod, "TRY_SYNC_INTERVAL", 0.0)
+        fx = build_chain(n_vals=4, n_heights=12, chain_id="off-loop")
+        bc, store = self._direct_reactor(fx, window=4, verifier=self._AcceptAll())
+        for _ in range(5):
+            assert bc._sync_cycle() is True
+        bc.pool._schedule_pass()
+        assert store.height() == fx.height - 1
+        bc.on_stop()
+
+    @pytest.mark.parametrize("traced", [False, True])
+    def test_block_intake_is_observed_once_a_block_received(
+            self, traced, verify_counters, request):
+        """``tendermint_verify_block_intake_seconds``: one observation a
+        ``BlockResponseMessage`` in ``receive``, tracing on or off, and none
+        for any other message."""
+        from tendermint_tpu.blockchain.messages import (
+            BlockResponseMessage, StatusResponseMessage, encode_msg)
+
+        if traced:
+            request.getfixturevalue("tracing")
+        fx = build_chain(n_vals=4, n_heights=6, chain_id="intake-chain")
+        bc, _store = self._direct_reactor(fx, window=4, verifier=self._AcceptAll())
+        bc.pool._requests.clear()
+
+        class Peer:
+            id = "peerA"
+
+        family = "tendermint_verify_block_intake_seconds"
+        before = (verify_counters(family + "_count"), verify_counters(family + "_sum"))
+        bc.receive(0x40, Peer(), encode_msg(StatusResponseMessage(6)))
+        for h in range(1, 6):
+            bc.receive(0x40, Peer(), encode_msg(
+                BlockResponseMessage(fx.block_store.load_block(h))))
+        assert verify_counters(family + "_count") - before[0] == 5
+        assert verify_counters(family + "_sum") > before[1]
 
     def test_discarded_speculation_is_spanned(self, tracing):
         import base64

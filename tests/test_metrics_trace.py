@@ -335,26 +335,6 @@ def _by_name(events):
 
 
 @pytest.fixture
-def no_tracing(monkeypatch):
-    """Tracing off, and any attempt to build a span or to touch a tracer's
-    thread-local stack fails the test."""
-    class Untouchable:
-        def __getattr__(self, name):
-            raise AssertionError(f"thread-local read: {name}")
-
-        def __setattr__(self, name, value):
-            raise AssertionError(f"thread-local written: {name}")
-
-    def no_span(*a, **k):
-        raise AssertionError("a _Span was built with tracing off")
-
-    assert not trace_mod.enabled()
-    monkeypatch.setattr(trace_mod, "_Span", no_span)
-    monkeypatch.setattr(trace_mod.get_tracer(), "_tls", Untouchable())
-    return Untouchable
-
-
-@pytest.fixture
 def guarded_host():
     """The guard as a node runs it (deadline, worker thread, 5 % audit)
     around the host verifier standing in for the device."""
@@ -492,6 +472,69 @@ class TestSpanIdentity:
         assert merged["planner.pack"]["args"] == pack
 
 
+class TestSpanCpuTime:
+    """``cpu_ms``: the CPU time of the span's own thread, beside its wall."""
+
+    @staticmethod
+    def _burn(ms):
+        import time
+
+        c0 = time.thread_time_ns()
+        while time.thread_time_ns() - c0 < ms * 1e6:
+            sum(range(2000))
+
+    def _one(self, t, name):
+        (ev,) = [e for e in _spans(t.export()) if e["name"] == name]
+        return ev["dur"] / 1000.0, ev["args"]["cpu_ms"]
+
+    def test_a_busy_span_reads_its_cpu_time_and_no_more_than_its_wall(self):
+        t = Tracer(capacity=8)
+        t.enable()
+        with t.span("busy"):
+            self._burn(20)
+        dur_ms, cpu_ms = self._one(t, "busy")
+        assert isinstance(cpu_ms, float) and 20.0 <= cpu_ms <= dur_ms + 1.0
+
+    def test_a_span_that_only_waits_reads_next_to_nothing(self):
+        t = Tracer(capacity=8)
+        t.enable()
+        with t.span("wait"):
+            threading.Event().wait(0.03)
+        dur_ms, cpu_ms = self._one(t, "wait")
+        assert dur_ms >= 25.0 and 0.0 <= cpu_ms < 5.0
+
+    def test_another_threads_work_does_not_count(self):
+        t = Tracer(capacity=8)
+        t.enable()
+
+        def child():
+            with t.span("child"):
+                self._burn(30)
+
+        with t.span("parent"):
+            th = threading.Thread(target=child)
+            th.start()
+            th.join(30)
+            assert not th.is_alive()
+        _, child_cpu = self._one(t, "child")
+        parent_dur, parent_cpu = self._one(t, "parent")
+        assert child_cpu >= 30.0 and parent_dur >= 30.0
+        assert parent_cpu < 10.0  # it stood in join() while the child ran
+
+    def test_a_dropped_span_is_never_recorded_and_leaves_the_stack(self):
+        t = Tracer(capacity=8)
+        t.enable()
+        with t.span("kept") as kept:
+            with t.span("empty.look") as sp:
+                sp.drop()
+            with t.span("after"):
+                pass
+        ev = {e["name"]: e["args"] for e in _spans(t.export())}
+        assert sorted(ev) == ["after", "kept"] and len(t) == 2
+        assert ev["after"]["parent_id"] == kept.span_id
+        _NOOP.drop()  # the disabled path takes the same call
+
+
 class TestProgramSpans:
     """The spans the two served paths draw (names as PERF.md section 3)."""
 
@@ -554,10 +597,16 @@ class TestProgramSpans:
         counts = {n: len(v) for n, v in by.items()}
         assert counts == {
             "fastsync.window": 1, "fastsync.precheck": 1, "planner.pack": 1,
+            "planner.execute": 1,
             "verify.generic": 1, "guard.call": 1, "guard.submit": 1,
             "verify.dispatch": 1, "guard.audit": 1,
         }
         assert by["fastsync.precheck"][0]["args"]["n"] == 8
+        # the host path's lane loop and tally are planner.execute's own time
+        execute = by["planner.execute"][0]["args"]
+        assert (execute["lanes"], execute["H"]) == (32, 8)
+        assert execute["parent_id"] == by["fastsync.window"][0]["args"]["span_id"]
+        assert by["verify.generic"][0]["args"]["parent_id"] == execute["span_id"]
         root = by["fastsync.window"][0]["args"]["span_id"]
         assert {e["args"]["root_id"] for e in events} == {root}
 
