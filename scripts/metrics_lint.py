@@ -314,6 +314,8 @@ def _self_check():
     vm.device_audit.add(1.0, ("mismatch",))
     # how a Pallas ed25519 call packed its lanes (ops/ed25519_pallas)
     vm.ed25519_pack.add(1.0, ("uniform",))
+    # the form a verify_commit's lanes went down in (types/validator_set)
+    vm.commit_collect.add(1.0, ("columns",))
     # a chain whose validator set changes: cut windows, applied changes,
     # whole-cache clears (blockchain/reactor, ops/ed25519_pallas)
     # a block's decode and hand-over to the pool (blockchain/reactor.receive)
@@ -481,6 +483,7 @@ def _self_check():
         "tendermint_verify_valset_cache_total",
         "tendermint_verify_sync_ticks_total",
         "tendermint_verify_ed25519_pack_total",
+        "tendermint_verify_commit_collect_total",
         # the third consumer of the interpreter in a fast sync: block intake
         "tendermint_verify_block_intake_seconds",
         # fast sync over a changing validator set

@@ -24,10 +24,12 @@ Accept/reject is bit-exact across backends (tests/test_ops_ed25519.py).
 
 from __future__ import annotations
 
+import hashlib
 import logging
 import math
 import os
 import random
+import struct
 import threading
 import time
 from typing import List, NamedTuple, Optional, Sequence
@@ -122,10 +124,35 @@ class SigItem(NamedTuple):
     sig: bytes
 
 
+def valset_key(keys: np.ndarray) -> bytes:
+    """What the Pallas path's two valset caches (ops/ed25519_pallas) know an
+    (n, 32) key array by.  THE definition: a caller that keeps a key array
+    (``ValidatorSet``'s membership columns) takes its identity here once and
+    hands it down with the array as ``valset_key=``, and the kernel's host
+    wrapper takes it here where none came."""
+    return hashlib.sha256(np.ascontiguousarray(keys)).digest()
+
+
+def _byte_rows(col):
+    """A column's rows as ``bytes``: the rows of a 2-D uint8 array, or the
+    list it already is."""
+    if not isinstance(col, np.ndarray):
+        return col
+    data, w = col.tobytes(), col.shape[1]
+    if not w:
+        return [b""] * len(col)
+    return [data[i:i + w] for i in range(0, len(data), w)]
+
+
 class HostBatchVerifier:
     """Serial host verification — the oracle backend."""
 
     name = "host"
+    # verify_ed25519_raw takes each column as a list of ``bytes`` or as the
+    # uint8 array a caller already holds ((n, 32), (n, ln), (n, 64)), and
+    # the key array's ``valset_key`` with them: verify_ed25519_columns asks
+    # before it hands arrays to a verifier
+    column_form = True
 
     def verify_ed25519(self, items: Sequence[SigItem]) -> np.ndarray:
         t0 = time.perf_counter()
@@ -138,11 +165,14 @@ class HostBatchVerifier:
         _record_dispatch("host", "ed25519", len(items), t0, ok)
         return ok
 
-    def verify_ed25519_raw(self, pubs, msgs, sigs) -> np.ndarray:
+    def verify_ed25519_raw(self, pubs, msgs, sigs,
+                           valset_key: Optional[bytes] = None) -> np.ndarray:
         """Parallel-sequence form of verify_ed25519 — the hot callers
         (verify_generic's homogeneous fast path) already hold the three
         columns, and building |window|x|valset| SigItems was a measured
-        slice of the fast-sync host ceiling."""
+        slice of the fast-sync host ceiling.  The oracle reads ``bytes``, so
+        an array is cut into its rows; ``valset_key`` is the device's."""
+        pubs, msgs, sigs = map(_byte_rows, (pubs, msgs, sigs))
         t0 = time.perf_counter()
         verify = _ed.verify
         with trace.span("verify.dispatch", backend="host", algo="ed25519",
@@ -195,7 +225,9 @@ class RLCHostVerifier(HostBatchVerifier):
         _record_dispatch("host_rlc", "ed25519", len(items), t0, ok)
         return ok
 
-    def verify_ed25519_raw(self, pubs, msgs, sigs) -> np.ndarray:
+    def verify_ed25519_raw(self, pubs, msgs, sigs,
+                           valset_key: Optional[bytes] = None) -> np.ndarray:
+        pubs, msgs, sigs = map(_byte_rows, (pubs, msgs, sigs))
         t0 = time.perf_counter()
         with trace.span("verify.dispatch", backend="host_rlc",
                         algo="ed25519", n=len(pubs)):
@@ -229,6 +261,7 @@ class TPUBatchVerifier:
     """
 
     name = "tpu"
+    column_form = True  # as HostBatchVerifier's
 
     def __init__(self, mesh=None, backend: Optional[str] = None,
                  ed25519_path: Optional[str] = None):
@@ -285,35 +318,36 @@ class TPUBatchVerifier:
             [it.sig for it in items],
         )
 
-    def verify_ed25519_raw(self, pubs, msgs, sigs) -> np.ndarray:
-        """Column form of verify_ed25519 (see HostBatchVerifier's note)."""
+    def verify_ed25519_raw(self, pubs, msgs, sigs,
+                           valset_key: Optional[bytes] = None) -> np.ndarray:
+        """Column form of verify_ed25519 (see HostBatchVerifier's note).
+        Each column is a list of ``bytes`` or the array a caller already
+        holds; ``valset_key`` is ``valset_key(pubs)`` where the caller keeps
+        it with the keys."""
         if len(pubs) == 0:
             return np.zeros((0,), dtype=bool)
         t0 = time.perf_counter()
         first = "ed25519" not in self._warm
         with trace.span("verify.dispatch", backend=self.backend,
                         algo="ed25519", n=len(pubs)):
-            pubs_a = np.frombuffer(b"".join(pubs), dtype=np.uint8).reshape(
-                len(pubs), 32
-            )
-            sigs_a = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(
-                len(sigs), 64
-            )
-            if self.backend == "pallas":
-                verify = (
-                    self._kernel.rlc_verify_batch
-                    if self.ed25519_path == "msm"
-                    else self._kernel.verify_batch
-                )
-                ok = verify(pubs_a, msgs, sigs_a)
-            elif self.ed25519_path == "msm":
-                # the MSM folds the window into one point equation — there
-                # is no lane axis to shard, so the mesh is not consulted
-                ok = self._kernel.rlc_verify_batch(pubs_a, msgs, sigs_a)
-            else:
+            pubs_a = pubs if isinstance(pubs, np.ndarray) else np.frombuffer(
+                b"".join(pubs), dtype=np.uint8).reshape(len(pubs), 32)
+            sigs_a = sigs if isinstance(sigs, np.ndarray) else np.frombuffer(
+                b"".join(sigs), dtype=np.uint8).reshape(len(sigs), 64)
+            if self.backend == "pallas" and self.ed25519_path != "msm":
                 ok = self._kernel.verify_batch(
-                    pubs_a, msgs, sigs_a, mesh=self._mesh,
-                )
+                    pubs_a, msgs, sigs_a, valset_key=valset_key)
+            else:
+                # only the Pallas ladder reads a message matrix in place
+                msgs = _byte_rows(msgs)
+                if self.ed25519_path == "msm":
+                    # the MSM folds the window into one point equation:
+                    # there is no lane axis to shard, so no mesh either
+                    ok = self._kernel.rlc_verify_batch(pubs_a, msgs, sigs_a)
+                else:
+                    ok = self._kernel.verify_batch(
+                        pubs_a, msgs, sigs_a, mesh=self._mesh,
+                    )
         ok = np.asarray(ok, dtype=bool)
         self._warm.add("ed25519")
         _record_dispatch(self.backend, "ed25519", len(pubs), t0, ok,
@@ -355,6 +389,21 @@ class TPUBatchVerifier:
 
 def _item_rows(items, lanes) -> list:
     return [(items[i].pubkey, items[i].msg, items[i].sig) for i in lanes]
+
+
+def _column_rows(pubs, msgs, sigs, lanes) -> list:
+    """The sampled lanes' (pubkey, msg, sig) as ``bytes``, from columns that
+    are lists or arrays: the oracle workers get the same rows either way."""
+    cols = (pubs, msgs, sigs)
+    if not all(isinstance(c, np.ndarray) for c in cols):
+        pubs, msgs, sigs = map(_byte_rows, cols)
+        return [(pubs[i], msgs[i], sigs[i]) for i in lanes]
+    # the sampled rows side by side, cut into three ``bytes`` a lane in one
+    # pass (1,500 slices made one by one cost three times as much)
+    idx = np.asarray(lanes)
+    cut = np.concatenate([c[idx] for c in cols], axis=1)
+    fmt = "".join(f"{c.shape[1]}s" for c in cols)
+    return list(struct.iter_unpack(fmt, cut.tobytes()))
 
 
 def _deadline_at(deadline, t0=None) -> Optional[float]:
@@ -433,12 +482,22 @@ class GuardedBatchVerifier:
             lambda lanes: _item_rows(items, lanes),
         )
 
-    def verify_ed25519_raw(self, pubs, msgs, sigs) -> np.ndarray:
+    @property
+    def column_form(self) -> bool:
+        """Whether the columns may come as arrays: the device's say."""
+        return getattr(self.device, "column_form", False)
+
+    def verify_ed25519_raw(self, pubs, msgs, sigs,
+                           valset_key: Optional[bytes] = None) -> np.ndarray:
+        # a device that does not say column_form (a foreign fake) is handed
+        # what it was before this parameter: three lists, no key
+        kw = {"valset_key": valset_key} if self.column_form else {}
         return self._guard(
             "ed25519", len(pubs),
-            lambda: self.device.verify_ed25519_raw(pubs, msgs, sigs),
-            lambda: self.host.verify_ed25519_raw(pubs, msgs, sigs),
-            lambda lanes: [(pubs[i], msgs[i], sigs[i]) for i in lanes],
+            lambda: self.device.verify_ed25519_raw(pubs, msgs, sigs, **kw),
+            lambda: self.host.verify_ed25519_raw(
+                *map(_byte_rows, (pubs, msgs, sigs))),
+            lambda lanes: _column_rows(pubs, msgs, sigs, lanes),
         )
 
     def verify_secp256k1(self, items: Sequence[SigItem]) -> np.ndarray:
@@ -866,7 +925,7 @@ def _verify_generic(pubkeys, msgs, sigs, verifier, sp) -> np.ndarray:
         len(s) == 64 for s in sigs
     ):
         sp.set(keys="ed25519")
-        return _verify_ed25519_columns(
+        return _dispatch_ed25519_rows(
             verifier, [pk.bytes() for pk in pubkeys], msgs, sigs
         )
     sp.set(keys="mixed")
@@ -906,7 +965,7 @@ def _verify_generic(pubkeys, msgs, sigs, verifier, sp) -> np.ndarray:
             pubkeys, msgs, sigs, ms_idx, ed_pubs, ed_msgs, ed_sigs, out
         )
     if ed_pubs:
-        res = _verify_ed25519_columns(verifier, ed_pubs, ed_msgs, ed_sigs)
+        res = _dispatch_ed25519_rows(verifier, ed_pubs, ed_msgs, ed_sigs)
         out[ed_idx] = res[: len(ed_idx)]
         if groups is not None and len(groups.member):
             # a validator's verdict from its run of lanes: all of them.  The
@@ -925,9 +984,32 @@ def _verify_generic(pubkeys, msgs, sigs, verifier, sp) -> np.ndarray:
     return out
 
 
-def _verify_ed25519_columns(verifier, pubs, msgs, sigs) -> np.ndarray:
-    """One ed25519 dispatch from three columns.  A verifier without the
-    column form (fakes in tests) gets ``SigItem``s."""
+def verify_ed25519_columns(
+    keys: np.ndarray, msgs: np.ndarray, sigs: np.ndarray, verifier=None,
+    valset_key: Optional[bytes] = None,
+) -> np.ndarray:
+    """verify_generic for a caller that holds an all-ed25519 batch as three
+    uint8 arrays, (n, 32) keys, (n, ln) messages, (n, 64) signatures: one
+    dispatch, no object a lane.  ``valset_key`` is ``valset_key(keys)``
+    where the caller keeps it with the keys.  A verifier that does not say
+    ``column_form`` (a fake in a test, a stand-in of the benchmark's) gets
+    the rows as lists of ``bytes``."""
+    with trace.span("verify.generic", n=len(keys), keys="ed25519"):
+        if verifier is None:
+            verifier = get_batch_verifier()
+        if getattr(verifier, "column_form", False):
+            return np.asarray(
+                verifier.verify_ed25519_raw(
+                    keys, msgs, sigs, valset_key=valset_key),
+                dtype=bool,
+            )
+        return _dispatch_ed25519_rows(
+            verifier, *map(_byte_rows, (keys, msgs, sigs)))
+
+
+def _dispatch_ed25519_rows(verifier, pubs, msgs, sigs) -> np.ndarray:
+    """One ed25519 dispatch from three lists of ``bytes``.  A verifier
+    without ``verify_ed25519_raw`` (fakes in tests) gets ``SigItem``s."""
     raw = getattr(verifier, "verify_ed25519_raw", None)
     if raw is not None:
         return np.asarray(raw(pubs, msgs, sigs), dtype=bool)

@@ -27,6 +27,10 @@ from tendermint_tpu.parallel.planner import LaneFeed
 class BatchingVerifier:
     """verify_generic-compatible verifier backed by a shared LaneFeed."""
 
+    # the feed takes rows of bytes; said here so that __getattr__ below does
+    # not answer with the default verifier's
+    column_form = False
+
     def __init__(self, feed: LaneFeed, result_timeout: Optional[float] = 60.0):
         self._feed = feed
         self._timeout = result_timeout

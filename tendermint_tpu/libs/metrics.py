@@ -443,6 +443,18 @@ class VerifyMetrics:
         )
         for path in ("uniform", "grouped"):  # both series from 0
             self.ed25519_pack.add(0.0, (path,))
+        # in which form ValidatorSet.verify_commit handed a commit's lanes
+        # to the verifier
+        self.commit_collect = r.counter(
+            "verify_commit_collect_total",
+            "Calls of ValidatorSet.verify_commit by the form its lanes went "
+            "down in: columns (an all-ed25519 set: arrays from the set's "
+            "own key and power columns, no object a lane) | lists (any "
+            "other set, or a lane that fits no column: verify_generic)",
+            label_names=("form",),
+        )
+        for form in ("columns", "lists"):  # both series from 0
+            self.commit_collect.add(0.0, (form,))
         # secp256k1 lanes the host prologue (secp256k1_verify.prep_batch)
         # decided: they never reach the device, so the guard's audit, which
         # samples the dispatch's answer, sees the host's verdict for them

@@ -39,7 +39,6 @@ lookup plus byte packing.
 
 from __future__ import annotations
 
-import hashlib
 from functools import partial
 from typing import List, Optional, Sequence, Tuple
 
@@ -52,6 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tendermint_tpu.crypto import ed25519 as _ed
+from tendermint_tpu.crypto.batch import valset_key as _valset_key
 from tendermint_tpu.libs import trace
 from tendermint_tpu.libs.metrics import get_verify_metrics
 from tendermint_tpu.ops import ed25519_verify as _xla
@@ -756,11 +756,6 @@ _valset_cache: dict = {}
 _VALSET_CACHE_MAX = 64
 
 
-def _valset_key(pubs: np.ndarray) -> bytes:
-    """What both valset caches know a (N, 32) pubkey array by."""
-    return hashlib.sha256(np.ascontiguousarray(pubs)).digest()
-
-
 def _decompress_valset(
     pubs: np.ndarray, key: Optional[bytes] = None,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -846,28 +841,46 @@ def _bucket(n: int, lanes: int = LANES) -> int:
     return ((n + 2047) // 2048) * 2048
 
 
+def _message_matrix(msgs, n: int, ln: int) -> np.ndarray:
+    """n messages of ln bytes as an (n, ln) uint8 array: the caller's own
+    where it came as one, else the list joined."""
+    if isinstance(msgs, np.ndarray):
+        return msgs
+    if not ln:
+        return np.zeros((n, 0), np.uint8)
+    return np.frombuffer(b"".join(msgs), dtype=np.uint8).reshape(n, ln)
+
+
 def verify_batch(pubs: np.ndarray, msgs: Sequence[bytes], sigs: np.ndarray,
                  interpret: bool = False,
-                 carry_mode: str = "lazy") -> np.ndarray:
+                 carry_mode: str = "lazy",
+                 valset_key: Optional[bytes] = None) -> np.ndarray:
     """Go-exact batched verify on the Pallas path, on the default jax
     device. Same contract as ops.ed25519_verify.verify_batch.
     `carry_mode` picks the eager or deferred (lazy) carry schedule — both
-    bit-exact at the canonical boundary."""
+    bit-exact at the canonical boundary.  ``msgs`` may be an (n, ln) uint8
+    array (one length, known from its shape); ``valset_key`` is
+    ``_valset_key(pubs)`` where the caller keeps it with the keys."""
     carry_mode = _fc.normalize_carry_mode(carry_mode)
     pubs = np.ascontiguousarray(pubs, dtype=np.uint8)
     sigs = np.ascontiguousarray(sigs, dtype=np.uint8)
     n = pubs.shape[0]
     if n == 0:
         return np.zeros((0,), dtype=bool)
+    if isinstance(msgs, np.ndarray):
+        if msgs.ndim != 2 or msgs.shape[0] != n:
+            raise ValueError(f"messages {msgs.shape} for {n} keys")
+        msgs = np.ascontiguousarray(msgs, dtype=np.uint8)
 
     # valset limbs and the length scan, before any launch: the host work that
     # is not packing.  One length (a commit, a sync window) goes down as the
     # caller's own columns; several are regrouped, one launch a length
     with trace.span("dispatch.prepare", n=n) as sp:
-        key = _valset_key(pubs)
+        key = _valset_key(pubs) if valset_key is None else valset_key
         neg_ax, ay, valid = _decompress_valset(pubs, key)
         valid = valid & ((sigs[:, 63] & 224) == 0)  # Go's only s range check
-        lengths = set(map(len, msgs))
+        lengths = ({msgs.shape[1]} if isinstance(msgs, np.ndarray)
+                   else set(map(len, msgs)))
         uniform = len(lengths) == 1 and len(msgs) == n
         groups = []
         if not uniform:
@@ -996,15 +1009,13 @@ def pack_variable_words(pubs, msgs, sigs, ln: int, b: int):
     values at those rows. Pure numpy (shared by _verify_uniform and the
     bench's device-resident re-dispatch timing).
 
-    Only the bytes of the varying rows are gathered, from the joined
-    messages; no lane's padded input is built but row 0's."""
+    Only the bytes of the varying rows are gathered, from the messages as
+    one matrix (the caller's own, or the list joined); no lane's padded
+    input is built but row 0's."""
     n = pubs.shape[0]
     total = 64 + ln
     nblocks = (total + 1 + 16 + 127) // 128
-    m = (
-        np.frombuffer(b"".join(msgs), dtype=np.uint8).reshape(n, ln)
-        if ln else np.zeros((n, 0), np.uint8)
-    )
+    m = _message_matrix(msgs, n, ln)
     # the padded input past the message, the same in every lane: 0x80,
     # zeros, the 16-byte bit length
     tail = np.zeros((nblocks * 128 - total,), dtype=np.uint8)
@@ -1086,9 +1097,7 @@ def _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln, interpret,
     padded = np.zeros((b, nblocks * 128), dtype=np.uint8)
     padded[:n, :32] = sigs[:, :32]
     padded[:n, 32:64] = pubs
-    if ln:
-        m = np.frombuffer(b"".join(msgs), dtype=np.uint8).reshape(n, ln)
-        padded[:n, 64:total] = m
+    padded[:n, 64:total] = _message_matrix(msgs, n, ln)
     padded[:, total] = 0x80
     padded[:, -16:] = np.frombuffer((total * 8).to_bytes(16, "big"), np.uint8)
     # big-endian 32-bit words

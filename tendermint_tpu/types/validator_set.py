@@ -14,13 +14,20 @@ from __future__ import annotations
 import heapq
 import struct as _struct
 from dataclasses import dataclass, field, replace
-from typing import Callable, Iterable, List, Optional, Sequence, Tuple
+from typing import Callable, Iterable, List, NamedTuple, Optional, Sequence, Tuple
+
+import numpy as np
 
 from tendermint_tpu.crypto import merkle
-from tendermint_tpu.crypto.batch import verify_generic
-from tendermint_tpu.crypto.keys import PubKey
+from tendermint_tpu.crypto.batch import (
+    valset_key,
+    verify_ed25519_columns,
+    verify_generic,
+)
+from tendermint_tpu.crypto.keys import PubKey, PubKeyEd25519
 from tendermint_tpu.encoding.codec import Reader, Writer
 from tendermint_tpu.libs import trace
+from tendermint_tpu.libs.metrics import get_verify_metrics
 from tendermint_tpu.types.core import (
     BlockID,
     SignedMsgType,
@@ -29,6 +36,11 @@ from tendermint_tpu.types.core import (
 from tendermint_tpu.types.vote import Vote
 
 _MAX_TOTAL_POWER = 1 << 60  # clip bound (reference uses int64 overflow clips)
+
+# a canonical vote's fixed64 timestamp: behind uvarint(type) = 1 byte,
+# fixed64(height) and fixed64(round)
+_TS_AT = 17
+_PACK_TS = _struct.Struct("<q").pack
 
 
 def _clip(v: int) -> int:
@@ -74,6 +86,26 @@ class Validator:
         return w.build()
 
 
+class _MemberColumns(NamedTuple):
+    """An all-ed25519 membership as verify_commit's columns: row i is
+    validators[i].  Read-only; they depend on keys and powers alone."""
+
+    keys: np.ndarray  # (n, 32) uint8, the raw keys
+    powers: np.ndarray  # (n,) int64
+    key_id: bytes  # crypto.batch.valset_key(keys)
+
+
+class _Membership:
+    """What every set of one membership shares however often it is copied
+    or its accums advance: handed on by copy(), replaced by _invalidate().
+    ``columns`` is None until asked for, then a _MemberColumns, or False for
+    a membership that does not take the column form."""
+
+    __slots__ = ("columns",)
+
+    def __init__(self):
+        self.columns = None
+
 
 class ValidatorSet:
     """Sorted by address; proposer rotates by accumulated voting power."""
@@ -89,6 +121,7 @@ class ValidatorSet:
         self._mver = 0  # bumped on any accum/membership change
         self._marshal_cache: Optional[Tuple[int, bytes]] = None
         self._members_blob: Optional[bytes] = None  # encode()'s pubkey section
+        self._membership = _Membership()
         self._cow = False  # True => `validators` is shared with another set
         if vals:
             self.increment_accum(1)
@@ -116,7 +149,36 @@ class ValidatorSet:
         self._addresses = None
         self._hash = None
         self._members_blob = None
+        self._membership = _Membership()
         self._mver += 1
+
+    def _member_columns(self) -> Optional[_MemberColumns]:
+        """The membership's key and power columns, built once a membership
+        (never per commit, height or call); None unless every member's key
+        is a PubKeyEd25519 and the powers sum as int64."""
+        box = self._membership
+        if box.columns is None:
+            box.columns = self._build_columns()
+        return box.columns or None
+
+    def _build_columns(self):
+        vals = self.validators
+        if not (
+            vals
+            and all(type(v.pub_key) is PubKeyEd25519 for v in vals)
+            and min(v.voting_power for v in vals) >= 0
+            and self.total_voting_power() <= _MAX_TOTAL_POWER
+        ):
+            return False
+        keys = np.frombuffer(
+            b"".join(v.pub_key.bytes() for v in vals), dtype=np.uint8
+        ).reshape(len(vals), 32)
+        return _MemberColumns(
+            keys,
+            np.array([v.voting_power for v in vals], dtype=np.int64),
+            # taken here once a membership and not once a dispatch
+            valset_key(keys),
+        )
 
     # size / lookup --------------------------------------------------------
     @property
@@ -213,6 +275,7 @@ class ValidatorSet:
         new._addresses = self._addresses  # same membership (rebuilt-if-None)
         new._hash = self._hash  # membership identical; accum changes don't matter
         new._members_blob = self._members_blob
+        new._membership = self._membership
         new._mver = 0
         new._marshal_cache = (
             (0, self._marshal_cache[1])
@@ -267,17 +330,18 @@ class ValidatorSet:
         return self._hash
 
     # THE hot path ---------------------------------------------------------
-    def collect_commit_sigs(
-        self, chain_id: str, block_id: BlockID, height: int, commit
-    ) -> Tuple[List[PubKey], List[bytes], List[bytes], List[int]]:
-        """Structural checks + (pubkeys, msgs, sigs, powers) for every non-nil
-        precommit; powers[j] is 0 for precommits voting a different block.
-        The ONE place the per-precommit validity rules live — shared by the
-        single-commit path below and fast sync's windowed batch
-        (blockchain/reactor.verify_block_window). Raises CommitError."""
-        if self.size != len(commit.precommits):
+    def _scan_commit(self, block_id: BlockID, height: int, commit):
+        """Structural checks, and what the non-nil precommits carry.  The
+        ONE place the per-precommit validity rules live: collect_commit_sigs
+        and verify_commit's column form are both made from what it returns,
+        ``(round, absent, timestamps, sigs, strays)`` — the indices of the
+        nil precommits, a timestamp and a signature a present one, and
+        ``(j, block id)`` for the j-th present precommit where it votes
+        another block.  Raises CommitError."""
+        precommits = commit.precommits
+        if self.size != len(precommits):
             raise CommitError(
-                f"wrong set size: {self.size} vs {len(commit.precommits)}"
+                f"wrong set size: {self.size} vs {len(precommits)}"
             )
         if height != commit.height():
             raise CommitError(f"wrong height: {height} vs {commit.height()}")
@@ -285,56 +349,116 @@ class ValidatorSet:
             raise CommitError("wrong block id")
 
         round = commit.round()
-        # Canonical precommit sign-bytes differ across validators ONLY in the
-        # fixed64 timestamp at offset 17 (uvarint(type)=1 + fixed64(height)=8
-        # + fixed64(round)=8) — and in block_id for stray votes. Build one
-        # template per distinct block_id and patch timestamps instead of
-        # re-encoding ~110 bytes per precommit (the sign-bytes assembly was
-        # a top host cost of fast sync; ref loop types/validator_set.go:281).
-        # The overwhelmingly common case is every precommit voting block_id,
-        # so that template is prebuilt and picked by ONE equality test per
-        # precommit (a dict keyed by BlockID pays a multi-field hash each
-        # probe); the same test decides power attribution.
-        main_tpl = canonical_vote_sign_bytes(
-            chain_id, SignedMsgType.PRECOMMIT, height, round, 0, block_id
-        )
-        main_head, main_tail = main_tpl[:17], main_tpl[25:]
-        stray_templates: Optional[dict] = None
-        _pack_ts = _struct.Struct("<q").pack
-        vals = self.validators
-        pubkeys, msgs, sigs, powers = [], [], [], []
-        for idx, precommit in enumerate(commit.precommits):
+        precommit_type = SignedMsgType.PRECOMMIT
+        # a precommit's block id against block_id: the same object (votes
+        # made in process share it) or, field by field, an equal one (every
+        # vote decoded from the wire carries its own); the dataclass __eq__
+        # builds four tuples a precommit to say the same
+        b_hash = block_id.hash
+        b_parts = block_id.parts_header
+        b_parts_hash, b_total = b_parts.hash, b_parts.total
+        absent, timestamps, sigs, strays = [], [], [], []
+        for idx, precommit in enumerate(precommits):
             if precommit is None:
+                absent.append(idx)
                 continue
             if precommit.height != height:
                 raise CommitError(f"precommit height {precommit.height} != {height}")
             if precommit.round != round:
                 raise CommitError(f"precommit round {precommit.round} != {round}")
-            if precommit.vote_type != SignedMsgType.PRECOMMIT:
+            if precommit.vote_type != precommit_type:
                 raise CommitError(f"not a precommit @ index {idx}")
-            val = vals[idx]
-            pubkeys.append(val.pub_key)
             key = precommit.block_id
-            if key == block_id:
-                msgs.append(
-                    main_head + _pack_ts(precommit.timestamp_ns) + main_tail
-                )
-                powers.append(val.voting_power)
-            else:  # stray vote: counts for availability, not power
-                if stray_templates is None:
-                    stray_templates = {}
-                tpl = stray_templates.get(key)
-                if tpl is None:
-                    tpl = canonical_vote_sign_bytes(
-                        chain_id, SignedMsgType.PRECOMMIT, height, round, 0, key
-                    )
-                    stray_templates[key] = tpl
-                msgs.append(
-                    tpl[:17] + _pack_ts(precommit.timestamp_ns) + tpl[25:]
-                )
-                powers.append(0)
+            if key is not block_id:
+                parts = key.parts_header
+                if (
+                    key.hash != b_hash
+                    or parts.hash != b_parts_hash
+                    or parts.total != b_total
+                ):  # stray vote: counts for availability, not power
+                    strays.append((len(sigs), key))
+            timestamps.append(precommit.timestamp_ns)
             sigs.append(precommit.signature)
+        return round, absent, timestamps, sigs, strays
+
+    def collect_commit_sigs(
+        self, chain_id: str, block_id: BlockID, height: int, commit
+    ) -> Tuple[List[PubKey], List[bytes], List[bytes], List[int]]:
+        """Structural checks + (pubkeys, msgs, sigs, powers) for every non-nil
+        precommit; powers[j] is 0 for precommits voting a different block.
+        Shared by the single-commit path below and fast sync's windowed batch
+        (blockchain/reactor.verify_block_window), whose 64-precommit calls
+        stay plain Python. Raises CommitError."""
+        return self._commit_lists(
+            chain_id, block_id, height,
+            self._scan_commit(block_id, height, commit),
+        )
+
+    def _commit_lists(self, chain_id, block_id, height, scan):
+        round, absent, timestamps, sigs, strays = scan
+        # Canonical precommit sign-bytes differ across validators ONLY in the
+        # fixed64 timestamp at offset 17 (uvarint(type)=1 + fixed64(height)=8
+        # + fixed64(round)=8) — and in block_id for stray votes. One template
+        # a distinct block_id, the timestamps patched in, instead of
+        # re-encoding ~110 bytes per precommit (the sign-bytes assembly was
+        # a top host cost of fast sync; ref loop types/validator_set.go:281).
+        tpl = canonical_vote_sign_bytes(
+            chain_id, SignedMsgType.PRECOMMIT, height, round, 0, block_id
+        )
+        head, tail = tpl[:_TS_AT], tpl[_TS_AT + 8:]
+        msgs = [head + ts + tail for ts in map(_PACK_TS, timestamps)]
+        vals = self.validators
+        if absent:
+            gone = set(absent)
+            vals = [v for i, v in enumerate(vals) if i not in gone]
+        pubkeys = [v.pub_key for v in vals]
+        powers = [v.voting_power for v in vals]
+        templates = {}
+        for j, key in strays:
+            tpl = templates.get(key)
+            if tpl is None:
+                tpl = templates[key] = canonical_vote_sign_bytes(
+                    chain_id, SignedMsgType.PRECOMMIT, height, round, 0, key
+                )
+            msgs[j] = tpl[:_TS_AT] + _PACK_TS(timestamps[j]) + tpl[_TS_AT + 8:]
+            powers[j] = 0
         return pubkeys, msgs, sigs, powers
+
+    def _commit_columns(self, chain_id, block_id, height, scan, members):
+        """The lanes of an all-ed25519 commit as columns, (keys (n, 32),
+        sign-bytes (n, ln), signatures (n, 64), powers (n,), the keys'
+        identity or None): the same lanes _commit_lists makes, with no
+        object a lane.  None where a lane does not fit a column (a signature
+        not 64 bytes, a stray vote whose sign-bytes have another length):
+        the lists decide such a commit."""
+        round, absent, timestamps, sigs, strays = scan
+        n = len(sigs)
+        if set(map(len, sigs)) != {64}:
+            return None
+        tpl = canonical_vote_sign_bytes(
+            chain_id, SignedMsgType.PRECOMMIT, height, round, 0, block_id
+        )
+        msgs = np.empty((n, len(tpl)), dtype=np.uint8)
+        msgs[:] = np.frombuffer(tpl, dtype=np.uint8)
+        keys, powers, key_id = members
+        if absent:
+            present = np.delete(np.arange(len(keys)), absent)
+            keys, powers, key_id = keys[present], powers[present], None
+        if strays:
+            powers = powers.copy()
+            for j, key in strays:
+                tpl = canonical_vote_sign_bytes(
+                    chain_id, SignedMsgType.PRECOMMIT, height, round, 0, key
+                )
+                if len(tpl) != msgs.shape[1]:
+                    return None
+                msgs[j] = np.frombuffer(tpl, dtype=np.uint8)
+                powers[j] = 0
+        msgs[:, _TS_AT:_TS_AT + 8] = np.frombuffer(
+            _struct.pack(f"<{n}q", *timestamps), dtype=np.uint8
+        ).reshape(n, 8)
+        sigs = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64)
+        return keys, msgs, sigs, powers, key_id
 
     def verify_commit(
         self, chain_id: str, block_id: BlockID, height: int, commit, verifier=None
@@ -342,23 +466,41 @@ class ValidatorSet:
         """Raise unless +2/3 of this set signed blockID at height.
 
         One BatchVerifier dispatch for all non-nil precommits (the reference
-        loops serially at validator_set.go:273-298)."""
+        loops serially at validator_set.go:273-298).  An all-ed25519 set's
+        lanes go down as columns (numpy arrays) whose keys and powers are
+        the membership's own; any other set's, and a commit with a lane
+        that fits no column, as lists through verify_generic."""
         with trace.span(
             "commit.verify", height=height, n=len(commit.precommits)
         ):
             # not inside collect_commit_sigs: fast sync calls that per block
             with trace.span("commit.collect", n=len(commit.precommits)):
-                pubkeys, msgs, sigs, powers = self.collect_commit_sigs(
-                    chain_id, block_id, height, commit
+                scan = self._scan_commit(block_id, height, commit)
+                members = self._member_columns()
+                columns = members and self._commit_columns(
+                    chain_id, block_id, height, scan, members
                 )
-            ok = verify_generic(pubkeys, msgs, sigs, verifier=verifier)
-            with trace.span("commit.tally", n=len(pubkeys)):
-                tallied = 0
-                for j in range(len(pubkeys)):
-                    if not ok[j]:
-                        raise CommitError("invalid signature in commit")
-                    tallied += powers[j]
-
+                if not columns:
+                    pubkeys, msgs, sigs, powers = self._commit_lists(
+                        chain_id, block_id, height, scan
+                    )
+            try:
+                get_verify_metrics().commit_collect.add(
+                    1.0, ("columns" if columns else "lists",)
+                )
+            except Exception:
+                pass
+            if columns:
+                keys, msgs, sigs, powers, key_id = columns
+                ok = verify_ed25519_columns(
+                    keys, msgs, sigs, verifier=verifier, valset_key=key_id
+                )
+            else:
+                ok = verify_generic(pubkeys, msgs, sigs, verifier=verifier)
+            with trace.span("commit.tally", n=len(ok)):
+                if not ok.all():
+                    raise CommitError("invalid signature in commit")
+                tallied = int(powers.sum()) if columns else sum(powers)
                 if tallied * 3 <= self.total_voting_power() * 2:
                     raise CommitError(
                         f"insufficient voting power: got {tallied}, "
@@ -482,6 +624,7 @@ class ValidatorSet:
         vs._mver = 0
         vs._marshal_cache = None
         vs._members_blob = members_blob
+        vs._membership = _Membership()
         vs._cow = False
         vs.proposer = vals[prop_idx] if 0 <= prop_idx < len(vals) else None
         return vs

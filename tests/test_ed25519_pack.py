@@ -15,6 +15,7 @@ dispatch hashes its keys once.
 import numpy as np
 import pytest
 
+from tendermint_tpu.crypto import batch
 from tendermint_tpu.ops import ed25519_pallas as ep
 
 
@@ -217,8 +218,9 @@ def test_signature_words_are_the_references(n, b, invalid):
 
 
 class _CountingHashlib:
-    """``hashlib`` as ops/ed25519_pallas sees it, counting the SHA-256
-    calls over a whole key array (>= 100 KB)."""
+    """``hashlib`` as ``crypto/batch.valset_key`` sees it (the one place a
+    key array's identity is taken; ops/ed25519_pallas calls it), counting
+    the SHA-256 calls over a whole key array (>= 100 KB)."""
 
     def __init__(self):
         import hashlib
@@ -256,7 +258,7 @@ def stood_in(monkeypatch):
     hashes = _CountingHashlib()
     monkeypatch.setattr(ep, "call_jit", fake_call_jit)
     monkeypatch.setattr(ep, "pack_variable_words", recording_pack)
-    monkeypatch.setattr(ep, "hashlib", hashes)
+    monkeypatch.setattr(batch, "hashlib", hashes)
     monkeypatch.setattr(ep, "_valset_cache", {})
     monkeypatch.setattr(ep, "_dev_valset_cache", {})
     return launches, packed, hashes
