@@ -18,6 +18,13 @@ against the host oracle:
                  equal the same replay under HostBatchVerifier; a second
                  pass with one seeded commit signature flipped stops at the
                  same height with the same error.
+  fast_sync_full the same replay over a chain that carries transactions
+                 (BENCHMARK.json's fastsync-64v-full, cut to 64 blocks):
+                 1,000 seeded txs of 250 bytes a block through the kvstore
+                 with the reference's Commit; the device replay equals the
+                 host's and ends at the app hash the rule gives by hand, and
+                 a block with one tx byte altered in its wire bytes is never
+                 applied.
   secp256k1      config 4: a 256-validator secp256k1 commit through
                  verify_commit / verify_generic -> ops/secp256k1_pallas.
   multisig       config 5, cut from 1,000 validators to 100: a commit of
@@ -66,16 +73,18 @@ SEED = 42
 N_VALIDATORS = 10_000
 CORRUPT_SHARE = 0.01
 FASTSYNC_BLOCKS, FASTSYNC_VALS, FASTSYNC_WINDOW = 2048, 64, 512
+FULL_BLOCKS, FULL_TXS, FULL_TX_BYTES = 64, 1000, 250
 SECP_VALIDATORS = 256
 MULTISIG_VALIDATORS, MULTISIG_K, MULTISIG_N = 100, 3, 5
 MSM_WINDOW = 512
 NODE_HEIGHT = 5
 BACKEND = "pallas"  # what every stage must have run on
 
-KERNELS_DEADLINE_S = 840.0
+KERNELS_DEADLINE_S = 1020.0
 NODE_DEADLINE_S = 240.0
 KERNEL_STAGES = (
-    "commit_verify", "fast_sync", "secp256k1", "multisig", "ed25519_msm")
+    "commit_verify", "fast_sync", "fast_sync_full", "secp256k1", "multisig",
+    "ed25519_msm")
 STAGE_PREFIX = "STAGE "
 
 NO_TPU_EXIT = 3
@@ -201,14 +210,14 @@ def stage_commit_verify(checks: dict) -> None:
     assert checks["rejected_host"] == len(lanes)
 
 
-def _replay(genesis, blocks, verifier):
+def _replay(genesis, blocks, verifier, **executor):
     """Windowed verify + apply, the way blockchain/reactor's sync loop does
     it.  Returns (height, app_hash, last block id, error)."""
     from tendermint_tpu.blockchain.reactor import verify_block_window
     from tendermint_tpu.testutil.chain import fresh_executor
     from tendermint_tpu.types import BlockID
 
-    st, block_exec = fresh_executor(genesis)
+    st, block_exec = fresh_executor(genesis, **executor)
     trusted, pos, err = set(), 0, None
     while pos < len(blocks) - 1 and err is None:
         window = blocks[pos : pos + FASTSYNC_WINDOW + 1]
@@ -273,6 +282,64 @@ def stage_fast_sync(checks: dict) -> None:
         "height": h_bad, "validator": j,
         "device": device_bad, "host": host_bad,
     }
+    assert device_bad == host_bad, "tampered replays differ"
+    assert host_bad[0] == h_bad - 1 and host_bad[3] is not None, host_bad
+
+
+def stage_fast_sync_full(checks: dict) -> None:
+    import random
+
+    from tendermint_tpu.abci.examples.kvstore import UpstreamKVStoreApp
+    from tendermint_tpu.blockchain.messages import (
+        BlockResponseMessage, encode_msg, unmarshal_msg)
+    from tendermint_tpu.crypto import batch
+    from tendermint_tpu.testutil.chain import build_chain
+
+    rng = random.Random(SEED)
+    half = (FULL_TX_BYTES - 1) // 2
+
+    def txs(_h, _st):  # hex key, '=', hex value; every key distinct
+        return [(rng.randbytes(half // 2).hex() + "="
+                 + rng.randbytes(half // 2 + 1).hex())[:FULL_TX_BYTES].encode()
+                for _ in range(FULL_TXS)]
+
+    device_default = batch.get_batch_verifier()
+    batch.set_batch_verifier(batch.HostBatchVerifier())
+    try:
+        fx = build_chain(
+            n_vals=FASTSYNC_VALS, n_heights=FULL_BLOCKS, chain_id="smoke-full",
+            app_factory=UpstreamKVStoreApp, on_height=txs)
+    finally:
+        batch.set_batch_verifier(device_default)
+    # as a peer's bytes, decoded: the blocks a sync applies
+    wire = [encode_msg(BlockResponseMessage(fx.block_store.load_block(h)))
+            for h in range(1, FULL_BLOCKS + 1)]
+    blocks = [unmarshal_msg(w).block for w in wire]
+    checks["block_bytes"] = len(wire[1])
+    assert all(len(b.data.txs) == FULL_TXS for b in blocks)
+    assert all(len(bytes(tx)) == FULL_TX_BYTES for tx in blocks[0].data.txs)
+
+    app = {"app_factory": UpstreamKVStoreApp}
+    host = _replay(fx.genesis, blocks, batch.HostBatchVerifier(), **app)
+    device = _replay(fx.genesis, blocks, None, **app)
+    checks["replay"] = {"device": device, "host": host}
+    assert device == host, "device replay differs from the host replay"
+    assert host[0] == FULL_BLOCKS - 1 and host[3] is None, host
+    # binary.PutVarint(make([]byte, 8), 63,000 txs), worked by hand
+    assert (FULL_BLOCKS - 1) * FULL_TXS == 63_000
+    assert host[1] == "b0d8070000000000", host[1]
+
+    # one byte of one tx altered in the bytes of a seeded block
+    # late enough that its window fills the lane bucket of the whole one
+    h_bad = rng.randrange(FULL_BLOCKS // 2 + 2, FULL_BLOCKS - 1)
+    raw = bytearray(wire[h_bad - 1])
+    tx = bytes(blocks[h_bad - 1].data.txs[rng.randrange(FULL_TXS)])
+    raw[raw.index(tx) + rng.randrange(FULL_TX_BYTES)] ^= 1 << rng.randrange(8)
+    tampered = list(blocks)
+    tampered[h_bad - 1] = unmarshal_msg(bytes(raw)).block
+    host_bad = _replay(fx.genesis, tampered, batch.HostBatchVerifier(), **app)
+    device_bad = _replay(fx.genesis, tampered, None, **app)
+    checks["tampered"] = {"height": h_bad, "device": device_bad, "host": host_bad}
     assert device_bad == host_bad, "tampered replays differ"
     assert host_bad[0] == h_bad - 1 and host_bad[3] is not None, host_bad
 
@@ -561,6 +628,7 @@ def kernels_main() -> int:
     stages = {
         "commit_verify": stage_commit_verify,
         "fast_sync": stage_fast_sync,
+        "fast_sync_full": stage_fast_sync_full,
         "secp256k1": stage_secp256k1,
         "multisig": stage_multisig,
         "ed25519_msm": stage_ed25519_msm,

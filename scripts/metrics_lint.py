@@ -320,6 +320,12 @@ def _self_check():
     # whole-cache clears (blockchain/reactor, ops/ed25519_pallas)
     # a block's decode and hand-over to the pool (blockchain/reactor.receive)
     vm.block_intake_seconds.observe(0.0003)
+    vm.block_intake_bytes.add(263052.0)
+    # a block's apply, stage by stage (state/execution, blockchain/reactor)
+    for stage in ("validate", "deliver", "save_responses", "update_state",
+                  "commit", "save_state", "save_block"):
+        vm.block_stage_seconds.observe(0.004, (stage,))
+    vm.txs_delivered.add(1000.0)
     vm.window_cut.add(1.0, ("valset_change",))
     vm.valset_changes.add(1.0)
     vm.valset_cache_clears.add(1.0, ("device",))
@@ -486,6 +492,11 @@ def _self_check():
         "tendermint_verify_commit_collect_total",
         # the third consumer of the interpreter in a fast sync: block intake
         "tendermint_verify_block_intake_seconds",
+        "tendermint_verify_block_intake_bytes_total",
+        # a block's apply by stage and the txs it delivered: process-wide
+        # beside the intake, shown under the state family's prefix
+        "tendermint_state_block_stage_seconds",
+        "tendermint_state_txs_delivered_total",
         # fast sync over a changing validator set
         "tendermint_verify_window_cut_total",
         "tendermint_verify_valset_changes_total",

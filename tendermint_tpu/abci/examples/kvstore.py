@@ -2,6 +2,8 @@
 persistent_kvstore.go, counter/counter.go).
 
   * KVStoreApp           — in-memory merkleized key=value store
+  * UpstreamKVStoreApp   — the same store with the reference's O(1) Commit
+    (app hash = the varint of the number of txs delivered)
   * PersistentKVStoreApp — + disk persistence and EndBlock validator-set
     changes via 'val:<pubkey_b64>!<power>' txs
   * CounterApp           — serial-number counter exercising CheckTx/DeliverTx
@@ -26,6 +28,7 @@ from typing import Dict, List, Optional
 
 from tendermint_tpu.abci import types as abci
 from tendermint_tpu.crypto import merkle
+from tendermint_tpu.encoding.codec import Writer
 
 VALIDATOR_TX_PREFIX = b"val:"
 
@@ -52,20 +55,17 @@ class KVStoreApp(abci.Application):
             last_block_app_hash=self._app_hash() if self.height else b"",
         )
 
-    def _apply(self, tx: bytes) -> None:
-        if b"=" in tx:
-            k, v = tx.split(b"=", 1)
-        else:
-            k = v = tx
+    def _apply(self, tx: bytes) -> bytes:
+        """Write the tx into the state; returns its key."""
+        k, sep, v = tx.partition(b"=")
+        if not sep:
+            v = k
         self.state[k] = v
         self.size += 1
+        return k
 
     def deliver_tx(self, req: abci.RequestDeliverTx) -> abci.ResponseDeliverTx:
-        self._apply(req.tx)
-        if b"=" in req.tx:
-            k, v = req.tx.split(b"=", 1)
-        else:
-            k = v = req.tx
+        k = self._apply(req.tx)
         return abci.ResponseDeliverTx(
             code=abci.CODE_TYPE_OK,
             tags=[
@@ -97,6 +97,34 @@ class KVStoreApp(abci.Application):
             # real policies override this)
             return abci.ResponseQuery(code=abci.CODE_TYPE_OK)
         return abci.ResponseQuery(code=1, log=f"unknown path {req.path}")
+
+
+APP_HASH_BYTES = 8
+
+
+def put_varint(size: int) -> bytes:
+    """Go's ``binary.PutVarint(buf, size)`` into ``make([]byte, 8)``: the
+    codec's signed varint (zigzag, seven bits a byte from the low end) at the
+    front, the rest of the buffer zero."""
+    buf = Writer().svarint(size).build()
+    if len(buf) > APP_HASH_BYTES:  # Go panics: index out of range
+        raise OverflowError(f"the varint of {size} does not fit the app hash")
+    return buf + bytes(APP_HASH_BYTES - len(buf))
+
+
+class UpstreamKVStoreApp(KVStoreApp):
+    """``KVStoreApp`` with v0.26.2's Commit and Info
+    (abci/example/kvstore/kvstore.go: "Using a memdb - just return the big
+    endian size of the db"): the app hash is the 8-byte buffer holding the
+    varint of ``size``, the count of delivered txs, so a Commit costs the
+    same however large the state has grown.  ``KVStoreApp`` walks and
+    hashes the whole sorted state at every Commit, which is this repo's own
+    rule and no chain's: a chain that carries transactions is synced
+    through this class.  DeliverTx, CheckTx, Query and the tags are
+    ``KVStoreApp``'s."""
+
+    def _app_hash(self) -> bytes:
+        return put_varint(self.size)
 
 
 PRIORITY_TX_PREFIX = b"pri"

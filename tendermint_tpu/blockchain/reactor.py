@@ -323,8 +323,9 @@ class BlockchainReactor(Reactor):
             self.pool.add_block(peer.id, msg.block)
             # a block's decode and its way into the pool, on whichever
             # thread received it: per block, so a histogram and no span
-            get_verify_metrics().block_intake_seconds.observe(
-                time.perf_counter() - t0)
+            vm = get_verify_metrics()
+            vm.block_intake_seconds.observe(time.perf_counter() - t0)
+            vm.block_intake_bytes.add(float(len(msg_bytes)))
         elif isinstance(msg, NoBlockResponseMessage):
             self.pool.no_block(peer.id, msg.height)
         elif isinstance(msg, StatusRequestMessage):
@@ -591,8 +592,11 @@ class BlockchainReactor(Reactor):
                 sp.set(started=len(self._spec) - before)
         # apply the verified prefix
         if n_ok > 0:
-            with trace.span("fastsync.apply", h0=blocks[0].height, n=n_ok):
-                self._apply_verified(blocks, parts_list, n_ok)
+            with trace.span(
+                "fastsync.apply", h0=blocks[0].height, n=n_ok
+            ) as sp:
+                txs, size = self._apply_verified(blocks, parts_list, n_ok)
+                sp.set(txs=txs, bytes=size)
         # the window's blocks and part sets die here (the stores kept their
         # bytes): freeing a hundred blocks' objects is a millisecond or two,
         # which would otherwise be the cycle's own time at this return
@@ -614,12 +618,20 @@ class BlockchainReactor(Reactor):
             )
         get_verify_metrics().valset_changes.add(1.0)
 
-    def _apply_verified(self, blocks, parts_list, n_ok: int) -> None:
+    def _apply_verified(self, blocks, parts_list, n_ok: int):
+        """Store and apply the verified prefix; returns the transactions and
+        the encoded bytes of the blocks applied (``fastsync.apply``'s args)."""
+        save_block_seconds = get_verify_metrics().block_stage_seconds.labels(
+            "save_block")
+        txs = size = 0
         for i in range(n_ok):
             block = blocks[i]
             parts = parts_list[i]
             block_id = BlockID(hash=block.hash(), parts_header=parts.header())
+            t0 = time.perf_counter()
             self.store.save_block(block, parts, blocks[i + 1].last_commit)
+            # the seventh stage of a block's apply; apply_block reads its six
+            save_block_seconds.observe(time.perf_counter() - t0)
             try:
                 # the first synced block's own LastCommit predates our
                 # batches — its membership check below is False, forcing
@@ -646,12 +658,15 @@ class BlockchainReactor(Reactor):
             self.state = new_state
             self.pool.pop_first()
             self.blocks_synced += 1
+            txs += len(block.data.txs)
+            size += len(block.marshal())  # a decoded block's wire buffer
             self._trusted_commit_heights.discard(block.height - 2)
             if self.blocks_synced % 100 == 0:
                 self.logger.info(
                     "fast sync at height %d (%d peers)",
                     self.pool.height, self.pool.num_peers(),
                 )
+        return txs, size
 
     def _switch_to_consensus(self) -> None:
         if self._switched.is_set():

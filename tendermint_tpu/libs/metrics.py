@@ -496,6 +496,33 @@ class VerifyMetrics:
             buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                      0.025, 0.1, 0.5, 2.5),
         )
+        self.block_intake_bytes = r.counter(
+            "verify_block_intake_bytes_total",
+            "Bytes of the BlockResponseMessages fast sync took in",
+        )
+        self.block_intake_bytes.add(0.0)  # exposed from 0
+        # a block's apply, stage by stage (state/execution.apply_block, and
+        # the block store's save in blockchain/reactor's apply loop): per
+        # block, so a histogram and no span, tracing on or off.  Process-wide
+        # beside the intake's family, so an executor built without a node's
+        # metrics is read too; a node's scrape shows it under the state
+        # family's prefix, beside state_block_processing_time
+        self.block_stage_seconds = r.histogram(
+            "state_block_stage_seconds",
+            "Wall seconds of one stage of one block's apply: validate | "
+            "deliver (BeginBlock, every DeliverTx, EndBlock) | save_responses "
+            "| update_state | commit (the app's Commit under the mempool's "
+            "lock, the evidence pool's update) | save_state | save_block "
+            "(the block store, in fast sync's apply loop)",
+            buckets=(0.00001, 0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025,
+                     0.005, 0.01, 0.025, 0.05, 0.1, 0.5, 2.5),
+            label_names=("stage",),
+        )
+        self.txs_delivered = r.counter(
+            "state_txs_delivered_total",
+            "Transactions delivered to the app by apply_block",
+        )
+        self.txs_delivered.add(0.0)  # exposed from 0
         # a chain whose validator set changes (blockchain/reactor): where
         # each verify_block_window call stopped collecting heights, how
         # often an applied block changed the set, and how often the Pallas

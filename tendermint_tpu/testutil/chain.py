@@ -140,13 +140,13 @@ def build_chain(
     )
 
 
-def fresh_executor(genesis: GenesisDoc):
+def fresh_executor(genesis: GenesisDoc, app_factory=KVStoreApp):
     """(genesis state, BlockExecutor over a fresh in-memory kvstore app) —
     the replay side of a fast-sync bench or smoke."""
     st = state_from_genesis(genesis)
     db = MemDB()
     sm_store.save_state(db, st)
-    conn = MultiAppConn(LocalClientCreator(KVStoreApp()))
+    conn = MultiAppConn(LocalClientCreator(app_factory()))
     conn.start()
     return st, BlockExecutor(db, conn.consensus)
 
