@@ -497,6 +497,7 @@ def _self_check():
         # beside the intake, shown under the state family's prefix
         "tendermint_state_block_stage_seconds",
         "tendermint_state_txs_delivered_total",
+        "tendermint_state_abci_responses_bytes_total",
         # fast sync over a changing validator set
         "tendermint_verify_window_cut_total",
         "tendermint_verify_valset_changes_total",

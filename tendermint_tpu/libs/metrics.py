@@ -523,6 +523,13 @@ class VerifyMetrics:
             "Transactions delivered to the app by apply_block",
         )
         self.txs_delivered.add(0.0)  # exposed from 0
+        self.abci_responses_bytes = r.counter(
+            "state_abci_responses_bytes_total",
+            "Bytes of the ABCIResponses records save_abci_responses put into "
+            "the state store; over state_block_stage_seconds_count"
+            '{stage="save_responses"} it is what the state DB grows by a block',
+        )
+        self.abci_responses_bytes.add(0.0)  # exposed from 0
         # a chain whose validator set changes (blockchain/reactor): where
         # each verify_block_window call stopped collecting heights, how
         # often an applied block changed the set, and how often the Pallas
