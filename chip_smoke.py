@@ -34,6 +34,12 @@ against the host oracle:
                  ed25519 dispatch and no validator is decided on the host;
                  then seeded bad sub-signatures through verify_generic,
                  each validator's verdict equal to its key's verify_bytes.
+  commit_absent  a live chain's commit (BENCHMARK.json's
+                 commit-ed25519-10k-live, cut to 1,000 validators): 300
+                 slots absent, 33 for nil, decoded from its wire bytes,
+                 through verify_commit: two message lengths regrouped once
+                 into two launches; every verdict equals the host's; the
+                 same commit with one more precommit for nil is refused.
   ed25519_msm    one 512-signature window with ed25519_path="msm" (the RLC
                  seed is a hash of the seeded content, so it is pinned).
   node           a live node through the CLI, no TM_BATCH_VERIFIER in its
@@ -47,7 +53,7 @@ device_fallback_total and host_fallback_total, zero audit mismatches and a
 closed breaker.  Seconds are printed as set-up facts, never recorded as
 performance numbers.
 
-One process per chip: this parent never imports jax.  The five kernel
+One process per chip: this parent never imports jax.  The kernel
 stages share one child; the CLI node is a second child started after the
 first has exited.  Without a TPU the first child says so and exits before
 any stage; the command takes no flag.
@@ -76,6 +82,7 @@ FASTSYNC_BLOCKS, FASTSYNC_VALS, FASTSYNC_WINDOW = 2048, 64, 512
 FULL_BLOCKS, FULL_TXS, FULL_TX_BYTES = 64, 1000, 250
 SECP_VALIDATORS = 256
 MULTISIG_VALIDATORS, MULTISIG_K, MULTISIG_N = 100, 3, 5
+ABSENT_VALIDATORS, ABSENT_ABSENT, ABSENT_NIL = 1000, 300, 33
 MSM_WINDOW = 512
 NODE_HEIGHT = 5
 BACKEND = "pallas"  # what every stage must have run on
@@ -84,7 +91,7 @@ KERNELS_DEADLINE_S = 1020.0
 NODE_DEADLINE_S = 240.0
 KERNEL_STAGES = (
     "commit_verify", "fast_sync", "fast_sync_full", "secp256k1", "multisig",
-    "ed25519_msm")
+    "commit_absent", "ed25519_msm")
 STAGE_PREFIX = "STAGE "
 
 NO_TPU_EXIT = 3
@@ -501,6 +508,92 @@ def stage_multisig(checks: dict) -> None:
     assert not want[bad].any() and checks["rejected_host"] == len(bad)
 
 
+def stage_commit_absent(checks: dict) -> None:
+    """A live chain's commit at the edge of its quorum, read from the wire:
+    of 1,000 slots 300 absent and 33 for nil, so 667 for the block.  What
+    the cell commit10k-absent asserts of every call in its window
+    (benchmark/drivers/commit_stream_absent.py), asserted here of one."""
+    import random
+
+    from tendermint_tpu.crypto import ed25519 as ed
+    from tendermint_tpu.crypto.batch import get_batch_verifier
+    from tendermint_tpu.crypto.keys import PubKeyEd25519
+    from tendermint_tpu.libs.metrics import get_verify_metrics
+    from tendermint_tpu.types import BlockID, PartSetHeader, SignedMsgType, Vote
+    from tendermint_tpu.types.block import Commit
+    from tendermint_tpu.types.validator_set import (
+        CommitError,
+        Validator,
+        ValidatorSet,
+    )
+
+    chain_id, height = "smoke-absent", 500
+    rng = random.Random(SEED + 3)
+    privs = {}
+    for _ in range(ABSENT_VALIDATORS):
+        priv = ed.gen_privkey(rng.randbytes(32))
+        privs[PubKeyEd25519(priv[32:]).address()] = priv
+    valset = ValidatorSet(
+        [Validator(PubKeyEd25519(p[32:]), 10) for p in privs.values()])
+    block_id = BlockID(b"\x77" * 32, PartSetHeader(1, b"\x88" * 32))
+
+    def precommit(idx, voted):
+        val = valset.validators[idx]
+        vote = Vote(
+            vote_type=SignedMsgType.PRECOMMIT, height=height, round=0,
+            timestamp_ns=1_700_000_000_000_000_000 + idx, block_id=voted,
+            validator_address=val.address, validator_index=idx,
+        )
+        return vote.with_signature(
+            ed.sign(privs[val.address], vote.sign_bytes(chain_id)))
+
+    order = rng.sample(range(ABSENT_VALIDATORS), ABSENT_VALIDATORS)
+    absent = set(order[:ABSENT_ABSENT])
+    nil = set(order[ABSENT_ABSENT:ABSENT_ABSENT + ABSENT_NIL])
+    votes = [None if i in absent
+             else precommit(i, BlockID() if i in nil else block_id)
+             for i in range(ABSENT_VALIDATORS)]
+    commit = Commit.unmarshal(Commit(block_id, votes).marshal())
+    assert commit.precommits[order[-1]].block_id is not commit.block_id
+
+    # the process default, chosen as a node chooses it; chosen here, because
+    # its selection self-test is a launch of its own
+    get_batch_verifier()
+    m = get_verify_metrics()
+    watched = (m.ed25519_pack, m.ed25519_launches, m.commit_precommits)
+    before = [c.snapshot() for c in watched]
+    valset.verify_commit(chain_id, block_id, height, commit)
+    checks["verify_commit_accepted"] = True
+    pack, launches, held = (
+        _delta(c.snapshot(), b) for c, b in zip(watched, before))
+    checks["valid_commit"] = {
+        "pack": {"/".join(k): v for k, v in pack.items()},
+        "launches": sum(launches.values()),
+        "held": {"/".join(k): v for k, v in held.items()},
+    }
+    assert pack == {("grouped",): 1}, f"packing of one commit: {pack}"
+    assert sum(launches.values()) == 2, launches
+    assert held == {
+        ("for_block",): ABSENT_VALIDATORS - ABSENT_ABSENT - ABSENT_NIL,
+        ("stray",): ABSENT_NIL, ("absent",): ABSENT_ABSENT}, held
+
+    pubkeys, msgs, sigs, _ = valset.collect_commit_sigs(
+        chain_id, block_id, height, commit)
+    assert len({len(x) for x in msgs}) == 2
+    _compare_with_host(list(pubkeys), list(msgs), list(sigs), checks)
+    assert checks["rejected_host"] == 0
+
+    # one more precommit for nil: every lane verifies, one vote under
+    votes[order[-1]] = precommit(order[-1], BlockID())
+    under = Commit.unmarshal(Commit(block_id, votes).marshal())
+    try:
+        valset.verify_commit(chain_id, block_id, height, under)
+        raise AssertionError("a commit one vote under two thirds was accepted")
+    except CommitError as e:
+        assert "insufficient voting power" in str(e), e
+    checks["one_more_nil_refused"] = True
+
+
 def stage_ed25519_msm(checks: dict) -> None:
     import random
 
@@ -631,6 +724,7 @@ def kernels_main() -> int:
         "fast_sync_full": stage_fast_sync_full,
         "secp256k1": stage_secp256k1,
         "multisig": stage_multisig,
+        "commit_absent": stage_commit_absent,
         "ed25519_msm": stage_ed25519_msm,
     }
     rc = 0

@@ -314,8 +314,10 @@ def _self_check():
     vm.device_audit.add(1.0, ("mismatch",))
     # how a Pallas ed25519 call packed its lanes (ops/ed25519_pallas)
     vm.ed25519_pack.add(1.0, ("uniform",))
+    vm.ed25519_launches.add(1.0)
     # the form a verify_commit's lanes went down in (types/validator_set)
     vm.commit_collect.add(1.0, ("columns",))
+    vm.commit_precommits.add(6667.0, ("for_block",))
     # a chain whose validator set changes: cut windows, applied changes,
     # whole-cache clears (blockchain/reactor, ops/ed25519_pallas)
     # a block's decode and hand-over to the pool (blockchain/reactor.receive)
@@ -490,6 +492,9 @@ def _self_check():
         "tendermint_verify_sync_ticks_total",
         "tendermint_verify_ed25519_pack_total",
         "tendermint_verify_commit_collect_total",
+        # what a commit held and the launches a call made
+        "tendermint_verify_commit_precommits_total",
+        "tendermint_verify_ed25519_launches_total",
         # the third consumer of the interpreter in a fast sync: block intake
         "tendermint_verify_block_intake_seconds",
         "tendermint_verify_block_intake_bytes_total",

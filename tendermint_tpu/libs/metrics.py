@@ -443,6 +443,14 @@ class VerifyMetrics:
         )
         for path in ("uniform", "grouped"):  # both series from 0
             self.ed25519_pack.add(0.0, (path,))
+        # device launches under those calls: one a message length
+        self.ed25519_launches = r.counter(
+            "verify_ed25519_launches_total",
+            "Device launches of the Pallas ed25519 verify "
+            "(ops/ed25519_pallas._verify_uniform): one a call whose messages "
+            "have one length, one a length where they differ",
+        )
+        self.ed25519_launches.add(0.0)  # exposed from 0
         # in which form ValidatorSet.verify_commit handed a commit's lanes
         # to the verifier
         self.commit_collect = r.counter(
@@ -455,6 +463,17 @@ class VerifyMetrics:
         )
         for form in ("columns", "lists"):  # both series from 0
             self.commit_collect.add(0.0, (form,))
+        # what those commits held, slot by slot: three adds a call
+        self.commit_precommits = r.counter(
+            "verify_commit_precommits_total",
+            "Slots of the commits ValidatorSet.verify_commit collected, by "
+            "kind: for_block (a precommit for the commit's block id: "
+            "verified and tallied) | stray (for nil or another block: "
+            "verified, not tallied) | absent (no precommit in the slot)",
+            label_names=("kind",),
+        )
+        for kind in ("for_block", "stray", "absent"):  # all three from 0
+            self.commit_precommits.add(0.0, (kind,))
         # secp256k1 lanes the host prologue (secp256k1_verify.prep_batch)
         # decided: they never reach the device, so the guard's audit, which
         # samples the dispatch's answer, sees the host's verdict for them

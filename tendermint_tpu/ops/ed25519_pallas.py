@@ -1061,6 +1061,7 @@ def _sig_words(sigs, valid, b: Optional[int] = None) -> np.ndarray:
 def _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln, interpret,
                     carry_mode="lazy", valset_key: Optional[bytes] = None):
     n = pubs.shape[0]
+    get_verify_metrics().ed25519_launches.add(1.0)
     # interpret mode (CPU tests) has no tile-alignment constraint: shrink the
     # lane count so the eager interpreter does 16x less padded work.
     lanes = 8 if interpret else LANES

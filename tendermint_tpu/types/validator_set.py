@@ -474,8 +474,10 @@ class ValidatorSet:
             "commit.verify", height=height, n=len(commit.precommits)
         ):
             # not inside collect_commit_sigs: fast sync calls that per block
-            with trace.span("commit.collect", n=len(commit.precommits)):
+            with trace.span("commit.collect", n=len(commit.precommits)) as sp:
                 scan = self._scan_commit(block_id, height, commit)
+                _round, absent, _timestamps, present, strays = scan
+                sp.set(absent=len(absent), strays=len(strays))
                 members = self._member_columns()
                 columns = members and self._commit_columns(
                     chain_id, block_id, height, scan, members
@@ -485,9 +487,14 @@ class ValidatorSet:
                         chain_id, block_id, height, scan
                     )
             try:
-                get_verify_metrics().commit_collect.add(
+                vm = get_verify_metrics()
+                vm.commit_collect.add(
                     1.0, ("columns" if columns else "lists",)
                 )
+                held = vm.commit_precommits
+                held.add(float(len(present) - len(strays)), ("for_block",))
+                held.add(float(len(strays)), ("stray",))
+                held.add(float(len(absent)), ("absent",))
             except Exception:
                 pass
             if columns:
