@@ -38,7 +38,11 @@ against the host oracle:
                  commit-ed25519-10k-live, cut to 1,000 validators): 300
                  slots absent, 33 for nil, decoded from its wire bytes,
                  through verify_commit: two message lengths regrouped once
-                 into two launches; every verdict equals the host's; the
+                 into two launches, its keys gathered by slot from the
+                 membership's table (one fill); a second height, another
+                 subset absent: a hit and no valset.miss, and every lane
+                 through the table path equals the host's, five spoiled
+                 lanes among them; every verdict equals the host's; the
                  same commit with one more precommit for nil is refused.
   ed25519_msm    one 512-signature window with ed25519_path="msm" (the RLC
                  seed is a hash of the seeded content, so it is pinned).
@@ -547,35 +551,47 @@ def stage_commit_absent(checks: dict) -> None:
         return vote.with_signature(
             ed.sign(privs[val.address], vote.sign_bytes(chain_id)))
 
+    def live_commit(order):
+        absent = set(order[:ABSENT_ABSENT])
+        nil = set(order[ABSENT_ABSENT:ABSENT_ABSENT + ABSENT_NIL])
+        votes = [None if i in absent
+                 else precommit(i, BlockID() if i in nil else block_id)
+                 for i in range(ABSENT_VALIDATORS)]
+        return votes, Commit.unmarshal(Commit(block_id, votes).marshal())
+
     order = rng.sample(range(ABSENT_VALIDATORS), ABSENT_VALIDATORS)
-    absent = set(order[:ABSENT_ABSENT])
-    nil = set(order[ABSENT_ABSENT:ABSENT_ABSENT + ABSENT_NIL])
-    votes = [None if i in absent
-             else precommit(i, BlockID() if i in nil else block_id)
-             for i in range(ABSENT_VALIDATORS)]
-    commit = Commit.unmarshal(Commit(block_id, votes).marshal())
+    votes, commit = live_commit(order)
     assert commit.precommits[order[-1]].block_id is not commit.block_id
 
     # the process default, chosen as a node chooses it; chosen here, because
     # its selection self-test is a launch of its own
     get_batch_verifier()
     m = get_verify_metrics()
-    watched = (m.ed25519_pack, m.ed25519_launches, m.commit_precommits)
+    watched = (m.ed25519_pack, m.ed25519_launches, m.commit_precommits,
+               m.valset_cache)
     before = [c.snapshot() for c in watched]
     valset.verify_commit(chain_id, block_id, height, commit)
     checks["verify_commit_accepted"] = True
-    pack, launches, held = (
+    pack, launches, held, caches = (
         _delta(c.snapshot(), b) for c, b in zip(watched, before))
     checks["valid_commit"] = {
         "pack": {"/".join(k): v for k, v in pack.items()},
         "launches": sum(launches.values()),
         "held": {"/".join(k): v for k, v in held.items()},
+        "valset_cache": {"/".join(k): v for k, v in caches.items()},
     }
     assert pack == {("grouped",): 1}, f"packing of one commit: {pack}"
     assert sum(launches.values()) == 2, launches
     assert held == {
         ("for_block",): ABSENT_VALIDATORS - ABSENT_ABSENT - ABSENT_NIL,
         ("stray",): ABSENT_NIL, ("absent",): ABSENT_ABSENT}, held
+    # the lanes are rows of the membership: its table is filled, once, and
+    # neither whole-array cache is shown this height's subset of the keys
+    assert caches == {("table", "miss"): 1}, caches
+    _second_height_through_the_table(
+        valset, chain_id, block_id, height,
+        live_commit(rng.sample(range(ABSENT_VALIDATORS), ABSENT_VALIDATORS))[1],
+        checks)
 
     pubkeys, msgs, sigs, _ = valset.collect_commit_sigs(
         chain_id, block_id, height, commit)
@@ -592,6 +608,77 @@ def stage_commit_absent(checks: dict) -> None:
     except CommitError as e:
         assert "insufficient voting power" in str(e), e
     checks["one_more_nil_refused"] = True
+
+
+def _second_height_through_the_table(
+        valset, chain_id, block_id, height, commit, checks) -> None:
+    """The same set's next commit, another 300 slots absent and another 33
+    for nil: served from the table the first one filled (a hit, no
+    ``valset.miss`` span), and every lane's verdict THROUGH THE TABLE PATH,
+    the membership's ``ValsetRows`` handed down with the lanes, equal to
+    the host's: the device gather, lane for lane, which no verdict of an
+    accepted commit shows."""
+    import numpy as np
+
+    from tendermint_tpu.crypto.batch import (
+        HostBatchVerifier,
+        verify_ed25519_columns,
+        verify_generic,
+    )
+    from tendermint_tpu.libs import trace
+    from tendermint_tpu.libs.metrics import get_verify_metrics
+
+    cache = get_verify_metrics().valset_cache
+    before = cache.snapshot()
+    trace.reset()
+    trace.enable()
+    try:
+        valset.verify_commit(chain_id, block_id, height, commit)
+        spans = [e["name"] for e in trace.export() if e.get("ph") == "X"]
+    finally:
+        trace.disable()
+        trace.reset()
+    moved = _delta(cache.snapshot(), before)
+    assert moved == {("table", "hit"): 1}, moved
+    assert "valset.miss" not in spans and spans.count("dispatch.launch") == 2, spans
+
+    # the lanes as verify_commit sends them (lists of two lengths: one call
+    # regrouped, the slots split between its two launches), five of them
+    # spoiled, one a lane for nil
+    absent = [i for i, pc in enumerate(commit.precommits) if pc is None]
+    rows = valset._valset_rows(valset._member_columns(), absent)
+    pubkeys, msgs, sigs, _ = valset.collect_commit_sigs(
+        chain_id, block_id, height, commit)
+    sigs = list(sigs)
+    short = min(range(len(msgs)), key=lambda j: len(msgs[j]))
+    bad = sorted({0, 7, len(sigs) // 2, len(sigs) - 1, short})
+    for j in bad:
+        sigs[j] = bytes([sigs[j][0] ^ 1]) + sigs[j][1:]
+    host = HostBatchVerifier()
+    before = cache.snapshot()
+    got = verify_generic(pubkeys, msgs, sigs, valset=rows)
+    want = verify_generic(pubkeys, msgs, sigs, verifier=host)
+    diff = np.flatnonzero(got != want)
+    assert diff.size == 0, f"table path != host in lanes {diff[:8].tolist()}"
+    assert np.flatnonzero(~want).tolist() == bad, np.flatnonzero(~want)[:8]
+
+    # and the lanes for the block as columns, with the same value
+    long = [j for j in range(len(msgs)) if len(msgs[j]) != len(msgs[short])]
+    cols = [np.frombuffer(b"".join(c[j] for j in long), dtype=np.uint8
+                          ).reshape(len(long), -1)
+            for c in ([pk.bytes() for pk in pubkeys], msgs, sigs)]
+    of_block = rows._replace(slots=rows.slots[long])
+    got_c = verify_ed25519_columns(*cols, valset=of_block)
+    assert got_c.tolist() == want[long].tolist()
+    moved = _delta(cache.snapshot(), before)
+    assert moved == {("table", "hit"): 2}, moved
+    checks["second_height"] = {
+        "valset_miss_spans": spans.count("valset.miss"),
+        "table_hits": 1 + int(moved[("table", "hit")]),
+        "table_path_lanes": len(want) + len(long),
+        "table_path_refused": int(np.count_nonzero(~got))
+        + int(np.count_nonzero(~got_c)),
+    }
 
 
 def stage_ed25519_msm(checks: dict) -> None:

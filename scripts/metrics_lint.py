@@ -315,6 +315,9 @@ def _self_check():
     # how a Pallas ed25519 call packed its lanes (ops/ed25519_pallas)
     vm.ed25519_pack.add(1.0, ("uniform",))
     vm.ed25519_launches.add(1.0)
+    # its key caches: a call's own key array, and a membership's table
+    vm.valset_cache.add(1.0, ("host", "miss"))
+    vm.valset_cache.add(1.0, ("table", "hit"))
     # the form a verify_commit's lanes went down in (types/validator_set)
     vm.commit_collect.add(1.0, ("columns",))
     vm.commit_precommits.add(6667.0, ("for_block",))

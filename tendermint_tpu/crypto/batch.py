@@ -125,12 +125,29 @@ class SigItem(NamedTuple):
 
 
 def valset_key(keys: np.ndarray) -> bytes:
-    """What the Pallas path's two valset caches (ops/ed25519_pallas) know an
+    """What the Pallas path's valset caches (ops/ed25519_pallas) know an
     (n, 32) key array by.  THE definition: a caller that keeps a key array
     (``ValidatorSet``'s membership columns) takes its identity here once and
-    hands it down with the array as ``valset_key=``, and the kernel's host
-    wrapper takes it here where none came."""
+    hands it down in a ``ValsetRows``, and the kernel's host wrapper takes it
+    here where none came."""
     return hashlib.sha256(np.ascontiguousarray(keys)).digest()
+
+
+class ValsetRows(NamedTuple):
+    """What a caller knows of a dispatch's keys beyond their bytes: they are
+    rows of a key array it keeps (``ValidatorSet``'s membership columns).
+    Goes down beside the three columns as ``valset=``; the columns' own keys
+    still go, and the audit, the host completion and the host verifier read
+    those alone.  The Pallas path keeps what it derives from ``keys`` by
+    ``key_id``: with ``slots`` None the lanes ARE ``keys``, row for row (its
+    two whole-array caches, no hash of the keys a call); else lane i is row
+    ``slots[i]``, and it gathers the lanes from one table a membership, held
+    on the device (a commit with absent slots: another subset every height,
+    the same members)."""
+
+    key_id: bytes  # valset_key(keys)
+    keys: np.ndarray  # (N, 32) uint8, every member's key
+    slots: Optional[np.ndarray]  # (n,) row of keys a lane; None: all, in order
 
 
 def _byte_rows(col):
@@ -150,8 +167,8 @@ class HostBatchVerifier:
     name = "host"
     # verify_ed25519_raw takes each column as a list of ``bytes`` or as the
     # uint8 array a caller already holds ((n, 32), (n, ln), (n, 64)), and
-    # the key array's ``valset_key`` with them: verify_ed25519_columns asks
-    # before it hands arrays to a verifier
+    # the keys' ``ValsetRows`` with them: verify_ed25519_columns and
+    # verify_generic ask before they hand either to a verifier
     column_form = True
 
     def verify_ed25519(self, items: Sequence[SigItem]) -> np.ndarray:
@@ -166,12 +183,12 @@ class HostBatchVerifier:
         return ok
 
     def verify_ed25519_raw(self, pubs, msgs, sigs,
-                           valset_key: Optional[bytes] = None) -> np.ndarray:
+                           valset: Optional[ValsetRows] = None) -> np.ndarray:
         """Parallel-sequence form of verify_ed25519 — the hot callers
         (verify_generic's homogeneous fast path) already hold the three
         columns, and building |window|x|valset| SigItems was a measured
         slice of the fast-sync host ceiling.  The oracle reads ``bytes``, so
-        an array is cut into its rows; ``valset_key`` is the device's."""
+        an array is cut into its rows; ``valset`` is the device's."""
         pubs, msgs, sigs = map(_byte_rows, (pubs, msgs, sigs))
         t0 = time.perf_counter()
         verify = _ed.verify
@@ -226,7 +243,7 @@ class RLCHostVerifier(HostBatchVerifier):
         return ok
 
     def verify_ed25519_raw(self, pubs, msgs, sigs,
-                           valset_key: Optional[bytes] = None) -> np.ndarray:
+                           valset: Optional[ValsetRows] = None) -> np.ndarray:
         pubs, msgs, sigs = map(_byte_rows, (pubs, msgs, sigs))
         t0 = time.perf_counter()
         with trace.span("verify.dispatch", backend="host_rlc",
@@ -319,11 +336,12 @@ class TPUBatchVerifier:
         )
 
     def verify_ed25519_raw(self, pubs, msgs, sigs,
-                           valset_key: Optional[bytes] = None) -> np.ndarray:
+                           valset: Optional[ValsetRows] = None) -> np.ndarray:
         """Column form of verify_ed25519 (see HostBatchVerifier's note).
         Each column is a list of ``bytes`` or the array a caller already
-        holds; ``valset_key`` is ``valset_key(pubs)`` where the caller keeps
-        it with the keys."""
+        holds; ``valset`` says which rows of a kept key array ``pubs`` are,
+        where the caller knows (the Pallas ladder's caches; no other path
+        reads it)."""
         if len(pubs) == 0:
             return np.zeros((0,), dtype=bool)
         t0 = time.perf_counter()
@@ -336,7 +354,7 @@ class TPUBatchVerifier:
                 b"".join(sigs), dtype=np.uint8).reshape(len(sigs), 64)
             if self.backend == "pallas" and self.ed25519_path != "msm":
                 ok = self._kernel.verify_batch(
-                    pubs_a, msgs, sigs_a, valset_key=valset_key)
+                    pubs_a, msgs, sigs_a, valset=valset)
             else:
                 # only the Pallas ladder reads a message matrix in place
                 msgs = _byte_rows(msgs)
@@ -488,10 +506,10 @@ class GuardedBatchVerifier:
         return getattr(self.device, "column_form", False)
 
     def verify_ed25519_raw(self, pubs, msgs, sigs,
-                           valset_key: Optional[bytes] = None) -> np.ndarray:
+                           valset: Optional[ValsetRows] = None) -> np.ndarray:
         # a device that does not say column_form (a foreign fake) is handed
-        # what it was before this parameter: three lists, no key
-        kw = {"valset_key": valset_key} if self.column_form else {}
+        # what it was before this parameter: three columns and nothing else
+        kw = {"valset": valset} if self.column_form else {}
         return self._guard(
             "ed25519", len(pubs),
             lambda: self.device.verify_ed25519_raw(pubs, msgs, sigs, **kw),
@@ -888,7 +906,7 @@ def verify_items(items: Sequence[SigItem], verifier=None) -> np.ndarray:
 
 def verify_generic(
     pubkeys: Sequence[PubKey], msgs: Sequence[bytes], sigs: Sequence[bytes],
-    verifier=None,
+    verifier=None, valset: Optional[ValsetRows] = None,
 ) -> np.ndarray:
     """Batch-verify over PubKey objects: ed25519 and secp256k1 keys batch to
     their backends; k-of-n threshold multisig aggregates FLATTEN into the
@@ -902,15 +920,20 @@ def verify_generic(
     ``multisig.flatten_columns`` reads in place from the marshalled
     signature (no item object a lane).  A multisig member's verdict is the
     AND of its run, taken for all members at once (``logical_and.reduceat``
-    at the runs' starts)."""
+    at the runs' starts).
+
+    ``valset`` is for a caller whose ``pubkeys`` are rows of a key array it
+    keeps (verify_commit, where a lane fits no column): it goes down with
+    the homogeneous ed25519 batch, where lane i is ``pubkeys[i]``, and
+    nowhere else."""
     # the span's own time is the key-type scan and the column lists; the
     # verifier's spans (guard.call or verify.dispatch) and, for multisig
     # members, multisig.flatten and multisig.reduce are its children
     with trace.span("verify.generic", n=len(pubkeys)) as sp:
-        return _verify_generic(pubkeys, msgs, sigs, verifier, sp)
+        return _verify_generic(pubkeys, msgs, sigs, verifier, sp, valset)
 
 
-def _verify_generic(pubkeys, msgs, sigs, verifier, sp) -> np.ndarray:
+def _verify_generic(pubkeys, msgs, sigs, verifier, sp, valset) -> np.ndarray:
     from tendermint_tpu.crypto.keys import PubKeySecp256k1
     from tendermint_tpu.crypto.multisig import PubKeyMultisigThreshold
 
@@ -926,7 +949,7 @@ def _verify_generic(pubkeys, msgs, sigs, verifier, sp) -> np.ndarray:
     ):
         sp.set(keys="ed25519")
         return _dispatch_ed25519_rows(
-            verifier, [pk.bytes() for pk in pubkeys], msgs, sigs
+            verifier, [pk.bytes() for pk in pubkeys], msgs, sigs, valset
         )
     sp.set(keys="mixed")
     out = np.zeros((n,), dtype=bool)
@@ -986,33 +1009,39 @@ def _verify_generic(pubkeys, msgs, sigs, verifier, sp) -> np.ndarray:
 
 def verify_ed25519_columns(
     keys: np.ndarray, msgs: np.ndarray, sigs: np.ndarray, verifier=None,
-    valset_key: Optional[bytes] = None,
+    valset: Optional[ValsetRows] = None,
 ) -> np.ndarray:
     """verify_generic for a caller that holds an all-ed25519 batch as three
     uint8 arrays, (n, 32) keys, (n, ln) messages, (n, 64) signatures: one
-    dispatch, no object a lane.  ``valset_key`` is ``valset_key(keys)``
-    where the caller keeps it with the keys.  A verifier that does not say
+    dispatch, no object a lane.  ``valset`` says which rows of a key array
+    the caller keeps ``keys`` are.  A verifier that does not say
     ``column_form`` (a fake in a test, a stand-in of the benchmark's) gets
-    the rows as lists of ``bytes``."""
+    the rows as lists of ``bytes`` and nothing else."""
     with trace.span("verify.generic", n=len(keys), keys="ed25519"):
         if verifier is None:
             verifier = get_batch_verifier()
         if getattr(verifier, "column_form", False):
             return np.asarray(
                 verifier.verify_ed25519_raw(
-                    keys, msgs, sigs, valset_key=valset_key),
+                    keys, msgs, sigs, valset=valset),
                 dtype=bool,
             )
         return _dispatch_ed25519_rows(
             verifier, *map(_byte_rows, (keys, msgs, sigs)))
 
 
-def _dispatch_ed25519_rows(verifier, pubs, msgs, sigs) -> np.ndarray:
-    """One ed25519 dispatch from three lists of ``bytes``.  A verifier
-    without ``verify_ed25519_raw`` (fakes in tests) gets ``SigItem``s."""
+def _dispatch_ed25519_rows(verifier, pubs, msgs, sigs,
+                           valset: Optional[ValsetRows] = None) -> np.ndarray:
+    """One ed25519 dispatch from three lists of ``bytes``, and the keys'
+    ``ValsetRows`` where one came and the verifier says ``column_form``.  A
+    verifier without ``verify_ed25519_raw`` (fakes in tests) gets
+    ``SigItem``s."""
     raw = getattr(verifier, "verify_ed25519_raw", None)
     if raw is not None:
-        return np.asarray(raw(pubs, msgs, sigs), dtype=bool)
+        kw = {}
+        if valset is not None and getattr(verifier, "column_form", False):
+            kw["valset"] = valset
+        return np.asarray(raw(pubs, msgs, sigs, **kw), dtype=bool)
     items = [SigItem(p, m, s) for p, m, s in zip(pubs, msgs, sigs)]
     return np.asarray(verifier.verify_ed25519(items), dtype=bool)
 

@@ -420,14 +420,17 @@ class VerifyMetrics:
         )
         # whole-valset caches of the Pallas path (ops/ed25519_pallas): the
         # decompressed limbs on the host and their padded copies on the
-        # device, each keyed by the dispatch's whole pubkey array; and the
-        # secp256k1 prologue's per-key decompression cache
+        # device, each keyed by the dispatch's whole pubkey array; the table
+        # a membership its lanes are gathered from where the caller says
+        # which rows of its key array they are (one lookup a call; a miss is
+        # a fill); and the secp256k1 prologue's per-key decompression cache
         # (ops/secp256k1_verify._decompress_cached: one lookup a lane)
         self.valset_cache = r.counter(
             "verify_valset_cache_total",
             "Verify-path key cache lookups by cache (host|device: a whole "
-            "ed25519 valset a dispatch; secp256k1_pubkey: one key a lane) "
-            "and result (hit|miss)",
+            "ed25519 valset a dispatch; table: a membership's resident "
+            "table, a call of lanes known as its rows; secp256k1_pubkey: one "
+            "key a lane) and result (hit|miss)",
             label_names=("cache", "result"),
         )
         # how ops/ed25519_pallas.verify_batch handed a call's columns down:

@@ -39,8 +39,9 @@ lookup plus byte packing.
 
 from __future__ import annotations
 
+import threading
 from functools import partial
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -51,7 +52,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tendermint_tpu.crypto import ed25519 as _ed
-from tendermint_tpu.crypto.batch import valset_key as _valset_key
+from tendermint_tpu.crypto.batch import ValsetRows, valset_key as _valset_key
 from tendermint_tpu.libs import trace
 from tendermint_tpu.libs.metrics import get_verify_metrics
 from tendermint_tpu.ops import ed25519_verify as _xla
@@ -756,19 +757,8 @@ _valset_cache: dict = {}
 _VALSET_CACHE_MAX = 64
 
 
-def _decompress_valset(
-    pubs: np.ndarray, key: Optional[bytes] = None,
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """(N, 32) pubkeys -> (neg_ax, ay, valid) with whole-set caching: commit
-    verification hits the same validator-set array every height.  ``key`` is
-    ``_valset_key(pubs)`` where the caller already holds it."""
-    if key is None:
-        key = _valset_key(pubs)
-    hit = _valset_cache.get(key)
-    get_verify_metrics().valset_cache.add(
-        1.0, ("host", "miss" if hit is None else "hit"))
-    if hit is not None:
-        return hit
+def _decompress_rows(pubs: np.ndarray):
+    """(N, 32) pubkeys -> (neg_ax, ay, valid), a per-key cache lookup a row."""
     n = pubs.shape[0]
     # one span a missed set, never a lane: what a new key array costs
     with trace.span("valset.miss", cache="host", lanes=n, bytes=32 * n):
@@ -782,6 +772,23 @@ def _decompress_valset(
             else:
                 neg_ax[i] = dec[0]
                 ay[i] = dec[1]
+    return neg_ax, ay, valid
+
+
+def _decompress_valset(
+    pubs: np.ndarray, key: Optional[bytes] = None,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(N, 32) pubkeys -> (neg_ax, ay, valid) with whole-set caching: commit
+    verification hits the same validator-set array every height.  ``key`` is
+    ``_valset_key(pubs)`` where the caller already holds it."""
+    if key is None:
+        key = _valset_key(pubs)
+    hit = _valset_cache.get(key)
+    get_verify_metrics().valset_cache.add(
+        1.0, ("host", "miss" if hit is None else "hit"))
+    if hit is not None:
+        return hit
+    neg_ax, ay, valid = _decompress_rows(pubs)
     if len(_valset_cache) >= _VALSET_CACHE_MAX:
         _valset_cache.clear()
         get_verify_metrics().valset_cache_clears.add(1.0, ("host",))
@@ -829,6 +836,117 @@ def _upload_valset(pubs, neg_ax, ay, b, key: Optional[bytes] = None):
     return entry
 
 
+# A membership a table: the lanes of a call whose caller knows them as rows
+# of a key array it keeps (``ValsetRows`` with slots: a commit with absent
+# slots, another subset every height) are gathered by row from what is
+# derived ONCE from that array, on the host and on the device.  The two
+# whole-array caches above know a call by its own key array, which is new at
+# every such height.
+_valset_tables: dict = {}  # key_id -> _ValsetTable, least recently used first
+_VALSET_TABLES_MAX = 4  # a node holds the current, the next and the last set
+_valset_tables_mtx = threading.Lock()
+_TABLE_WORDS = 2 * NLIMB + 8  # a device row: negax | ay | key words
+
+
+class _ValsetTable:
+    """One membership's key material, a row a member."""
+
+    __slots__ = ("keys", "neg_ax", "ay", "valid", "device")
+
+    def __init__(self, keys, neg_ax, ay, valid):
+        self.keys = keys  # (N, 32) u8, the caller's own array
+        self.neg_ax, self.ay, self.valid = neg_ax, ay, valid  # (N, 20) x2, (N,)
+        # (N + 1, 48) u32 on the device, negax | ay | key words a row and
+        # row N all zero (a bucket's padding lanes); None until a launch
+        self.device = None
+
+
+def _valset_table(rows: ValsetRows) -> _ValsetTable:
+    """The table of ``rows.keys``, filled at a membership's first call.  A
+    handful are resident and the least recently used one goes alone."""
+    with _valset_tables_mtx:
+        table = _valset_tables.pop(rows.key_id, None)
+        if table is not None:
+            _valset_tables[rows.key_id] = table  # the most recently used
+    get_verify_metrics().valset_cache.add(
+        1.0, ("table", "miss" if table is None else "hit"))
+    if table is not None:
+        return table
+    keys = np.ascontiguousarray(rows.keys, dtype=np.uint8)
+    table = _ValsetTable(keys, *_decompress_rows(keys))
+    with _valset_tables_mtx:
+        while len(_valset_tables) >= _VALSET_TABLES_MAX:
+            del _valset_tables[next(iter(_valset_tables))]
+        _valset_tables[rows.key_id] = table
+    return table
+
+
+def _table_on_device(table: _ValsetTable):
+    if table.device is None:
+        n = table.valid.shape[0]
+        with trace.span("valset.miss", cache="device", lanes=n,
+                        bytes=4 * (n + 1) * _TABLE_WORDS):
+            pub_words = table.keys.view("<u4").astype(np.uint32)
+            table.device = jnp.asarray(_pad_rows(np.concatenate(
+                [table.neg_ax, table.ay, pub_words], axis=1), n + 1))
+    return table.device
+
+
+@jax.jit
+def _gather_valset_rows(table, idx):
+    """Rows ``idx`` of a membership's device table as the three key arrays
+    ``_device_verify_packed`` takes: (b, 20) negax, (b, 20) ay, (b, 8) key
+    words."""
+    rows = jnp.take(table, idx, axis=0, mode="clip")
+    return rows[:, :NLIMB], rows[:, NLIMB:2 * NLIMB], rows[:, 2 * NLIMB:]
+
+
+def _table_index(slots: np.ndarray, n_members: int, b: int) -> np.ndarray:
+    """A launch's gather index: the lanes' rows, then the zero row up to the
+    bucket."""
+    idx = np.full((b,), n_members, dtype=np.int32)
+    idx[:slots.shape[0]] = slots
+    return idx
+
+
+class _OwnKeys(NamedTuple):
+    """A launch's key limbs where the call's keys are known by themselves:
+    decompressed for this key array, and on the device a copy a (key array,
+    bucket)."""
+
+    neg_ax: np.ndarray
+    ay: np.ndarray
+    key: Optional[bytes]  # the key array's _valset_key where it is held
+
+    def take(self, idx):
+        return _OwnKeys(self.neg_ax[idx], self.ay[idx], None)
+
+    def on_host(self):
+        return self.neg_ax, self.ay
+
+    def on_device(self, pubs, b):
+        return _upload_valset(pubs, self.neg_ax, self.ay, b, self.key)
+
+
+class _TableKeys(NamedTuple):
+    """A launch's key limbs as rows of a membership's table: an index goes
+    up and the device gathers."""
+
+    table: _ValsetTable
+    slots: np.ndarray
+
+    def take(self, idx):
+        return _TableKeys(self.table, self.slots[idx])
+
+    def on_host(self):
+        return self.table.neg_ax[self.slots], self.table.ay[self.slots]
+
+    def on_device(self, _pubs, b):
+        idx = _table_index(self.slots, self.table.valid.shape[0], b)
+        return call_jit(_gather_valset_rows, _table_on_device(self.table),
+                        jnp.asarray(idx))
+
+
 def _bucket(n: int, lanes: int = LANES) -> int:
     b = lanes
     while b < n and b < 4096:
@@ -854,13 +972,15 @@ def _message_matrix(msgs, n: int, ln: int) -> np.ndarray:
 def verify_batch(pubs: np.ndarray, msgs: Sequence[bytes], sigs: np.ndarray,
                  interpret: bool = False,
                  carry_mode: str = "lazy",
-                 valset_key: Optional[bytes] = None) -> np.ndarray:
+                 valset: Optional[ValsetRows] = None) -> np.ndarray:
     """Go-exact batched verify on the Pallas path, on the default jax
     device. Same contract as ops.ed25519_verify.verify_batch.
     `carry_mode` picks the eager or deferred (lazy) carry schedule — both
     bit-exact at the canonical boundary.  ``msgs`` may be an (n, ln) uint8
-    array (one length, known from its shape); ``valset_key`` is
-    ``_valset_key(pubs)`` where the caller keeps it with the keys."""
+    array (one length, known from its shape).  ``valset`` says which rows
+    of a key array the caller keeps ``pubs`` are: every row in order
+    (``slots`` None: the two whole-array caches, known by its ``key_id``)
+    or ``keys[slots]`` (the membership's table, gathered by slot)."""
     carry_mode = _fc.normalize_carry_mode(carry_mode)
     pubs = np.ascontiguousarray(pubs, dtype=np.uint8)
     sigs = np.ascontiguousarray(sigs, dtype=np.uint8)
@@ -876,8 +996,21 @@ def verify_batch(pubs: np.ndarray, msgs: Sequence[bytes], sigs: np.ndarray,
     # is not packing.  One length (a commit, a sync window) goes down as the
     # caller's own columns; several are regrouped, one launch a length
     with trace.span("dispatch.prepare", n=n) as sp:
-        key = _valset_key(pubs) if valset_key is None else valset_key
-        neg_ax, ay, valid = _decompress_valset(pubs, key)
+        if valset is not None and valset.slots is not None:
+            slots = np.asarray(valset.slots)
+            # what the device gathers is what the lanes say: a caller whose
+            # slots name other keys is refused, not verified against them
+            if slots.shape != (n,) or slots.min() < 0 or not np.array_equal(
+                    valset.keys[slots], pubs):
+                raise ValueError("valset.keys[valset.slots] are not the keys")
+            table = _valset_table(valset)
+            limbs, valid = _TableKeys(table, slots), table.valid[slots]
+        else:
+            if valset is not None and valset.keys.shape != pubs.shape:
+                raise ValueError("valset.keys are not the keys")
+            key = _valset_key(pubs) if valset is None else valset.key_id
+            neg_ax, ay, valid = _decompress_valset(pubs, key)
+            limbs = _OwnKeys(neg_ax, ay, key)
         valid = valid & ((sigs[:, 63] & 224) == 0)  # Go's only s range check
         lengths = ({msgs.shape[1]} if isinstance(msgs, np.ndarray)
                    else set(map(len, msgs)))
@@ -889,15 +1022,15 @@ def verify_batch(pubs: np.ndarray, msgs: Sequence[bytes], sigs: np.ndarray,
                 idx = np.nonzero(lens == ln)[0]
                 groups.append((idx, (
                     pubs[idx], [msgs[i] for i in idx], sigs[idx],
-                    neg_ax[idx], ay[idx], valid[idx], int(ln),
+                    limbs.take(idx), valid[idx], int(ln),
                 )))
         sp.set(groups=len(lengths))
     get_verify_metrics().ed25519_pack.add(
         1.0, ("uniform" if uniform else "grouped",))
     if uniform:
         (ln,) = lengths
-        return _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln,
-                               interpret, carry_mode, valset_key=key)
+        return _verify_uniform(pubs, msgs, sigs, limbs, valid, ln,
+                               interpret, carry_mode)
     out = np.zeros((n,), dtype=bool)
     for idx, cols in groups:
         out[idx] = _verify_uniform(*cols, interpret, carry_mode)
@@ -1058,8 +1191,11 @@ def _sig_words(sigs, valid, b: Optional[int] = None) -> np.ndarray:
     return sig_words
 
 
-def _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln, interpret,
-                    carry_mode="lazy", valset_key: Optional[bytes] = None):
+def _verify_uniform(pubs, msgs, sigs, limbs, valid, ln, interpret,
+                    carry_mode="lazy"):
+    """One launch: ``limbs`` (_OwnKeys or _TableKeys) gives the lanes' key
+    limbs, on the device for the packed path and on the host for the
+    reference path."""
     n = pubs.shape[0]
     get_verify_metrics().ed25519_launches.add(1.0)
     # interpret mode (CPU tests) has no tile-alignment constraint: shrink the
@@ -1079,8 +1215,7 @@ def _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln, interpret,
             tmpl, vrows, vwords = pack_variable_words(pubs, msgs, sigs, ln, b)
             sp.set(vwords=int(vrows.size))
         with trace.span("dispatch.launch", lanes=b):  # copies in + enqueue
-            negax_d, ay_d, pubw_d = _upload_valset(pubs, neg_ax, ay, b,
-                                                   valset_key)
+            negax_d, ay_d, pubw_d = limbs.on_device(pubs, b)
             out = call_jit(
                 _device_verify_packed,
                 negax_d, ay_d, pubw_d,
@@ -1105,6 +1240,7 @@ def _verify_uniform(pubs, msgs, sigs, neg_ax, ay, valid, ln, interpret,
     msg_words = padded.reshape(b, -1, 4)[:, :, ::-1].reshape(b, -1)
     msg_words = np.ascontiguousarray(msg_words).view("<u4").astype(np.uint32)
 
+    neg_ax, ay = limbs.on_host()
     ok = np.asarray(
         _device_verify(
             jnp.asarray(_pad_rows(neg_ax, b)),

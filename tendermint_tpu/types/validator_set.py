@@ -20,6 +20,7 @@ import numpy as np
 
 from tendermint_tpu.crypto import merkle
 from tendermint_tpu.crypto.batch import (
+    ValsetRows,
     valset_key,
     verify_ed25519_columns,
     verify_generic,
@@ -424,14 +425,24 @@ class ValidatorSet:
             powers[j] = 0
         return pubkeys, msgs, sigs, powers
 
-    def _commit_columns(self, chain_id, block_id, height, scan, members):
+    @staticmethod
+    def _valset_rows(members: _MemberColumns, absent: List[int]) -> ValsetRows:
+        """Which rows of an all-ed25519 membership's key array a commit's
+        lanes are, whichever form they go down in: the present slots, None
+        where every slot is.  The kernel's host wrapper keeps what it
+        derives from the keys a membership, not a height's subset of them."""
+        slots = np.delete(np.arange(len(members.keys)), absent) if absent else None
+        return ValsetRows(members.key_id, members.keys, slots)
+
+    def _commit_columns(self, chain_id, block_id, height, scan, powers, rows):
         """The lanes of an all-ed25519 commit as columns, (keys (n, 32),
-        sign-bytes (n, ln), signatures (n, 64), powers (n,), the keys'
-        identity or None): the same lanes _commit_lists makes, with no
-        object a lane.  None where a lane does not fit a column (a signature
-        not 64 bytes, a stray vote whose sign-bytes have another length):
-        the lists decide such a commit."""
-        round, absent, timestamps, sigs, strays = scan
+        sign-bytes (n, ln), signatures (n, 64), powers (n,)): the same lanes
+        _commit_lists makes, with no object a lane; ``powers`` and ``rows``
+        are the membership's, ``rows.slots`` the present slots.  None where
+        a lane does not fit a column (a signature not 64 bytes, a stray vote
+        whose sign-bytes have another length): the lists decide such a
+        commit."""
+        round, _absent, timestamps, sigs, strays = scan
         n = len(sigs)
         if set(map(len, sigs)) != {64}:
             return None
@@ -440,10 +451,9 @@ class ValidatorSet:
         )
         msgs = np.empty((n, len(tpl)), dtype=np.uint8)
         msgs[:] = np.frombuffer(tpl, dtype=np.uint8)
-        keys, powers, key_id = members
-        if absent:
-            present = np.delete(np.arange(len(keys)), absent)
-            keys, powers, key_id = keys[present], powers[present], None
+        keys = rows.keys
+        if rows.slots is not None:
+            keys, powers = keys[rows.slots], powers[rows.slots]
         if strays:
             powers = powers.copy()
             for j, key in strays:
@@ -458,7 +468,7 @@ class ValidatorSet:
             _struct.pack(f"<{n}q", *timestamps), dtype=np.uint8
         ).reshape(n, 8)
         sigs = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64)
-        return keys, msgs, sigs, powers, key_id
+        return keys, msgs, sigs, powers
 
     def verify_commit(
         self, chain_id: str, block_id: BlockID, height: int, commit, verifier=None
@@ -479,8 +489,9 @@ class ValidatorSet:
                 _round, absent, _timestamps, present, strays = scan
                 sp.set(absent=len(absent), strays=len(strays))
                 members = self._member_columns()
-                columns = members and self._commit_columns(
-                    chain_id, block_id, height, scan, members
+                rows = members and self._valset_rows(members, absent)
+                columns = rows and self._commit_columns(
+                    chain_id, block_id, height, scan, members.powers, rows
                 )
                 if not columns:
                     pubkeys, msgs, sigs, powers = self._commit_lists(
@@ -498,12 +509,14 @@ class ValidatorSet:
             except Exception:
                 pass
             if columns:
-                keys, msgs, sigs, powers, key_id = columns
+                keys, msgs, sigs, powers = columns
                 ok = verify_ed25519_columns(
-                    keys, msgs, sigs, verifier=verifier, valset_key=key_id
+                    keys, msgs, sigs, verifier=verifier, valset=rows
                 )
             else:
-                ok = verify_generic(pubkeys, msgs, sigs, verifier=verifier)
+                ok = verify_generic(
+                    pubkeys, msgs, sigs, verifier=verifier, valset=rows
+                )
             with trace.span("commit.tally", n=len(ok)):
                 if not ok.all():
                     raise CommitError("invalid signature in commit")

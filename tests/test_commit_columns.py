@@ -337,6 +337,16 @@ def test_the_verdict_is_the_list_forms(variant, n, verify_counters):
         {} if refused else {_form_of(lanes, block_id, height): 1})
 
 
+def _columns_of(valset, block_id, height, commit):
+    """``_commit_columns`` as verify_commit calls it: (the four columns or
+    None, the membership's ``ValsetRows`` that goes down with them)."""
+    scan = valset._scan_commit(block_id, height, commit)
+    members = valset._member_columns()
+    rows = valset._valset_rows(members, scan[1])
+    return valset._commit_columns(
+        CHAIN, block_id, height, scan, members.powers, rows), rows
+
+
 @pytest.mark.parametrize("n", SIZES)
 @pytest.mark.parametrize("variant", sorted(VARIANTS))
 def test_the_lanes_are_the_list_forms(variant, n):
@@ -355,13 +365,11 @@ def test_the_lanes_are_the_list_forms(variant, n):
     pubkeys, msgs, sigs, powers = lanes
     assert all(type(p) is int for p in got[1][3])
 
-    scan = ch.valset._scan_commit(block_id, height, commit)
-    columns = ch.valset._commit_columns(
-        CHAIN, block_id, height, scan, ch.valset._member_columns())
+    columns, rows = _columns_of(ch.valset, block_id, height, commit)
     if _form_of(lanes, block_id, height) == "lists":
         assert columns is None
         return
-    keys, m, s, pw, key_id = columns
+    keys, m, s, pw = columns
     for a in (keys, m, s):
         assert a.dtype == np.uint8 and a.flags["C_CONTIGUOUS"]
     assert pw.dtype == np.int64
@@ -370,10 +378,16 @@ def test_the_lanes_are_the_list_forms(variant, n):
     assert [r.tobytes() for r in m] == msgs
     assert [r.tobytes() for r in s] == sigs
     assert pw.tolist() == powers
-    # the set's own identity rides along only with the set's own key array
+    # the set's identity and its whole key array ride along, and the
+    # present slots where the lanes are not the set's own key array
+    members = ch.valset._member_columns()
     whole = len(sigs) == ch.n
-    assert (key_id is not None) == whole
-    assert (keys is ch.valset._member_columns().keys) == whole
+    assert rows.key_id == members.key_id and rows.keys is members.keys
+    assert (rows.slots is None) == whole and (keys is members.keys) == whole
+    if not whole:
+        assert rows.slots.tolist() == [
+            i for i, pc in enumerate(commit.precommits) if pc is not None]
+        assert keys.tobytes() == members.keys[rows.slots].tobytes()
 
 
 @pytest.mark.parametrize("variant,n", [
@@ -385,9 +399,7 @@ def test_the_lanes_are_the_list_forms(variant, n):
 def test_a_row_of_the_matrix_is_the_precommits_sign_bytes(variant, n):
     ch = _chain(n)
     commit, block_id, height = VARIANTS[variant](ch)
-    scan = ch.valset._scan_commit(block_id, height, commit)
-    _keys, m, _s, _pw, _id = ch.valset._commit_columns(
-        CHAIN, block_id, height, scan, ch.valset._member_columns())
+    (_keys, m, _s, _pw), _rows = _columns_of(ch.valset, block_id, height, commit)
     present = [pc for pc in commit.precommits if pc is not None]
     assert m.shape[0] == len(present)
     for row, pc in zip(m, present):
@@ -522,6 +534,8 @@ def pallas(monkeypatch):
     launches, large_hashes = [], []
 
     def fake_call_jit(fn, *args, **static):
+        if fn is ep._gather_valset_rows:  # a membership's rows: it runs, here
+            return fn(*args)
         assert fn is ep._device_verify_packed
         host = [np.asarray(a) for a in args]
         launches.append(host)
@@ -533,6 +547,7 @@ def pallas(monkeypatch):
     monkeypatch.setattr(batch, "hashlib", _CountingHashlib(large_hashes))
     monkeypatch.setattr(ep, "_valset_cache", {})
     monkeypatch.setattr(ep, "_dev_valset_cache", {})
+    monkeypatch.setattr(ep, "_valset_tables", {})
     device = batch.TPUBatchVerifier(backend="pallas")
     return SimpleNamespace(device=device, launches=launches,
                            large_hashes=large_hashes, ep=ep)
@@ -616,8 +631,8 @@ class _ColumnDevice:
         self.seen = []
         self._host = batch.HostBatchVerifier()
 
-    def verify_ed25519_raw(self, pubs, msgs, sigs, valset_key=None):
-        self.seen.append((pubs, msgs, sigs, valset_key))
+    def verify_ed25519_raw(self, pubs, msgs, sigs, valset=None):
+        self.seen.append((pubs, msgs, sigs, valset))
         return self._host.verify_ed25519_raw(pubs, msgs, sigs)
 
 
@@ -661,11 +676,13 @@ def test_the_guard_audits_the_same_rows(variant, n, verify_counters):
     assert all(type(x) is bytes for row in from_lists[0][2] for x in row)
     assert verify_counters("tendermint_verify_device_audit_total",
                            {"outcome": "ok"}) == audited + 4 * lanes
-    # the device behind the guard got arrays, and the set's identity with
-    # the set's own key array
-    pubs, msgs, sigs, key = device.seen[0]
+    # the device behind the guard got arrays, and with them the set's
+    # identity, its key array and (some absent) the slots the lanes are
+    pubs, msgs, sigs, rows = device.seen[0]
     assert all(isinstance(c, np.ndarray) for c in (pubs, msgs, sigs))
-    assert (key is not None) == (variant != "some_absent")
+    members = ch.valset._member_columns()
+    assert rows.key_id == members.key_id and rows.keys is members.keys
+    assert (rows.slots is not None) == (variant == "some_absent")
 
 
 def test_a_device_without_the_column_form_is_handed_lists():
@@ -713,7 +730,7 @@ def test_the_host_completes_a_failed_device_call_from_the_columns():
         column_form = True
         backend = "fake-broken"
 
-        def verify_ed25519_raw(self, pubs, msgs, sigs, valset_key=None):
+        def verify_ed25519_raw(self, pubs, msgs, sigs, valset=None):
             raise RuntimeError("device lost")
 
     g = batch.GuardedBatchVerifier(Broken(), breaker=CircuitBreaker(),
@@ -819,21 +836,20 @@ def test_the_sets_identity_is_the_caches_own_key():
 
 @pytest.mark.parametrize("host", [batch.HostBatchVerifier, batch.RLCHostVerifier])
 def test_the_host_verifiers_take_the_columns_as_arrays(host):
-    """Verdict for verdict what they say of the same rows as lists; the
-    key array's identity is the device's and they take no notice of it."""
+    """Verdict for verdict what they say of the same rows as lists; what
+    the caller knows of the key array is the device's and they take no
+    notice of it."""
     ch = _chain(64)
     commit, block_id, height = _sig_flipped(ch)
-    members = ch.valset._member_columns()
-    keys, msgs, sigs, _powers, key_id = ch.valset._commit_columns(
-        CHAIN, block_id, height,
-        ch.valset._scan_commit(block_id, height, commit), members)
+    (keys, msgs, sigs, _powers), rows = _columns_of(
+        ch.valset, block_id, height, commit)
     assert host.column_form is True
-    got = host().verify_ed25519_raw(keys, msgs, sigs, valset_key=key_id)
+    got = host().verify_ed25519_raw(keys, msgs, sigs, valset=rows)
     want = host().verify_ed25519_raw(
         *(batch._byte_rows(c) for c in (keys, msgs, sigs)))
     assert got.tolist() == want.tolist() and 0 < got.sum() < len(got)
     assert batch.verify_ed25519_columns(
-        keys, msgs, sigs, verifier=host(), valset_key=key_id
+        keys, msgs, sigs, verifier=host(), valset=rows
     ).tolist() == want.tolist()
 
 

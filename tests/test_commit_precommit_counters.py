@@ -147,6 +147,8 @@ def pallas(monkeypatch):
     launches = []
 
     def fake_call_jit(fn, *args, **static):
+        if fn is ep._gather_valset_rows:  # a membership's rows: it runs, here
+            return fn(*args)
         assert fn is ep._device_verify_packed
         launches.append(int(np.asarray(args[3]).shape[0]))
         return np.ones((launches[-1],), dtype=bool)
@@ -155,6 +157,7 @@ def pallas(monkeypatch):
     monkeypatch.setattr(ep, "call_jit", fake_call_jit)
     monkeypatch.setattr(ep, "_valset_cache", {})
     monkeypatch.setattr(ep, "_dev_valset_cache", {})
+    monkeypatch.setattr(ep, "_valset_tables", {})
     return SimpleNamespace(ep=ep, launches=launches,
                            device=batch.TPUBatchVerifier(backend="pallas"))
 
