@@ -219,6 +219,43 @@ def stage_commit_verify(checks: dict) -> None:
     checks["corrupted"] = len(lanes)
     assert not want[lanes].any(), "host oracle accepted a corrupted lane"
     assert checks["rejected_host"] == len(lanes)
+    _resident_lanes_against_host(
+        valset, valset.collect_commit_sigs(chain_id, block_id, height, commit),
+        lanes, want, rng, checks)
+
+
+def _resident_lanes_against_host(valset, lanes_of, spoiled, want, rng, checks):
+    """The same commit's lanes THROUGH THE MEMBERSHIP'S WINDOW TABLES (every
+    slot present, so ``slots`` None: the ladder's resident form, which the
+    accepted commit above ran and whose lanes no accepted commit tells
+    apart), the same hundredth of them spoiled in the signature or the
+    message: equal to the host's, which the built form's lanes were compared
+    with above."""
+    import numpy as np
+
+    from tendermint_tpu.crypto.batch import verify_generic
+    from tendermint_tpu.libs.metrics import get_verify_metrics
+
+    pubkeys, msgs, sigs, _ = lanes_of
+    msgs, sigs = list(msgs), list(sigs)
+    for k, lane in enumerate(spoiled):
+        if k % 2:
+            msgs[lane] = _flip(msgs[lane], rng)
+        else:
+            sigs[lane] = _flip(sigs[lane], rng)
+    rows = valset._valset_rows(valset._member_columns(), [])
+    assert rows.slots is None
+    counted = get_verify_metrics().ed25519_ladder_lanes
+    before = counted.snapshot()
+    got = verify_generic(pubkeys, msgs, sigs, valset=rows)
+    moved = _delta(counted.snapshot(), before)
+    refused = np.flatnonzero(~got).tolist()
+    assert refused == sorted(spoiled), (
+        f"resident lanes != the spoiled ones: {refused[:8]}")
+    assert refused == np.flatnonzero(~want).tolist()
+    assert set(moved) == {("resident",)}, moved
+    checks["resident"] = {
+        "ladder_lanes": int(moved[("resident",)]), "refused": len(refused)}
 
 
 def _replay(genesis, blocks, verifier, **executor):
@@ -568,18 +605,21 @@ def stage_commit_absent(checks: dict) -> None:
     get_batch_verifier()
     m = get_verify_metrics()
     watched = (m.ed25519_pack, m.ed25519_launches, m.commit_precommits,
-               m.valset_cache)
+               m.valset_cache, m.ed25519_ladder_lanes)
     before = [c.snapshot() for c in watched]
     valset.verify_commit(chain_id, block_id, height, commit)
     checks["verify_commit_accepted"] = True
-    pack, launches, held, caches = (
+    pack, launches, held, caches, ladder = (
         _delta(c.snapshot(), b) for c, b in zip(watched, before))
     checks["valid_commit"] = {
         "pack": {"/".join(k): v for k, v in pack.items()},
         "launches": sum(launches.values()),
         "held": {"/".join(k): v for k, v in held.items()},
         "valset_cache": {"/".join(k): v for k, v in caches.items()},
+        "ladder_lanes": {"/".join(k): v for k, v in ladder.items()},
     }
+    # both launches read their lanes' window tables from the membership's
+    assert set(ladder) == {("resident",)}, ladder
     assert pack == {("grouped",): 1}, f"packing of one commit: {pack}"
     assert sum(launches.values()) == 2, launches
     assert held == {

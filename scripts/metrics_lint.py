@@ -315,6 +315,7 @@ def _self_check():
     # how a Pallas ed25519 call packed its lanes (ops/ed25519_pallas)
     vm.ed25519_pack.add(1.0, ("uniform",))
     vm.ed25519_launches.add(1.0)
+    vm.ed25519_ladder_lanes.add(128.0, ("resident",))
     # its key caches: a call's own key array, and a membership's table
     vm.valset_cache.add(1.0, ("host", "miss"))
     vm.valset_cache.add(1.0, ("table", "hit"))
@@ -498,6 +499,7 @@ def _self_check():
         # what a commit held and the launches a call made
         "tendermint_verify_commit_precommits_total",
         "tendermint_verify_ed25519_launches_total",
+        "tendermint_verify_ed25519_ladder_lanes_total",
         # the third consumer of the interpreter in a fast sync: block intake
         "tendermint_verify_block_intake_seconds",
         "tendermint_verify_block_intake_bytes_total",

@@ -139,11 +139,12 @@ class ValsetRows(NamedTuple):
     Goes down beside the three columns as ``valset=``; the columns' own keys
     still go, and the audit, the host completion and the host verifier read
     those alone.  The Pallas path keeps what it derives from ``keys`` by
-    ``key_id``: with ``slots`` None the lanes ARE ``keys``, row for row (its
-    two whole-array caches, no hash of the keys a call); else lane i is row
-    ``slots[i]``, and it gathers the lanes from one table a membership, held
-    on the device (a commit with absent slots: another subset every height,
-    the same members)."""
+    ``key_id``, one table a membership held on the device (key limbs and
+    words, and the window tables its ladder reads in place of building one
+    a lane), and gathers a dispatch's lanes from it: lane i is row
+    ``slots[i]`` (a commit with absent slots: another subset every height,
+    the same members), or with ``slots`` None the lanes ARE ``keys``, row
+    for row.  No hash of the keys a call either way."""
 
     key_id: bytes  # valset_key(keys)
     keys: np.ndarray  # (N, 32) uint8, every member's key

@@ -454,6 +454,17 @@ class VerifyMetrics:
             "have one length, one a length where they differ",
         )
         self.ed25519_launches.add(0.0)  # exposed from 0
+        # which form of the ladder those launches ran, in padded lanes
+        self.ed25519_ladder_lanes = r.counter(
+            "verify_ed25519_ladder_lanes_total",
+            "Lanes (a launch's bucket) of the Pallas ed25519 ladder by where "
+            "their window tables came from: resident (gathered from the "
+            "table of a membership the caller keeps: 64 doublings a lane) | "
+            "built (made from the key in every lane of the launch: 263)",
+            label_names=("tables",),
+        )
+        for form in ("resident", "built"):  # both series from 0
+            self.ed25519_ladder_lanes.add(0.0, (form,))
         # in which form ValidatorSet.verify_commit handed a commit's lanes
         # to the verifier
         self.commit_collect = r.counter(

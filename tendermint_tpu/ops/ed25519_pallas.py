@@ -18,12 +18,26 @@ Algorithm (per 128-lane block, batch on lanes, limbs on sublanes):
     here: fe_common's bound_* propagators recompute the closed set
     mechanically, and tests/test_fe_common.py asserts closure (carried
     limbs <= 13000) and that no intermediate reaches 2^32.
-  * Double-scalar mult R' = [s]B + [h](-A) via 4-bit windowed Straus:
-    64 MSB-first windows sharing 252 doublings; per window one mixed add
-    from a constant niels table [0..15]B (affine, identity at digit 0) and
-    one extended add from a per-signature table [0..15](-A) built with
-    7 doublings + 7 adds. Complete extended formulas throughout (adversarial
-    low-order/identity points need no special case).
+  * Double-scalar mult R' = [s]B + [h](-A) via 4-bit windowed Straus, in
+    two forms of one algorithm (``ladder_math``), the launch's own to tell
+    apart by what it was handed:
+      - BUILT (no identity came with the keys: fast-sync windows, a multisig
+        set's sub-keys, the vote set, ``rlc_verify_batch``): 64 MSB-first
+        windows sharing 252 doublings; per window one mixed add from a
+        constant niels table [0..15]B (affine, identity at digit 0) and one
+        add from a per-signature table [0..15](-A) built in the lane with
+        7 doublings + 7 adds.  263 doublings a signature.
+      - RESIDENT (the caller handed down ``crypto/batch.ValsetRows``: a
+        ``verify_commit`` of an all-ed25519 set): the lanes' WINDOW TABLES,
+        [0..15] * 16^(16 (3 - k)) * (-A) at K = 4 offsets of the scalar,
+        cached-niels, built ONCE A MEMBERSHIP on the device
+        (``window_tables_math``, ``_ValsetTable``) and gathered by slot;
+        digit 16 k + w looks up table k, so 16 rounds of 4 doublings, 4 mixed
+        adds from the constant tables [0..15] * 16^(16 (3 - k)) * B and 4
+        cached adds.  64 doublings a signature, 128 additions, no build;
+        24.6 KB of HBM a member.
+    Same group element either way.  Complete extended formulas throughout
+    (adversarial low-order/identity points need no special case).
   * Accept iff canonical-enc(R') equals sig[:32] byte-for-byte — the exact
     Go accept set (see crypto/ed25519.py quirk list): s range-checked only
     on the top 3 bits (host), A decompressed with Go's non-canonical
@@ -40,7 +54,7 @@ lookup plus byte packing.
 from __future__ import annotations
 
 import threading
-from functools import partial
+from functools import lru_cache, partial
 from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -205,19 +219,29 @@ def pt_double(p, ksub, fe=_FE_EAGER, kd=None):
 
 
 # ---------------------------------------------------------------------------
-# Constant tables: [0..15]B in niels form
+# Constant tables: [0..15]B in niels form, at every window offset
 # ---------------------------------------------------------------------------
 
+# A resident launch reads its lanes' window tables at K offsets of the
+# scalar: digit t = k * (nwin // K) + w is looked up in table k, which holds
+# [0..15] * 16^((nwin // K) * (K - 1 - k)) * P, so the K digits of one w
+# share the 4 doublings between w and w + 1 (64 doublings a signature where
+# one table serving all 64 windows needs 256).
+K = 4
+NROW = 24  # a resident entry's limbs on sublanes: NLIMB up to the 8-row tile
+_B_COL = 52  # first column of the B tables at the K offsets in _consts()
 
-def _build_b_niels() -> np.ndarray:
-    """(16, 3, 20) uint32: (y+x, y-x, 2dxy) limbs of j*B, identity at j=0."""
+
+def _build_b_niels(scale: int = 1) -> np.ndarray:
+    """(16, 3, 20) uint32: (y+x, y-x, 2dxy) limbs of j*scale*B, identity at
+    j=0."""
     out = np.zeros((16, 3, NLIMB), dtype=np.uint32)
-    Bpt = (_ed.B_AFFINE, _ed._BY)
+    base = _ed.pt_scalar_mult(_ed._to_extended((_ed.B_AFFINE, _ed._BY)), scale)
     for j in range(16):
         if j == 0:
             x, y = 0, 1
         else:
-            ext = _ed.pt_scalar_mult(_ed._to_extended(Bpt), j)
+            ext = _ed.pt_scalar_mult(base, j)
             zinv = pow(ext[2], P - 2, P)
             x, y = ext[0] * zinv % P, ext[1] * zinv % P
         out[j, 0] = int_to_limbs((y + x) % P)
@@ -226,20 +250,30 @@ def _build_b_niels() -> np.ndarray:
     return out
 
 
-_B_NIELS = _build_b_niels()
+@lru_cache(maxsize=None)
+def _consts(stride: int = NWIN // K) -> np.ndarray:
+    """All per-limb constants bundled into one (20, 52 + 48 K) kernel input
+    (Pallas kernels cannot capture array constants): columns 0..15 = ypx of
+    [j]B, 16..31 = ymx, 32..47 = t2d, 48 = 2d, 49 = the fe_sub K constant,
+    50 = KD (the wide zero the lazy carry plan sizes for deferred-class
+    subtraction); from _B_COL on, the same three groups of 16 for
+    [j] * 16^(stride * (K - 1 - k)) * B, k = 0..K-1: the B side of a
+    resident launch, whose windows are ``stride`` apart."""
+    out = np.zeros((NLIMB, _B_COL + 48 * K), dtype=np.uint32)
+    tables = [(0, _build_b_niels())] + [
+        (_B_COL + 48 * k, _build_b_niels(16 ** (stride * (K - 1 - k))))
+        for k in range(K)]
+    for col, niels in tables:
+        for c in range(3):
+            out[:, col + 16 * c:col + 16 * (c + 1)] = niels[:, c].T
+    out[:, 48] = _D2_LIMBS
+    out[:, 49] = _K_SUB
+    out[:, 50] = np.asarray(_fc.derive_carry_plan("ed25519").kd, np.uint32)
+    return out
 
-# All per-limb constants bundled into one (20, 52) kernel input (Pallas
-# kernels cannot capture array constants): columns 0..15 = ypx of [j]B,
-# 16..31 = ymx, 32..47 = t2d, 48 = 2d, 49 = the fe_sub K constant, 50 = KD
-# (the wide zero the lazy carry plan sizes for deferred-class subtraction).
-_CONSTS = np.zeros((NLIMB, 52), dtype=np.uint32)
-for _j in range(16):
-    _CONSTS[:, _j] = _B_NIELS[_j, 0]
-    _CONSTS[:, 16 + _j] = _B_NIELS[_j, 1]
-    _CONSTS[:, 32 + _j] = _B_NIELS[_j, 2]
-_CONSTS[:, 48] = _D2_LIMBS
-_CONSTS[:, 49] = _K_SUB
-_CONSTS[:, 50] = np.asarray(_fc.derive_carry_plan("ed25519").kd, np.uint32)
+
+_B_NIELS = _build_b_niels()
+_CONSTS = _consts()
 
 
 # ---------------------------------------------------------------------------
@@ -279,8 +313,37 @@ def _canonical_ref(v, s1, s2):
     return jnp.where(ge, s2[:], s1[:])
 
 
+def _identity(B: int):
+    """The neutral element (0, 1, 1, 0) in (20, B) limbs."""
+    zero = jnp.zeros((NLIMB, B), jnp.uint32)
+    one = jnp.pad(jnp.ones((1, B), jnp.uint32), ((0, NLIMB - 1), (0, 0)))
+    return zero, one, one, zero
+
+
+def _cached_multiples(p1, d2, ksub, fe, kd):
+    """[0..15]p1 for an extended point p1: evens by doubling, odds by +p1;
+    under lazy in cached-niels form (one mulF + two carries an entry buys a
+    pt_add_cached per window: 353 vs 457 row-slots of carry work)."""
+    tbl = [_identity(p1[0].shape[1]), p1]
+    for j in range(2, 16):
+        tbl.append(pt_double(tbl[j // 2], ksub, fe, kd) if j % 2 == 0
+                   else pt_add(tbl[j - 1], p1, d2, ksub, fe, kd))
+    if fe.carry_mode == "lazy":
+        tbl = [pt_to_cached(t, d2, ksub, fe) for t in tbl]
+    return tbl
+
+
+def _select16(entry, mask16):
+    """The entry a lane's digit names: entry(j) -> (rows, B) or (rows, 1),
+    mask16 a list of (1, B) uint32 one-hot masks."""
+    acc = entry(0) * mask16[0]
+    for j in range(1, 16):
+        acc = acc + entry(j) * mask16[j]
+    return acc
+
+
 def ladder_math(consts, negax, ay, digs_get, digh_get, nwin: int = NWIN,
-                loop=lax.fori_loop, carry_mode: str = "lazy"):
+                loop=lax.fori_loop, carry_mode: str = "lazy", tables=None):
     """The windowed-Straus double-scalar multiply [s]B + [h](-A) — pure jnp,
     shared by the pallas kernel (on ref values) and the CPU parity tests
     (tests/test_pallas_interpret.py).  digs_get/digh_get: t -> (1, B)
@@ -290,74 +353,113 @@ def ladder_math(consts, negax, ay, digs_get, digh_get, nwin: int = NWIN,
     eagerly (XLA's CPU compile of these graphs runs minutes — its
     simplifier thrashes on the carry patterns).  carry_mode picks eager (one
     carry ripple per field op) or lazy (one per point op; the default).
+
+    Two forms of one algorithm.  BUILT (``tables`` None): the table
+    [0..15](-A) is made from negax/ay in every lane, and each of the nwin
+    windows is 4 doublings, one add from [0..15]B and one from that table.
+    RESIDENT (``tables`` given, lazy only): ``tables(m)`` -> (20, B) is row
+    m = (4 k + c) * 16 + j of the lanes' window tables, coordinate c of the
+    cached-niels [j] * 16^((nwin // K) * (K - 1 - k)) * (-A) as
+    ``window_tables_math`` makes them (negax/ay are not read); nwin // K
+    rounds of 4 doublings, K adds from the B tables at the same offsets
+    (``_consts(nwin // K)``) and K from the lanes'.  Same group element.
+
     Returns (X, Y, Z, T) with limbs in the certified carried class of the
     active mode (congruent mod p across modes)."""
     fe = _get_fe(carry_mode)
     lazy = carry_mode == "lazy"
-    B = negax.shape[1]
-    zero = jnp.zeros((NLIMB, B), jnp.uint32)
-    one = jnp.pad(jnp.ones((1, B), jnp.uint32), ((0, NLIMB - 1), (0, 0)))
+    ident = _identity((digs_get(0) if negax is None else negax).shape[1])
     d2 = consts[:, 48:49]
     ksub = consts[:, 49:50]
     kd = consts[:, 50:51] if lazy else None
 
-    ident = (zero, one, one, zero)
-    a1 = (negax, ay, one, fe.mul(negax, ay))
+    def one_hot(d):  # (1, B) digits -> 16 (1, B) masks
+        return [(d == j).astype(jnp.uint32) for j in range(16)]
 
-    # per-signature table [0..15](-A): evens by doubling, odds by +(-A)
-    tbl = [ident, a1]
-    for j in range(2, 16):
-        tbl.append(pt_double(tbl[j // 2], ksub, fe, kd) if j % 2 == 0
-                   else pt_add(tbl[j - 1], a1, d2, ksub, fe, kd))
-    if lazy:
-        # cached-niels conversion: one mulF + two carries per entry buys a
-        # pt_add_cached per window (353 vs 457 row-slots of carry work)
-        tbl = [pt_to_cached(t, d2, ksub, fe) for t in tbl]
-    tbl_x = jnp.stack([t[0] for t in tbl])  # (16, 20, B)
-    tbl_y = jnp.stack([t[1] for t in tbl])
-    tbl_z = jnp.stack([t[2] for t in tbl])
-    tbl_t = jnp.stack([t[3] for t in tbl])
+    def add_b(acc, mk_s, col):
+        # constant niels entry for the B part: (20, 1) x (1, B) masked sum
+        ypx, ymx, t2d = (
+            _select16(lambda j: consts[:, col + 16 * c + j:col + 16 * c + j + 1],
+                      mk_s) for c in range(3))
+        return pt_madd(acc, ypx, ymx, t2d, ksub, fe, kd)
 
-    def select16(stacked, mask16):
-        # stacked (16, 20, B), mask16 list of (1, B) uint32 one-hot masks
-        acc = stacked[0] * mask16[0]
-        for j in range(1, 16):
-            acc = acc + stacked[j] * mask16[j]
-        return acc
+    if tables is None:
+        # one table serving every window: the resident form at K = 1, its
+        # rows the stack [0..15](-A) made here, (16, 20, B) a coordinate
+        a1 = (negax, ay, ident[1], fe.mul(negax, ay))
+        tbl = [jnp.stack(c) for c in zip(*_cached_multiples(a1, d2, ksub, fe, kd))]
+        offsets, b_col = 1, 0
+        entry = lambda k, c, j: tbl[c][j]
+    else:
+        if not lazy or nwin % K:
+            raise ValueError("resident tables: lazy carries, nwin a multiple of K")
+        offsets, b_col = K, _B_COL
+        entry = lambda k, c, j: tables((4 * k + c) * 16 + j)
+    stride = nwin // offsets
 
-    def body(t, acc):
+    def round_(w, acc):
         for _ in range(4):
             acc = pt_double(acc, ksub, fe, kd)
-        ds = digs_get(t)  # (1, B)
-        dh = digh_get(t)
-        mk_s = [(ds == j).astype(jnp.uint32) for j in range(16)]
-        mk_h = [(dh == j).astype(jnp.uint32) for j in range(16)]
-        # constant niels entry for the B part: (20, 1) x (1, B) masked sum
-        ypx = sum(consts[:, j : j + 1] * mk_s[j] for j in range(16))
-        ymx = sum(consts[:, 16 + j : 17 + j] * mk_s[j] for j in range(16))
-        t2d = sum(consts[:, 32 + j : 33 + j] * mk_s[j] for j in range(16))
-        acc = pt_madd(acc, ypx, ymx, t2d, ksub, fe, kd)
-        q = (select16(tbl_x, mk_h), select16(tbl_y, mk_h),
-             select16(tbl_z, mk_h), select16(tbl_t, mk_h))
-        acc = (pt_add_cached(acc, q, ksub, kd, fe) if lazy
-               else pt_add(acc, q, d2, ksub, fe))
+        for k in range(offsets):
+            t = k * stride + w if k else w
+            acc = add_b(acc, one_hot(digs_get(t)), b_col + 48 * k)
+            mk_h = one_hot(digh_get(t))
+            q = tuple(_select16(lambda j: entry(k, c, j), mk_h)
+                      for c in range(4))
+            acc = (pt_add_cached(acc, q, ksub, kd, fe) if lazy
+                   else pt_add(acc, q, d2, ksub, fe))
         return acc
 
-    return loop(0, nwin, body, ident)
+    return loop(0, stride, round_, ident)
 
 
-def _ladder_kernel(consts_ref, negax_ref, ay_ref, digs_ref, digh_ref,
-                   rlimb_ref, rsign_ref, out_ref, s1, s2,
-                   carry_mode: str = "lazy"):
+def window_tables_math(consts, negax, ay, put, stride: int = NWIN // K,
+                       loop=lax.fori_loop):
+    """A member's window tables from its -A (negax/ay (20, B), lanes are
+    members): ``put(m, rows)`` is handed row m = (4 k + c) * 16 + j,
+    coordinate c of the cached-niels [j] * 16^(stride * (K - 1 - k)) * (-A)
+    (identity at j = 0), for every k, c, j; between two offsets the point is
+    doubled 4 * stride times.  ``put`` takes a traced m (a ref store
+    in-kernel, a list in tests).  The point operations are the ladder's, in
+    lazy carries: every entry is in the class the ladder's adds read."""
+    fe = _get_fe("lazy")
+    one = _identity(negax.shape[1])[1]
+    d2, ksub, kd = consts[:, 48:49], consts[:, 49:50], consts[:, 50:51]
+
+    def offset(i, p):  # the i-th offset from the scalar's low end: k = K-1-i
+        for j, entry in enumerate(_cached_multiples(p, d2, ksub, fe, kd)):
+            for c in range(4):
+                put((4 * (K - 1 - i) + c) * 16 + j, entry[c])
+        return loop(0, 4 * stride,
+                    lambda _, q: pt_double(q, ksub, fe, kd), p)
+
+    # one body for every offset: the doublings behind the last table are
+    # wasted once a membership, and nothing is traced twice
+    loop(0, K, offset, (negax, ay, one, fe.mul(negax, ay)))
+
+
+def _ladder_kernel(consts_ref, *refs, carry_mode: str = "lazy",
+                   resident: bool = False):
+    # the lanes' keys come as their window tables (resident) or as -A's
+    # limbs, from which the kernel builds one table itself
+    if resident:
+        tbl_ref, *refs = refs
+        negax = ay = None
+        tables = lambda m: tbl_ref[m, 0:NLIMB, :]
+    else:
+        negax_ref, ay_ref, *refs = refs
+        negax, ay, tables = negax_ref[:], ay_ref[:], None
+    digs_ref, digh_ref, rlimb_ref, rsign_ref, out_ref, s1, s2 = refs
     # window count comes from the digit rows: production always passes
     # (NWIN, B), while reduced parity tests drive the identical math with
     # fewer windows (small scalars)
     X, Y, Z, _T = ladder_math(
-        consts_ref[:], negax_ref[:], ay_ref[:],
+        consts_ref[:], negax, ay,
         lambda t: digs_ref[pl.ds(t, 1), :],
         lambda t: digh_ref[pl.ds(t, 1), :],
         nwin=digs_ref.shape[0],
         carry_mode=carry_mode,
+        tables=tables,
     )
 
     # Under lazy, fe.inv/fe.mul run on mulF and keep the epilogue inside the
@@ -372,24 +474,57 @@ def _ladder_kernel(consts_ref, negax_ref, ay_ref, digs_ref, digh_ref,
 
 
 def _ladder_call(negax, ay, digs, digh, rlimb, rsign, *, interpret=False,
-                 lanes=LANES, carry_mode="lazy"):
+                 lanes=LANES, carry_mode="lazy", tables=None):
     """negax/ay/rlimb (20, N), digs/digh (nwin, N) — NWIN=64 in production,
-    fewer in the reduced interpret tests — rsign (1, N); N % lanes == 0."""
-    n = negax.shape[1]
-    nwin = digs.shape[0]
-    cspec = pl.BlockSpec(_CONSTS.shape, lambda i: (0, 0), memory_space=pltpu.VMEM)
+    fewer in the reduced interpret tests — rsign (1, N); N % lanes == 0.
+    ``tables`` (64 K, NROW, N): the lanes' window tables in place of
+    negax/ay, the resident form of ``ladder_math``."""
+    nwin, n = digs.shape
+    resident = tables is not None
+    consts = _consts(nwin // K) if resident else _CONSTS
+    cspec = pl.BlockSpec(consts.shape, lambda i: (0, 0), memory_space=pltpu.VMEM)
     spec20 = pl.BlockSpec((NLIMB, lanes), lambda i: (0, i), memory_space=pltpu.VMEM)
     spec64 = pl.BlockSpec((nwin, lanes), lambda i: (0, i), memory_space=pltpu.VMEM)
     spec1 = pl.BlockSpec((1, lanes), lambda i: (0, i), memory_space=pltpu.VMEM)
+    tspec = pl.BlockSpec((64 * K, NROW, lanes), lambda i: (0, 0, i),
+                         memory_space=pltpu.VMEM)
+    keys, kspecs = ((tables,), [tspec]) if resident else (
+        (negax, ay), [spec20, spec20])
     return pl.pallas_call(
-        partial(_ladder_kernel, carry_mode=carry_mode),
+        partial(_ladder_kernel, carry_mode=carry_mode, resident=resident),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.uint32),
         grid=(n // lanes,),
-        in_specs=[cspec, spec20, spec20, spec64, spec64, spec20, spec1],
+        in_specs=[cspec, *kspecs, spec64, spec64, spec20, spec1],
         out_specs=spec1,
         scratch_shapes=[pltpu.VMEM((NLIMB, lanes), jnp.uint32)] * 2,
         interpret=interpret,
-    )(jnp.asarray(_CONSTS), negax, ay, digs, digh, rlimb, rsign)
+    )(jnp.asarray(consts), *keys, digs, digh, rlimb, rsign)
+
+
+def _windows_kernel(consts_ref, negax_ref, ay_ref, out_ref):
+    def put(m, rows):
+        out_ref[pl.ds(m, 1), :, :] = jnp.pad(
+            rows, ((0, NROW - NLIMB), (0, 0)))[None]
+
+    window_tables_math(consts_ref[:], negax_ref[:], ay_ref[:], put)
+
+
+def _windows_call(negax, ay, *, interpret=False, lanes=LANES):
+    """negax/ay (20, N) -> (64 K, NROW, N): every lane's window tables, rows
+    as ``ladder_math`` reads them; N % lanes == 0."""
+    n = negax.shape[1]
+    cspec = pl.BlockSpec(_CONSTS.shape, lambda i: (0, 0), memory_space=pltpu.VMEM)
+    spec20 = pl.BlockSpec((NLIMB, lanes), lambda i: (0, i), memory_space=pltpu.VMEM)
+    tspec = pl.BlockSpec((64 * K, NROW, lanes), lambda i: (0, 0, i),
+                         memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        _windows_kernel,
+        out_shape=jax.ShapeDtypeStruct((64 * K, NROW, n), jnp.uint32),
+        grid=(n // lanes,),
+        in_specs=[cspec, spec20, spec20],
+        out_specs=tspec,
+        interpret=interpret,
+    )(jnp.asarray(_CONSTS), negax, ay)
 
 
 # ---------------------------------------------------------------------------
@@ -717,7 +852,7 @@ _device_verify_jit = partial(
 
 @partial(jax.jit, static_argnames=("lanes", "carry_mode"))
 def _device_verify_packed(negax, ay, pub_words, sig_words, tmpl, vidx, vwords,
-                          lanes=LANES, carry_mode="lazy"):
+                          tables=None, lanes=LANES, carry_mode="lazy"):
     """Transfer-minimizing verify: the padded SHA-512 input is ASSEMBLED ON
     DEVICE instead of shipped from the host.
 
@@ -731,6 +866,9 @@ def _device_verify_packed(negax, ay, pub_words, sig_words, tmpl, vidx, vwords,
     tmpl (rows,) BE u32 — padded SHA input of batch row 0; vidx (k,) i32 —
     word rows >= 16 whose value varies per signature; vwords (b, k) BE u32 —
     those rows' values. Rows 0..15 (R || A) always come from sig/pub words.
+    ``tables`` (64 K, NROW, b): the lanes' window tables as a membership's
+    table holds them; given, the ladder takes its resident form and negax/ay
+    are not read.
     """
     b = negax.shape[0]
     rows = tmpl.shape[0]
@@ -745,7 +883,7 @@ def _device_verify_packed(negax, ay, pub_words, sig_words, tmpl, vidx, vwords,
     mw = mw.at[vidx, :].set(vwords.T)
     digs, digh, rlimb, rsign = _prologue_call(mw, sig_words.T, lanes=lanes)
     ok = _ladder_call(negax.T, ay.T, digs, digh, rlimb, rsign, lanes=lanes,
-                      carry_mode=carry_mode)
+                      carry_mode=carry_mode, tables=tables)
     return ok[0].astype(bool)
 
 
@@ -837,33 +975,43 @@ def _upload_valset(pubs, neg_ax, ay, b, key: Optional[bytes] = None):
 
 
 # A membership a table: the lanes of a call whose caller knows them as rows
-# of a key array it keeps (``ValsetRows`` with slots: a commit with absent
-# slots, another subset every height) are gathered by row from what is
-# derived ONCE from that array, on the host and on the device.  The two
-# whole-array caches above know a call by its own key array, which is new at
-# every such height.
+# of a key array it keeps (``ValsetRows``: a commit's present slots, another
+# subset every height, or every slot) are gathered by row from what is
+# derived ONCE from that array, on the host and on the device: the key limbs
+# and words, and the window tables the ladder's resident form reads.  The
+# two whole-array caches above know a call by its own key array, which is
+# new at every height of a live chain; they serve the callers that hand
+# down no identity.
 _valset_tables: dict = {}  # key_id -> _ValsetTable, least recently used first
 _VALSET_TABLES_MAX = 4  # a node holds the current, the next and the last set
 _valset_tables_mtx = threading.Lock()
 _TABLE_WORDS = 2 * NLIMB + 8  # a device row: negax | ay | key words
+_WINDOW_WORDS = 64 * K * NROW  # a member's window tables, a device row
 
 
 class _ValsetTable:
     """One membership's key material, a row a member."""
 
-    __slots__ = ("keys", "neg_ax", "ay", "valid", "device")
+    __slots__ = ("keys", "neg_ax", "ay", "valid", "device", "whole")
 
     def __init__(self, keys, neg_ax, ay, valid):
         self.keys = keys  # (N, 32) u8, the caller's own array
         self.neg_ax, self.ay, self.valid = neg_ax, ay, valid  # (N, 20) x2, (N,)
-        # (N + 1, 48) u32 on the device, negax | ay | key words a row and
-        # row N all zero (a bucket's padding lanes); None until a launch
+        # on the device, None until a launch: (R, 48) u32, negax | ay | key
+        # words a row, R = _bucket(N + 1) so that programs are a bucket's
+        # and not a member count's, rows N.. zero (row N is the launch's
+        # padding lanes'); and, from the first launch that reads them on,
+        # (R, _WINDOW_WORDS) u32, the members' window tables
         self.device = None
+        # bucket -> what a resident launch of every member in order was
+        # handed: the gather's result, kept
+        self.whole = {}
 
 
 def _valset_table(rows: ValsetRows) -> _ValsetTable:
     """The table of ``rows.keys``, filled at a membership's first call.  A
-    handful are resident and the least recently used one goes alone."""
+    handful are resident and the least recently used one goes alone, its
+    device arrays with it."""
     with _valset_tables_mtx:
         table = _valset_tables.pop(rows.key_id, None)
         if table is not None:
@@ -875,30 +1023,67 @@ def _valset_table(rows: ValsetRows) -> _ValsetTable:
     keys = np.ascontiguousarray(rows.keys, dtype=np.uint8)
     table = _ValsetTable(keys, *_decompress_rows(keys))
     with _valset_tables_mtx:
+        # another caller may have filled it meanwhile: one table a
+        # membership, so one build of its window tables
+        other = _valset_tables.get(rows.key_id)
+        if other is not None:
+            return other
         while len(_valset_tables) >= _VALSET_TABLES_MAX:
             del _valset_tables[next(iter(_valset_tables))]
         _valset_tables[rows.key_id] = table
     return table
 
 
-def _table_on_device(table: _ValsetTable):
+@jax.jit
+def _build_valset_windows(table):
+    """A membership's (R, 48) device rows -> (R, _WINDOW_WORDS): every
+    member's window tables, a row a member in the order the ladder reads a
+    lane's (row m of ``window_tables_math``, NROW limbs each).  The zero rows
+    past the members give what a key that is no point gives: no lane's
+    verdict reads either."""
+    limbs = table[:, :2 * NLIMB].T
+    out = _windows_call(limbs[:NLIMB], limbs[NLIMB:])
+    return out.reshape(_WINDOW_WORDS, table.shape[0]).T
+
+
+def _table_on_device(table: _ValsetTable, windows: bool):
+    """The membership's device rows and, where the launch reads them
+    (``windows``), its window tables: the rows go up at the membership's
+    first launch, the tables are built at its first resident one (the same
+    launch, but for an eager one)."""
+    n = table.valid.shape[0]
+
+    def built(rows):
+        # what a set change costs a live node, beside the upload
+        with trace.span("valset.tables", members=n,
+                        bytes=4 * rows.shape[0] * _WINDOW_WORDS):
+            return call_jit(_build_valset_windows, rows)
+
     if table.device is None:
-        n = table.valid.shape[0]
         with trace.span("valset.miss", cache="device", lanes=n,
-                        bytes=4 * (n + 1) * _TABLE_WORDS):
+                        bytes=4 * _bucket(n + 1) * _TABLE_WORDS):
             pub_words = table.keys.view("<u4").astype(np.uint32)
-            table.device = jnp.asarray(_pad_rows(np.concatenate(
-                [table.neg_ax, table.ay, pub_words], axis=1), n + 1))
-    return table.device
+            rows = jnp.asarray(_pad_rows(np.concatenate(
+                [table.neg_ax, table.ay, pub_words], axis=1), _bucket(n + 1)))
+            table.device = rows, built(rows) if windows else None
+    elif windows and table.device[1] is None:
+        table.device = table.device[0], built(table.device[0])
+    rows, tables = table.device
+    return rows, tables if windows else None
 
 
 @jax.jit
-def _gather_valset_rows(table, idx):
-    """Rows ``idx`` of a membership's device table as the three key arrays
+def _gather_valset_rows(table, windows, idx):
+    """Rows ``idx`` of a membership's device arrays as what
     ``_device_verify_packed`` takes: (b, 20) negax, (b, 20) ay, (b, 8) key
-    words."""
+    words, and, of ``windows`` where the launch reads them (else None), the
+    window tables in the kernel's layout, (64 K, NROW, b)."""
     rows = jnp.take(table, idx, axis=0, mode="clip")
-    return rows[:, :NLIMB], rows[:, NLIMB:2 * NLIMB], rows[:, 2 * NLIMB:]
+    tables = None if windows is None else jnp.take(
+        windows, idx, axis=0, mode="clip").T.reshape(
+            64 * K, NROW, idx.shape[0])
+    return (rows[:, :NLIMB], rows[:, NLIMB:2 * NLIMB], rows[:, 2 * NLIMB:],
+            tables)
 
 
 def _table_index(slots: np.ndarray, n_members: int, b: int) -> np.ndarray:
@@ -912,7 +1097,7 @@ def _table_index(slots: np.ndarray, n_members: int, b: int) -> np.ndarray:
 class _OwnKeys(NamedTuple):
     """A launch's key limbs where the call's keys are known by themselves:
     decompressed for this key array, and on the device a copy a (key array,
-    bucket)."""
+    bucket).  No window tables: the ladder builds one a lane."""
 
     neg_ax: np.ndarray
     ay: np.ndarray
@@ -924,27 +1109,40 @@ class _OwnKeys(NamedTuple):
     def on_host(self):
         return self.neg_ax, self.ay
 
-    def on_device(self, pubs, b):
-        return _upload_valset(pubs, self.neg_ax, self.ay, b, self.key)
+    def on_device(self, pubs, b, _resident):
+        return (*_upload_valset(pubs, self.neg_ax, self.ay, b, self.key), None)
 
 
 class _TableKeys(NamedTuple):
-    """A launch's key limbs as rows of a membership's table: an index goes
-    up and the device gathers."""
+    """A launch's key limbs and window tables as rows of a membership's
+    table: an index goes up and the device gathers.  ``slots`` None: every
+    member in order, whose gather is made once a bucket."""
 
     table: _ValsetTable
-    slots: np.ndarray
+    slots: Optional[np.ndarray]
 
     def take(self, idx):
-        return _TableKeys(self.table, self.slots[idx])
+        return _TableKeys(
+            self.table, idx if self.slots is None else self.slots[idx])
 
     def on_host(self):
+        if self.slots is None:
+            return self.table.neg_ax, self.table.ay
         return self.table.neg_ax[self.slots], self.table.ay[self.slots]
 
-    def on_device(self, _pubs, b):
-        idx = _table_index(self.slots, self.table.valid.shape[0], b)
-        return call_jit(_gather_valset_rows, _table_on_device(self.table),
-                        jnp.asarray(idx))
+    def on_device(self, _pubs, b, resident):
+        table = self.table
+        n = table.valid.shape[0]
+        rows, windows = _table_on_device(table, resident)
+        keep = self.slots is None and windows is not None
+        if keep and b in table.whole:
+            return table.whole[b]
+        idx = _table_index(np.arange(n) if self.slots is None else self.slots,
+                           n, b)
+        out = call_jit(_gather_valset_rows, rows, windows, jnp.asarray(idx))
+        if keep:
+            table.whole[b] = out
+        return out
 
 
 def _bucket(n: int, lanes: int = LANES) -> int:
@@ -978,9 +1176,11 @@ def verify_batch(pubs: np.ndarray, msgs: Sequence[bytes], sigs: np.ndarray,
     `carry_mode` picks the eager or deferred (lazy) carry schedule — both
     bit-exact at the canonical boundary.  ``msgs`` may be an (n, ln) uint8
     array (one length, known from its shape).  ``valset`` says which rows
-    of a key array the caller keeps ``pubs`` are: every row in order
-    (``slots`` None: the two whole-array caches, known by its ``key_id``)
-    or ``keys[slots]`` (the membership's table, gathered by slot)."""
+    of a key array the caller keeps ``pubs`` are, every row in order
+    (``slots`` None) or ``keys[slots]``: the lanes' limbs and window tables
+    are gathered from the membership's table and the ladder runs in its
+    resident form.  Without it the call's keys are known by themselves (the
+    two whole-array caches) and the ladder builds a table a lane."""
     carry_mode = _fc.normalize_carry_mode(carry_mode)
     pubs = np.ascontiguousarray(pubs, dtype=np.uint8)
     sigs = np.ascontiguousarray(sigs, dtype=np.uint8)
@@ -996,19 +1196,21 @@ def verify_batch(pubs: np.ndarray, msgs: Sequence[bytes], sigs: np.ndarray,
     # is not packing.  One length (a commit, a sync window) goes down as the
     # caller's own columns; several are regrouped, one launch a length
     with trace.span("dispatch.prepare", n=n) as sp:
-        if valset is not None and valset.slots is not None:
-            slots = np.asarray(valset.slots)
+        if valset is not None:
             # what the device gathers is what the lanes say: a caller whose
             # slots name other keys is refused, not verified against them
-            if slots.shape != (n,) or slots.min() < 0 or not np.array_equal(
-                    valset.keys[slots], pubs):
+            slots = None if valset.slots is None else np.asarray(valset.slots)
+            named = valset.keys
+            if slots is not None:
+                named = named[slots] if (
+                    slots.shape == (n,) and slots.min() >= 0) else None
+            if named is not pubs and not np.array_equal(named, pubs):
                 raise ValueError("valset.keys[valset.slots] are not the keys")
             table = _valset_table(valset)
-            limbs, valid = _TableKeys(table, slots), table.valid[slots]
+            limbs = _TableKeys(table, slots)
+            valid = table.valid if slots is None else table.valid[slots]
         else:
-            if valset is not None and valset.keys.shape != pubs.shape:
-                raise ValueError("valset.keys are not the keys")
-            key = _valset_key(pubs) if valset is None else valset.key_id
+            key = _valset_key(pubs)  # one hash for both whole-array caches
             neg_ax, ay, valid = _decompress_valset(pubs, key)
             limbs = _OwnKeys(neg_ax, ay, key)
         valid = valid & ((sigs[:, 63] & 224) == 0)  # Go's only s range check
@@ -1214,13 +1416,18 @@ def _verify_uniform(pubs, msgs, sigs, limbs, valid, ln, interpret,
             sig_words = _sig_words(sigs, valid, b)
             tmpl, vrows, vwords = pack_variable_words(pubs, msgs, sigs, ln, b)
             sp.set(vwords=int(vrows.size))
-        with trace.span("dispatch.launch", lanes=b):  # copies in + enqueue
-            negax_d, ay_d, pubw_d = limbs.on_device(pubs, b)
+        with trace.span("dispatch.launch", lanes=b) as sp:  # copies in + enqueue
+            # the resident form is written in lazy carries
+            *keys_d, tables = limbs.on_device(pubs, b, carry_mode == "lazy")
+            form = "built" if tables is None else "resident"
+            sp.set(tables=form)
+            get_verify_metrics().ed25519_ladder_lanes.add(float(b), (form,))
             out = call_jit(
                 _device_verify_packed,
-                negax_d, ay_d, pubw_d,
+                *keys_d,
                 jnp.asarray(sig_words),
                 jnp.asarray(tmpl), jnp.asarray(vrows), jnp.asarray(vwords),
+                *(() if tables is None else (tables,)),
                 lanes=lanes, carry_mode=carry_mode,
             )
         # the device's run, the copy back and the wake of this thread
@@ -1241,6 +1448,7 @@ def _verify_uniform(pubs, msgs, sigs, limbs, valid, ln, interpret,
     msg_words = np.ascontiguousarray(msg_words).view("<u4").astype(np.uint32)
 
     neg_ax, ay = limbs.on_host()
+    get_verify_metrics().ed25519_ladder_lanes.add(float(b), ("built",))
     ok = np.asarray(
         _device_verify(
             jnp.asarray(_pad_rows(neg_ax, b)),

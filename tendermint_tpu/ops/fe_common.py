@@ -1187,11 +1187,16 @@ def _carry_op_costs(curve: str, carry_mode: str) -> dict:
 
 def carry_cost_model(curve: str = "ed25519", carry_mode: str = "lazy") -> dict:
     """Per-signature carry-round cost in row-slots (see module comment).
-    Composition mirrors the Pallas kernels: 64 windows of 4 doubles + 1
-    niels madd + 1 table add for ed25519 (plus table build, cached-table
-    conversion under lazy, and the 265-mul inversion); 64 windows of 6
-    RCB16 adds for secp256k1 (plus the 15-add table and the inversion-free
-    projective epilogue)."""
+    Composition mirrors the Pallas kernels.  ed25519's ladder has two forms
+    (ops/ed25519_pallas.ladder_math): BUILT, 64 windows of 4 doubles + 1
+    niels madd + 1 table add, plus the table build and its cached-table
+    conversion under lazy (``per_window``, ``table``, ``per_signature``);
+    RESIDENT, where the lanes' window tables at K = 4 offsets come from the
+    device, 16 rounds of 4 doubles + 4 niels madds + 4 cached adds and no
+    build (``per_round_resident``, ``per_signature_resident``; lazy only).
+    Both end in the 265-mul inversion.  secp256k1: 64 windows of 6 RCB16
+    adds, plus the 15-add table and the inversion-free projective
+    epilogue."""
     if carry_mode not in CARRY_MODES:
         raise ValueError(f"carry mode must be one of {CARRY_MODES}, got {carry_mode!r}")
     costs = _carry_op_costs(curve, carry_mode)
@@ -1208,11 +1213,17 @@ def carry_cost_model(curve: str = "ed25519", carry_mode: str = "lazy") -> dict:
                  + 16 * point["niels_convert"])
         inv = 265 * mul1
         per_sig = 64 * window + table + inv
-        return {
+        out = {
             "curve": curve, "carry_mode": carry_mode, "unit": "row-slots",
             "per_op": costs, "per_point_op": point, "per_window": window,
             "table": table, "inv": inv, "per_signature": per_sig,
         }
+        if carry_mode == "lazy":
+            round_ = (4 * point["pt_double"]
+                      + 4 * (point["pt_madd"] + point["pt_add_cached"]))
+            out["per_round_resident"] = round_
+            out["per_signature_resident"] = 16 * round_ + inv
+        return out
     if curve == "secp256k1":
         mix = _SECP_POINT_MIX[carry_mode]
         point = {name: op(m) for name, m in mix.items()}

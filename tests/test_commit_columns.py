@@ -536,6 +536,8 @@ def pallas(monkeypatch):
     def fake_call_jit(fn, *args, **static):
         if fn is ep._gather_valset_rows:  # a membership's rows: it runs, here
             return fn(*args)
+        if fn is ep._build_valset_windows:  # its window tables: not here
+            return np.zeros((args[0].shape[0], ep._WINDOW_WORDS), np.uint32)
         assert fn is ep._device_verify_packed
         host = [np.asarray(a) for a in args]
         launches.append(host)
@@ -577,14 +579,18 @@ def test_the_device_is_handed_the_list_paths_arrays(
         ch.valset, CHAIN, block_id, height, commit, verifier=pallas.device))
     assert got == want  # the stand-in's verdicts, tallied alike
     columns, lists = pallas.launches
-    _same_arrays(columns, lists)
+    # the set's own launch is a resident one (PR 47): the reference's seven
+    # arrays and, an eighth, its lanes' window tables
+    assert len(columns) == 8
+    _same_arrays(columns[:7], lists)
     # the spans the per-layer entries read, nested as they were
     spans = [e for e in tracing.export() if e.get("ph") == "X"]
     first_call = [e for e in spans
                   if e["args"]["root_id"] == spans[0]["args"]["root_id"]]
     by_id = {e["args"]["span_id"]: e["name"] for e in first_call}
     parent_of = {e["name"]: by_id.get(e["args"].get("parent_id"))
-                 for e in first_call if e["name"] != "valset.miss"}
+                 for e in first_call
+                 if e["name"] not in ("valset.miss", "valset.tables")}
     assert parent_of == {
         "commit.verify": None,
         "commit.collect": "commit.verify", "verify.generic": "commit.verify",
@@ -878,16 +884,19 @@ def test_a_memberships_keys_are_hashed_once_not_once_a_call(
     vs = ValidatorSet(vs.validators)  # a membership nobody has asked yet
     caches = {
         c: verify_counters("tendermint_verify_valset_cache_total", {"cache": c})
-        for c in ("host", "device")}
+        for c in ("host", "device", "table")}
     for k in range(3):
         with pytest.raises(CommitError, match="invalid signature"):
             (vs.copy_increment_accum(1) if k else vs).verify_commit(
                 CHAIN, BLOCK, HEIGHT, commit, verifier=pallas.device)
     assert pallas.large_hashes == [32 * N_BIG]
     assert len(pallas.launches) == 3
-    for c, before in caches.items():  # both caches still asked once a call
+    # the membership's table is asked once a call, under the identity the
+    # set handed down; neither whole-array cache is shown the keys (PR 47)
+    for c, before in caches.items():
         assert verify_counters(
-            "tendermint_verify_valset_cache_total", {"cache": c}) == before + 3
+            "tendermint_verify_valset_cache_total", {"cache": c}
+        ) == before + (3 if c == "table" else 0)
     # the list form of the same lanes pays it every call, as it did
     lanes = vs.collect_commit_sigs(CHAIN, BLOCK, HEIGHT, commit)[:3]
     for _ in range(2):

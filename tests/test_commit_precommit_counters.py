@@ -149,6 +149,8 @@ def pallas(monkeypatch):
     def fake_call_jit(fn, *args, **static):
         if fn is ep._gather_valset_rows:  # a membership's rows: it runs, here
             return fn(*args)
+        if fn is ep._build_valset_windows:  # its window tables: not here
+            return np.zeros((args[0].shape[0], ep._WINDOW_WORDS), np.uint32)
         assert fn is ep._device_verify_packed
         launches.append(int(np.asarray(args[3]).shape[0]))
         return np.ones((launches[-1],), dtype=bool)

@@ -17,6 +17,8 @@ intermediate reaches 2^32.  If a future edit to the carry/fold chains breaks eit
 these tests fail instead of a comment going stale.
 """
 
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
@@ -259,3 +261,107 @@ class TestBounds:
             c = [b >> BITS for b in bs]
             bs = [min(b, MASK) + s for b, s in zip(bs, [0] + c[:-1])]
         assert bs[2 * NLIMB] > 0
+
+
+class TestResidentRoundBounds:
+    """The ladder's resident form (PR 47) runs, after 4 doublings, K mixed
+    adds from the B tables and K cached adds from a member's window tables
+    IN A ROW, and reads those tables from device memory where the built form
+    made one in VMEM.  The same propagators that certify the lazy plan, run
+    over that sequence's own chain shapes: every point op maps class C to
+    class C and no intermediate reaches 2^32, so what the build program
+    stores is what the ladder's adds were certified to read."""
+
+    @staticmethod
+    def _ops():
+        plan = fc.derive_carry_plan("ed25519")
+        C, KD, KSUB = list(plan.c), list(plan.kd), list(plan.ksub)
+        peaks = []
+
+        def seen(pair):
+            bounds, peak = pair
+            peaks.append(peak)
+            return bounds
+
+        def raw(*terms):
+            return [sum(v) for v in zip(*terms)]
+
+        def mul_l(a, b):
+            return seen(fc.bound_ed_mul_lazy(a, b, wide=plan.mull_wide,
+                                             fix=plan.mull_fix))
+
+        def mul_f(a, b):
+            return seen(fc.bound_ed_mul_lazy(a, b, wide=plan.mulf_wide,
+                                             fix=plan.mulf_fix))
+
+        def norm(r):
+            return seen(fc.bound_ed_norm1(r, fix=plan.norm_fix))
+
+        def out4(E, F, G, H):  # mul4: four mulF sharing one carry tail
+            return mul_f(E, F), mul_f(G, H), mul_f(F, G), mul_f(E, H)
+
+        def pt_double(p):
+            X, Y, Z, _T = p
+            A, B, ZZ = mul_l(X, X), mul_l(Y, Y), mul_l(Z, Z)
+            H = norm(raw(A, B))
+            xy = norm(raw(X, Y))
+            E = norm(raw(H, KD))            # sub(H, mul_lazy(xy, xy), kd)
+            mul_l(xy, xy)
+            G = norm(raw(A, KD))            # sub(A, B, kd)
+            F = norm(raw(raw(ZZ, ZZ), G))   # add(add_raw(ZZ, ZZ), G)
+            return out4(E, F, G, H)
+
+        def pt_madd(p, ypx, ymx, t2d):
+            X, Y, Z, T = p
+            A = mul_l(norm(raw(Y, KSUB)), ymx)
+            B = mul_l(raw(Y, X), ypx)
+            Cc = mul_l(T, t2d)
+            Dv = raw(Z, Z)
+            return out4(norm(raw(B, KD)), norm(raw(Dv, KD)),
+                        norm(raw(Dv, Cc)), norm(raw(B, A)))
+
+        def pt_add_cached(p, q):
+            X, Y, Z, T = p
+            ypx, ymx, Z2, t2d = q
+            A = mul_l(norm(raw(Y, KSUB)), ymx)
+            B = mul_l(raw(Y, X), ypx)
+            Cc = mul_l(T, t2d)
+            Dv = mul_l(raw(Z, Z), Z2)
+            return out4(norm(raw(B, KD)), norm(raw(Dv, KD)),
+                        norm(raw(Dv, Cc)), norm(raw(B, A)))
+
+        def pt_to_cached(p):
+            X, Y, Z, T = p
+            return norm(raw(Y, X)), norm(raw(Y, KSUB)), Z, mul_f(T, C)
+
+        return SimpleNamespace(
+            C=C, peaks=peaks, pt_double=pt_double, pt_madd=pt_madd,
+            pt_add_cached=pt_add_cached, pt_to_cached=pt_to_cached)
+
+    def test_a_resident_round_closes_over_class_c(self):
+        from tendermint_tpu.ops import ed25519_pallas as ep
+
+        ops = self._ops()
+        C = ops.C
+        inside = lambda v: all(x <= c for x, c in zip(v, C))
+        # a B entry is canonical limbs; a member's entry is what the build
+        # stores: pt_to_cached of a class-C point.  A one-hot masked sum of
+        # sixteen entries is bounded by the largest entry
+        niels = ([fc.MASK] * NLIMB,) * 3
+        cached = ops.pt_to_cached((C, C, C, C))
+        assert all(inside(v) for v in cached)
+        acc = (C, C, C, C)
+        for _ in range(2):  # a round, and the round behind it
+            for _ in range(4):
+                acc = ops.pt_double(acc)
+                assert all(inside(v) for v in acc)
+            for _ in range(ep.K):
+                acc = ops.pt_madd(acc, *niels)
+                assert all(inside(v) for v in acc)
+                acc = ops.pt_add_cached(acc, cached)
+                assert all(inside(v) for v in acc)
+        assert max(ops.peaks) < 1 << 32
+
+    def test_a_members_entries_fit_the_words_they_are_stored_in(self):
+        # the build program's rows are uint32 words of class-C limbs
+        assert max(self._ops().C) < 1 << 16

@@ -1,21 +1,26 @@
 """A membership a table: the lanes of a call whose caller knows them as rows
-of a key array it keeps (``crypto/batch.ValsetRows`` with slots: a commit
-with absent slots) are gathered by slot from what the kernel's host wrapper
-derives ONCE from that array, and decided as the whole-array path decides
-them, lane for lane.
+of a key array it keeps (``crypto/batch.ValsetRows``: a commit's present
+slots, or every slot) are gathered by slot from what the kernel's host
+wrapper derives ONCE from that array (the key limbs and words, and since
+PR 47 the window tables the ladder's resident form reads), and decided as
+the whole-array path decides them, lane for lane.
 
-No chip here, and the interpreted kernels take minutes a launch, so the two
+No chip here, and the interpreted kernels take minutes a launch, so the
 programs are stood in for by the host oracle OVER THE ARRAYS THEY ARE HANDED:
 ``_device_verify_packed`` (the packed path, ``call_jit`` stood in for; the
-row gather itself runs, on the CPU) and ``_device_verify`` (the
-interpret-mode reference path).  A lane is true iff its signature verifies
-under the key bytes it was handed AND the limbs it was handed are that key's:
-a lane gathered from the wrong row shows."""
+row gather itself runs, on the CPU), ``_build_valset_windows`` (a member's
+window tables stood in for by its 48 device words repeated, so a lane handed
+another member's tables shows) and ``_device_verify`` (the interpret-mode
+reference path).  A lane is true iff its signature verifies under the key
+bytes it was handed AND the limbs and the tables it was handed are that
+key's: a lane gathered from the wrong row shows.  What the real build
+program and the resident ladder compute is tests/test_resident_ladder.py's."""
 
 import sys
 import threading
 from types import SimpleNamespace
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -59,9 +64,24 @@ def _padded_inputs_ok(padded, negax, ay, sig_words) -> np.ndarray:
     return out
 
 
-def _oracle_packed(negax, ay, pub_words, sig_words, tmpl, vidx, vwords):
-    """``_device_verify_packed`` on the host, from its own seven arrays."""
+def _stand_in_windows(table):
+    """``_build_valset_windows`` stood in for: a member's 48 words, repeated
+    up to the width of its window tables."""
+    table = np.asarray(table)
+    return np.tile(table, (1, ep._WINDOW_WORDS // table.shape[1]))
+
+
+def _oracle_packed(negax, ay, pub_words, sig_words, tmpl, vidx, vwords,
+                   tables=None):
+    """``_device_verify_packed`` on the host, from its own seven arrays, and
+    the lanes' window tables where the launch is a resident one."""
     b = negax.shape[0]
+    if tables is not None:
+        assert tables.shape == (64 * ep.K, ep.NROW, b)
+        own = _stand_in_windows(np.concatenate([negax, ay, pub_words], axis=1))
+        theirs = np.all(tables.reshape(-1, b).T == own, axis=1)
+        return theirs & _oracle_packed(
+            negax, ay, pub_words, sig_words, tmpl, vidx, vwords)
     mw = np.broadcast_to(tmpl, (b, tmpl.shape[0])).copy()
     mw[:, vidx] = vwords
     padded = mw.astype(">u4").view(np.uint8).reshape(b, -1)
@@ -79,19 +99,23 @@ def _oracle_reference(negax, ay, sig_words, msg_words, **_static):
 
 @pytest.fixture
 def programs(monkeypatch):
-    """Both programs stood in for, the caches and the tables empty; every
-    packed launch and every gather recorded."""
-    seen = SimpleNamespace(launches=[], gathers=[])
+    """The programs stood in for, the caches and the tables empty; every
+    packed launch, every gather and every build recorded."""
+    seen = SimpleNamespace(launches=[], gathers=[], builds=[])
 
     def fake_call_jit(fn, *args, **static):
         if fn is ep._gather_valset_rows:
-            seen.gathers.append(np.asarray(args[1]))
+            seen.gathers.append(np.asarray(args[2]))
             return fn(*args)
+        if fn is ep._build_valset_windows:
+            seen.builds.append(args[0].shape[0])  # the program's rows
+            return jnp.asarray(_stand_in_windows(*args))
         assert fn is ep._device_verify_packed
         host = [np.asarray(a) for a in args]
         seen.launches.append(host)
         return _oracle_packed(*host)
 
+    seen.call_jit = fake_call_jit
     monkeypatch.setattr(ep, "call_jit", fake_call_jit)
     monkeypatch.setattr(ep, "_device_verify", _oracle_reference)
     monkeypatch.setattr(ep, "_valset_cache", {})
@@ -114,6 +138,7 @@ def _moved(after, before):
 # ---------------------------------------------------------------------------
 
 N = 40
+ROWS = 128  # a membership's device rows: _bucket(N + 1), the zero rows last
 BAD_KEY = 7  # the member whose 32 bytes are no curve point
 
 
@@ -232,10 +257,13 @@ def test_the_table_paths_verdicts_are_the_whole_array_paths(
         # the whole-array path's launch was
         assert len(programs.gathers) == groups
         assert len(programs.launches) == launches + groups
+        # ... and the lanes' window tables as an eighth: the resident form
         for whole, table in zip(programs.launches[:launches],
                                 programs.launches[launches:]):
+            assert (len(whole), len(table)) == (7, 8)
             for w, t in zip(whole, table):
                 assert w.dtype == t.dtype and w.tobytes() == t.tobytes()
+        assert programs.builds == [ROWS]
     # another subset of the same membership: a hit, nothing filled
     again = _lookups(verify_counters)
     ep.verify_batch(pubs[1:], msgs[1:], sigs[1:], interpret=interpret,
@@ -245,22 +273,45 @@ def test_the_table_paths_verdicts_are_the_whole_array_paths(
 
 
 @pytest.mark.parametrize("interpret", [False, True], ids=["packed", "reference"])
-def test_slots_none_is_the_whole_array_path_under_the_sets_identity(
+def test_slots_none_is_every_row_of_the_memberships_one_table(
         interpret, programs, verify_counters, monkeypatch):
+    """Every slot present (``commit10k-stream``): no hash of the keys a call,
+    neither whole-array cache asked, the membership's one table, whose
+    gather of every member in order is made once a bucket and kept; verdicts
+    as before."""
     slots = np.arange(N)
     msgs, sigs = _signed(slots, [LONG] * N)
     key_id = batch.valset_key(KEYS)
+    want = ep.verify_batch(KEYS, msgs, sigs, interpret=interpret)
     hashed = []
     monkeypatch.setattr(ep, "_valset_key", lambda keys: hashed.append(1) or b"x")
+    monkeypatch.setattr(ep, "_valset_cache", {})
+    monkeypatch.setattr(ep, "_dev_valset_cache", {})
+    launches = len(programs.launches)
+    rows = batch.ValsetRows(key_id, KEYS, None)
     before = _lookups(verify_counters)
-    got = ep.verify_batch(KEYS, msgs, sigs, interpret=interpret,
-                          valset=batch.ValsetRows(key_id, KEYS, None))
-    assert [i for i, ok in enumerate(got) if not ok] == [BAD_KEY]
-    assert hashed == [] and ep._valset_tables == {} and programs.gathers == []
-    assert key_id in ep._valset_cache
-    assert _moved(_lookups(verify_counters), before) == (
-        {("host", "miss"): 1} if interpret
-        else {("host", "miss"): 1, ("device", "miss"): 1})
+    for k in range(2):
+        got = ep.verify_batch(KEYS, msgs, sigs, interpret=interpret, valset=rows)
+        assert got.tolist() == want.tolist()
+        assert [i for i, ok in enumerate(got) if not ok] == [BAD_KEY]
+    assert hashed == [] and ep._valset_cache == {} and ep._dev_valset_cache == {}
+    assert list(ep._valset_tables) == [key_id]
+    assert _moved(_lookups(verify_counters), before) == {
+        ("table", "miss"): 1, ("table", "hit"): 1}
+    if interpret:
+        assert programs.gathers == [] and programs.builds == []
+        return
+    # one build, ONE gather for both calls (rows 0..N-1, then the zero row),
+    # and both launches resident, handed the very arrays that gather made
+    assert programs.builds == [ROWS] and len(programs.gathers) == 1
+    assert programs.gathers[0].tolist() == list(range(N)) + [N] * (128 - N)
+    first, second = programs.launches[launches:]
+    assert len(first) == len(second) == 8
+    # another subset of the same membership is gathered by slot from the
+    # same table: no other build
+    ep.verify_batch(KEYS[3:], msgs[3:], sigs[3:], valset=rows._replace(
+        slots=slots[3:]))
+    assert programs.builds == [ROWS] and len(programs.gathers) == 2
 
 
 @pytest.mark.parametrize("wrong", ["another_slot", "a_negative_slot",
@@ -294,12 +345,20 @@ def test_slots_that_do_not_name_the_lanes_keys_are_refused(wrong, programs):
 def test_the_gather_is_the_padded_rows_of_the_membership(slots, b, programs):
     slots = np.array(slots, dtype=np.int64)
     table = ep._valset_table(batch.ValsetRows(b"id", KEYS, slots))
-    device = ep._table_on_device(table)
-    assert device.shape == (N + 1, 48) and device.dtype == np.uint32
-    assert not np.asarray(device)[N].any()
+    device, none = ep._table_on_device(table, False)
+    assert none is None and programs.builds == []  # an eager launch's: no build
+    assert ep._table_on_device(table, True)[0] is device
+    assert programs.builds == [ROWS]
+    device, windows = ep._table_on_device(table, True)
+    # programs are a bucket's, not a member count's: the rows are padded on
+    # the host to the bucket that holds the members and the zero row
+    assert ROWS == ep._bucket(N + 1)
+    assert device.shape == (ROWS, 48) and device.dtype == np.uint32
+    assert windows.shape == (ROWS, ep._WINDOW_WORDS) and windows.dtype == np.uint32
+    assert not np.asarray(device)[N:].any()
     idx = ep._table_index(slots, N, b)
     assert idx.dtype == np.int32 and idx.shape == (b,)
-    got = ep._gather_valset_rows(device, idx)
+    *got, tables = ep._gather_valset_rows(device, windows, idx)
     full = (table.neg_ax, table.ay, KEYS.view("<u4").astype(np.uint32))
     for g, whole in zip(got, full):
         want = ep._pad_rows(whole[slots], b)
@@ -307,6 +366,12 @@ def test_the_gather_is_the_padded_rows_of_the_membership(slots, b, programs):
         assert g.dtype == want.dtype and g.shape == want.shape
         assert g.tobytes() == want.tobytes()
         assert not g[len(slots):].any()
+    # the window tables in the layout the kernel reads: row m of a lane's
+    # tables is words NROW * m .. of its member's device row, lanes last
+    tables = np.asarray(tables)
+    assert tables.shape == (64 * ep.K, ep.NROW, b) and tables.dtype == np.uint32
+    want = np.asarray(windows)[idx].reshape(b, 64 * ep.K, ep.NROW)
+    assert tables.tobytes() == np.ascontiguousarray(want.transpose(1, 2, 0)).tobytes()
     # the member with no point is a row of zero limbs and valid False
     assert not table.valid[BAD_KEY] and table.valid.sum() == N - 1
     assert not table.neg_ax[BAD_KEY].any() and not table.ay[BAD_KEY].any()
@@ -349,13 +414,115 @@ def test_a_fill_is_spanned_as_a_miss_of_the_whole_membership(programs, tracing):
     for e in spans:
         by.setdefault(e["name"], []).append(e["args"])
     # the first call's fill: the host's rows under prepare, the upload under
-    # launch; the second call draws neither
+    # launch and the window tables' build inside it; the second call draws
+    # none of the three
     assert {(m["cache"], m["lanes"], m["bytes"]) for m in by["valset.miss"]} == {
-        ("host", N, 32 * N), ("device", N, 4 * 48 * (N + 1))}
+        ("host", N, 32 * N), ("device", N, 4 * 48 * ROWS)}
     parents = {m["cache"]: m["parent_id"] for m in by["valset.miss"]}
     assert parents == {"host": by["dispatch.prepare"][0]["span_id"],
                        "device": by["dispatch.launch"][0]["span_id"]}
+    (build,) = by["valset.tables"]
+    assert (build["members"], build["bytes"]) == (N, 4 * ep._WINDOW_WORDS * ROWS)
+    (device_miss,) = [m for m in by["valset.miss"] if m["cache"] == "device"]
+    assert build["parent_id"] == device_miss["span_id"]
     assert len(by["dispatch.prepare"]) == len(by["dispatch.launch"]) == 2
+    assert [m["tables"] for m in by["dispatch.launch"]] == ["resident"] * 2
+
+
+LANES_FORM = "tendermint_verify_ed25519_ladder_lanes_total"
+
+
+def _ladder_lanes(verify_counters):
+    return {form: verify_counters(LANES_FORM, {"tables": form})
+            for form in ("resident", "built")}
+
+
+@pytest.mark.parametrize("case,interpret,carry_mode,want", [
+    # who handed a ValsetRows down takes the resident form, in padded lanes
+    ("slots", False, "lazy", {"resident": 128.0}),
+    ("every_slot", False, "lazy", {"resident": 128.0}),
+    ("two_lengths", False, "lazy", {"resident": 256.0}),  # a launch a length
+    # everyone else builds a table a lane: no identity, ...
+    ("no_valset", False, "lazy", {"built": 128.0}),
+    # ... the interpret-mode reference path, 8 lanes a block, ...
+    ("slots", True, "lazy", {"built": 32.0}),
+    # ... and eager carries, in which the resident form is not written
+    ("slots", False, "eager", {"built": 128.0}),
+])
+def test_a_launch_counts_its_lanes_by_where_its_tables_came_from(
+        case, interpret, carry_mode, want, programs, verify_counters, tracing):
+    slots = _seeded_subset(absent=[BAD_KEY])
+    lengths = [SHORT if case == "two_lengths" and i % 4 == 0 else LONG
+               for i in range(len(slots))]
+    if case == "every_slot":
+        slots, lengths = np.arange(N), [LONG] * N
+    msgs, sigs = _signed(slots, lengths)
+    rows = None if case == "no_valset" else batch.ValsetRows(
+        batch.valset_key(KEYS), KEYS, None if case == "every_slot" else slots)
+    before = _ladder_lanes(verify_counters)
+    got = ep.verify_batch(KEYS[slots], msgs, sigs, interpret=interpret,
+                          carry_mode=carry_mode, valset=rows)
+    assert [i for i, ok in enumerate(got) if not ok] == (
+        [BAD_KEY] if case == "every_slot" else [])
+    assert _moved(_ladder_lanes(verify_counters), before) == want
+    (form,) = want
+    launches = [e["args"] for e in tracing.export()
+                if e.get("ph") == "X" and e["name"] == "dispatch.launch"]
+    assert [m["tables"] for m in launches] == (
+        [] if interpret else [form] * (2 if case == "two_lengths" else 1))
+    assert all(len(launch) == (8 if form == "resident" else 7)
+               for launch in programs.launches)
+    # who reads no tables has none built and none gathered
+    assert programs.builds == ([ROWS] if form == "resident" else [])
+
+
+def test_the_tables_are_built_once_a_membership(programs, tracing):
+    """Another subset every height, every slot at some: one ``valset.tables``
+    span and one build program's run, at the membership's first launch."""
+    rows = batch.ValsetRows(batch.valset_key(KEYS), KEYS, None)
+    for absent in ([BAD_KEY], [BAD_KEY, 3, 4], None, [BAD_KEY, 0, N - 1]):
+        slots = np.arange(N) if absent is None else _seeded_subset(absent=absent)
+        msgs, sigs = _signed(slots, [LONG] * len(slots))
+        ep.verify_batch(KEYS[slots], msgs, sigs, valset=rows._replace(
+            slots=None if absent is None else slots))
+    assert programs.builds == [ROWS] and len(programs.gathers) == 4
+    built = [e["args"] for e in tracing.export()
+             if e.get("ph") == "X" and e["name"] == "valset.tables"]
+    assert [(m["members"], m["bytes"]) for m in built] == [
+        (N, 4 * ep._WINDOW_WORDS * ROWS)]
+
+
+def test_an_evicted_table_frees_its_device_arrays(programs, monkeypatch):
+    import gc
+    import weakref
+
+    def call_jit(fn, *args, **static):
+        # no host view of an operand is taken: on the CPU that is a
+        # reference to the device array, and the matter here is who holds one
+        if fn is ep._device_verify_packed:
+            return np.ones((args[3].shape[0],), dtype=bool)
+        return programs.call_jit(fn, *args, **static)
+
+    monkeypatch.setattr(ep, "call_jit", call_jit)
+    monkeypatch.setattr(ep, "_VALSET_TABLES_MAX", 2)
+    members = [_random_membership(200 + s, n=9) for s in range(3)]
+    held = []
+    for m in members:
+        # random bytes are seldom points: the verdicts are not the matter
+        ep.verify_batch(m.keys, [b"m"] * 9, np.zeros((9, 64), np.uint8),
+                        valset=m._replace(slots=None))
+        table = ep._valset_tables[m.key_id]
+        rows, windows = table.device
+        (kept,) = table.whole.values()
+        held.append([weakref.ref(a) for a in (rows, windows, kept[3])])
+        del table, rows, windows, kept
+    gc.collect()
+    assert list(ep._valset_tables) == [m.key_id for m in members[1:]]
+    # the first membership's rows, window tables and kept gather are gone
+    # with its table; the resident two's are alive
+    assert [[r() is None for r in refs] for refs in held] == [
+        [True] * 3, [False] * 3, [False] * 3]
+    assert programs.builds == [128, 128, 128]
 
 
 def test_callers_on_many_threads_share_the_tables(programs, monkeypatch):
@@ -385,6 +552,30 @@ def test_callers_on_many_threads_share_the_tables(programs, monkeypatch):
         sys.setswitchinterval(interval)
     assert not any(t.is_alive() for t in threads)
     assert wrong == [] and len(ep._valset_tables) == 4
+
+
+def test_two_callers_filling_one_membership_at_once_share_one_table(
+        programs, monkeypatch):
+    """Both miss, both decompress, ONE table stays and both get it: its
+    window tables are built once, not once a caller."""
+    m = _random_membership(300)
+    both_in = threading.Barrier(2, timeout=30)
+    real = ep._decompress_rows
+
+    def decompress(keys):
+        both_in.wait()  # neither returns before the other has missed too
+        return real(keys)
+
+    monkeypatch.setattr(ep, "_decompress_rows", decompress)
+    got = []
+    threads = [threading.Thread(target=lambda: got.append(ep._valset_table(m)))
+               for _ in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert len(got) == 2 and got[0] is got[1]
+    assert ep._valset_tables == {m.key_id: got[0]}
 
 
 # ---------------------------------------------------------------------------
@@ -634,6 +825,9 @@ def test_a_membership_change_is_another_table(
     assert _moved(_lookups(verify_counters), before) == {
         ("table", "hit" if same_keys else "miss"): 1}
     assert len(ep._valset_tables) == (1 if same_keys else 2)
+    # another key array, another build of the window tables; the same keys
+    # at another power, none
+    assert programs.builds == ([128] if same_keys else [128, 128])
     if change == "remove":
         # the slot the removed member held is another member's now: a
         # precommit there under the removed member's key is refused
@@ -683,22 +877,56 @@ def one_chip():
 @pytest.mark.parametrize("lanes", [8192, 512])
 def test_the_gather_compiles_for_v5e_at_10_000_members(one_chip, lanes):
     """``commit10k-absent``'s two launches: 6,667 lanes in the 8,192 bucket
-    and 333 in the 512 one, out of a table of 10,000 members and the zero
-    row; what comes out is what ``_device_verify_packed`` takes."""
+    and 333 in the 512 one, out of a table of 10,000 members and its zero
+    rows (10,240: the bucket); what comes out is what a resident
+    ``_device_verify_packed`` takes."""
     import jax
     import jax.numpy as jnp
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = ep._gather_valset_rows.lower(
-        sds((10_001, 48), jnp.uint32), sds((lanes,), jnp.int32)).compile()
-    out = compiled.output_shardings
-    assert len(out) == 3
+    args = (sds((10_240, 48), jnp.uint32),
+            sds((10_240, ep._WINDOW_WORDS), jnp.uint32), sds((lanes,), jnp.int32))
+    compiled = ep._gather_valset_rows.lower(*args).compile()
+    assert len(compiled.output_shardings) == 4
     shapes = [(s.shape, s.dtype) for s in jax.eval_shape(
-        ep._gather_valset_rows, sds((10_001, 48), jnp.uint32),
-        sds((lanes,), jnp.int32))]
+        ep._gather_valset_rows, *args)]
     assert shapes == [((lanes, 20), jnp.uint32), ((lanes, 20), jnp.uint32),
-                      ((lanes, 8), jnp.uint32)]
+                      ((lanes, 8), jnp.uint32),
+                      ((64 * ep.K, ep.NROW, lanes), jnp.uint32)]
+    # the gathered rows once, and once more turned lanes-last
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes < 4 << 20 and mem.temp_size_in_bytes < 16 << 20
+    per_lane = 4 * ep._WINDOW_WORDS
+    assert mem.argument_size_in_bytes < 260 << 20
+    assert mem.temp_size_in_bytes <= per_lane * lanes + (1 << 20)
+    assert mem.output_size_in_bytes <= per_lane * lanes + (2 << 20)
+
+
+@pytest.mark.parametrize("program", ["resident_launch", "build"])
+def test_the_resident_programs_compile_for_v5e_at_10_000_members(one_chip, program):
+    """``commit10k-stream``'s launch in the ladder's resident form (10,240
+    lanes, their window tables an eighth operand: a 3 MB block a grid step in
+    VMEM) and the build of a 10,000-member table: what the TPU's compilers
+    refuse, they refuse here."""
+    import jax
+    import jax.numpy as jnp
+
+    def sds(shape, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if program == "build":
+        compiled = ep._build_valset_windows.lower(sds((10_240, 48))).compile()
+        (out,) = jax.tree_util.tree_leaves(jax.eval_shape(
+            ep._build_valset_windows, sds((10_240, 48))))
+        assert (out.shape, out.dtype) == ((10_240, ep._WINDOW_WORDS), jnp.uint32)
+    else:
+        lanes, rows, kpad = 10_240, 64, 2
+        compiled = ep._device_verify_packed.lower(
+            sds((lanes, 20)), sds((lanes, 20)), sds((lanes, 8)), sds((lanes, 16)),
+            sds((rows,)), sds((kpad,), jnp.int32), sds((lanes, kpad)),
+            sds((64 * ep.K, ep.NROW, lanes)), lanes=ep.LANES).compile()
+        # negax and ay are not read: the tables are the lanes' keys
+        assert compiled.memory_analysis().argument_size_in_bytes < (
+            4 * ep._WINDOW_WORDS * lanes + (2 << 20))
+    assert compiled.as_text().count("tpu_custom_call") >= 1
